@@ -53,8 +53,6 @@ exercises every section end-to-end in well under a minute for CI.
 
 from __future__ import annotations
 
-import bench_env  # noqa: F401 — persistent XLA cache, pre-jax
-
 import atexit
 import json
 import os
@@ -147,8 +145,8 @@ def bench_selector(n_rows: int, breakdown: bool = False, smoke: bool = False):
     sel = _selector(smoke=smoke)
     label.transform_with(sel, vec)
     sel.fit(ds)  # warm-up: compiles + transfer warming
-    # best of two timed fits: remote-device transports have multi-second
-    # per-run jitter that would otherwise dominate the number.  Warm fits
+    # best of two timed fits: a single run's jitter would otherwise
+    # dominate the number.  Warm fits
     # must perform ZERO new XLA compilations (executable cache + jit cache);
     # the probe count is reported so the driver artifact records it.
     dt = float("inf")
@@ -1504,8 +1502,7 @@ def bench_tree_hist(n_rows: int, device_kind: str):
             jnp.float32(1.0), jnp.float32(0.3), jnp.float32(0.0))
         return tree.value.sum() + node.sum()
 
-    np.asarray(grow(binned, grad, hess))  # compile + warm (full host sync —
-    # block_until_ready does not reliably drain the remote-transport queue)
+    np.asarray(grow(binned, grad, hess))  # compile + warm (full host sync)
     reps = 3
     t0 = time.perf_counter()
     for _ in range(reps):
@@ -1562,7 +1559,7 @@ def bench_tree_hist_batched(n_rows: int, device_kind: str, trees_n: int = 50):
         return T._fit_forest(binned, y_cols, w, max_depth, n_bins,
                              jnp.float32(1.0), jnp.float32(0.0), fm, boot)
 
-    np.asarray(fit().value)  # compile + warm (hard sync through transport)
+    np.asarray(fit().value)  # compile + warm (hard host sync)
     reps = 3
     t0 = time.perf_counter()
     for _ in range(reps):

@@ -9,13 +9,8 @@ Run:  python benchmarks/full_pipeline_1m.py
 
 from __future__ import annotations
 
-import os
-import sys
-
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-import bench_env  # noqa: F401,E402 — persistent XLA cache, pre-jax
-
 import json
+import os
 import sys
 import time
 

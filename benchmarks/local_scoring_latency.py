@@ -15,16 +15,14 @@ Run:  python benchmarks/local_scoring_latency.py
 
 from __future__ import annotations
 
+import json
 import os
 import sys
-
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-import bench_env  # noqa: F401,E402
-
-import json
 import time
 
 import numpy as np
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
 def main():

@@ -154,7 +154,7 @@ def main():
 
     def timeit(fn, *args, reps=3):
         out = fn(*args)
-        np.asarray(out)  # hard sync through remote transports
+        np.asarray(out)  # hard host sync
         t0 = time.perf_counter()
         for _ in range(reps):
             out = fn(*args)

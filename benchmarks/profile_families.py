@@ -2,7 +2,6 @@
 import os
 import sys
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-import bench_env  # noqa: F401
 import time
 
 import numpy as np

@@ -2,7 +2,6 @@
 GBT tree growth vs chunk size, IRLS sweep pass structure.  Run on TPU."""
 import os, sys
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-import bench_env  # noqa: F401
 import sys
 import time
 

@@ -55,6 +55,29 @@ class TestDispatch:
         expected = "pallas" if jax.default_backend() == "tpu" else "xla"
         assert KD.kernel_mode() == expected
 
+    def test_auto_keeps_xla_under_a_multi_device_mesh(self, monkeypatch):
+        """jax refuses to lower a Mosaic kernel into a multi-device program
+        ("cannot be automatically partitioned"), so on a TPU ``auto`` must
+        not select one under ``use_mesh`` — by decision, and counted — while
+        an explicit TMOG_PALLAS=pallas still reaches the kernel."""
+        from transmogrifai_tpu.parallel.mesh import make_mesh, use_mesh
+
+        monkeypatch.delenv("TMOG_PALLAS", raising=False)
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        assert KD.kernel_mode() == "pallas"
+        with use_mesh(make_mesh(n_data=1, n_model=1,
+                                devices=jax.devices()[:1])):
+            assert KD.kernel_mode() == "pallas"      # one device: no SPMD
+        with use_mesh(make_mesh(n_data=2, n_model=2,
+                                devices=jax.devices()[:4])):
+            assert KD.kernel_mode() == "xla"
+            assert KD.cache_token() == "kernels:xla"
+            before = KD.kernel_selections().get("split:xla", 0)
+            assert KD.split_mode(1 << 16) is None
+            assert KD.kernel_selections()["split:xla"] == before + 1
+            monkeypatch.setenv("TMOG_PALLAS", "pallas")
+            assert KD.kernel_mode() == "pallas"
+
     def test_escape_hatch_and_interpret_env(self, monkeypatch):
         monkeypatch.setenv("TMOG_PALLAS", "0")
         assert KD.kernel_mode() == "xla"

@@ -200,18 +200,16 @@ class TestExecutableCache:
     def test_persistent_cache_roundtrip(self, tmp_path):
         """Process A compiles a sweep program into the persistent cache;
         process B (fresh interpreter, same key) must HIT it instead of
-        backend-compiling (satellite: key stability across processes)."""
+        backend-compiling (satellite: key stability across processes).  The
+        directory is placed from outside through JAX's own
+        ``JAX_COMPILATION_CACHE_DIR``; the library persists every compile
+        (minimum compile time 0), so this sub-second program round-trips
+        with no knob of its own."""
         script = (
-            "import os; os.environ.setdefault('JAX_PLATFORMS','cpu')\n"
             "import numpy as np\n"
             "from transmogrifai_tpu.perf import (measure_compiles,"
             " compile_snapshot, run_cached, enable_persistent_cache)\n"
             "from transmogrifai_tpu.models.logistic import _irls_sweep\n"
-            "import jax\n"
-            # the library default (1s) would leave this sub-second test
-            # program memory-only — persist everything for the round-trip
-            "jax.config.update("
-            "'jax_persistent_cache_min_compile_time_secs', 0.0)\n"
             "rng=np.random.default_rng(0)\n"
             "x=rng.normal(size=(512,5)).astype(np.float32)\n"
             "y=(rng.random(512)<.5).astype(np.float32)\n"
@@ -222,25 +220,61 @@ class TestExecutableCache:
             "s=compile_snapshot()\n"
             "print('STATS', c.backend_compiles, s.persistent_cache_hits,"
             " s.persistent_cache_misses)\n"
+            "print('DIR', enable_persistent_cache())\n"
         )
         env = {**os.environ, "JAX_PLATFORMS": "cpu",
                "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
-               "TMOG_XLA_CACHE_DIR": str(tmp_path),
-               # persist even sub-second CPU compiles for the round-trip
-               "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "0"}
+               "JAX_COMPILATION_CACHE_DIR": str(tmp_path)}
         stats = []
         for _ in range(2):
             out = subprocess.run([sys.executable, "-c", script], cwd=REPO,
                                  env=env, capture_output=True, text=True,
                                  timeout=240)
             assert out.returncode == 0, out.stderr[-2000:]
-            line = [ln for ln in out.stdout.splitlines()
-                    if ln.startswith("STATS")][-1]
+            lines = out.stdout.splitlines()
+            line = [ln for ln in lines if ln.startswith("STATS")][-1]
             stats.append([int(v) for v in line.split()[1:]])
-        (_, _, miss_a), (_, hit_b, _) = stats
+            assert f"DIR {tmp_path}" in lines
+        (compiles_a, _, miss_a), (compiles_b, hit_b, _) = stats
         assert miss_a >= 1          # first process wrote the cache
         assert hit_b >= 1           # second process read it back
+        assert compiles_b < compiles_a
         assert os.listdir(tmp_path)  # entries actually landed on disk
+
+    def test_cache_dir_is_placed_from_outside(self, monkeypatch):
+        """With ``JAX_COMPILATION_CACHE_DIR`` (or an earlier
+        ``jax.config.update``) holding a directory, the library sets NO
+        directory of its own; with nothing set it uses the one fixed
+        in-checkout path."""
+        import jax
+
+        from transmogrifai_tpu.perf import programs
+
+        assert programs.DEFAULT_CACHE_DIR == os.path.join(REPO, ".jax_cache")
+        updates = []
+        real_update = jax.config.update
+
+        def spy(name, value):
+            updates.append(name)
+            real_update(name, value)
+
+        before = jax.config.jax_compilation_cache_dir
+        monkeypatch.setattr(jax.config, "update", spy)
+        try:
+            real_update("jax_compilation_cache_dir", "/placed/from/outside")
+            assert programs.enable_persistent_cache() \
+                == "/placed/from/outside"
+            assert "jax_compilation_cache_dir" not in updates
+            real_update("jax_compilation_cache_dir", None)
+            assert programs.enable_persistent_cache() \
+                == programs.DEFAULT_CACHE_DIR
+            assert updates.count("jax_compilation_cache_dir") == 1
+        finally:
+            real_update("jax_compilation_cache_dir", before)
+        src = open(programs.__file__).read()
+        assert src.count('update("jax_compilation_cache_dir"') == 1
+        for stale in ("TMOG_XLA_CACHE_DIR", "expanduser"):
+            assert stale not in src
 
 
 class TestSweepCacheOnSelector:

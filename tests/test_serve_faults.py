@@ -151,11 +151,11 @@ class TestFaultHarness:
         assert is_retryable(TransientScoringError("x"))
         assert not is_retryable(ValueError("bad payload"))
 
-        class XlaRuntimeError(Exception):
-            pass
+        # the class the installed jax raises for device/runtime failures
+        from jax.errors import JaxRuntimeError
 
-        assert is_retryable(XlaRuntimeError("RESOURCE_EXHAUSTED: oom"))
-        assert not is_retryable(XlaRuntimeError("INVALID_ARGUMENT: shape"))
+        assert is_retryable(JaxRuntimeError("RESOURCE_EXHAUSTED: oom"))
+        assert not is_retryable(JaxRuntimeError("INVALID_ARGUMENT: shape"))
 
 
 # ---------------------------------------------------------------------------

@@ -47,8 +47,8 @@ def sweep_placements(x32: np.ndarray, extras, train_w, val_w):
     xd, n0 = place_rows_bucketed_cached(x32)
     pad = int(xd.shape[0]) - n0
     # extras and fold weights are content-cached: families re-derive the same
-    # padded labels/targets/weights per fit, and over remote transports the
-    # repeated multi-MB transfers dominate the actual sweep dispatch
+    # padded labels/targets/weights per fit, and each repeat would be another
+    # multi-MB host->device transfer ahead of the sweep dispatch
     extra_devs = [
         place_cached(pad_rows_bucketed_for_mesh(np.asarray(e), n=n0)[0],
                      (DATA_AXIS,))
@@ -113,9 +113,9 @@ def eval_metric(payload, y, w, *, metric_fn):
     Metric functions come from module-level registries (Evaluator.metric_fn),
     so their identity is stable across cv_sweep calls — WITHOUT this wrapper,
     every sweep re-traces the metric eagerly (or re-jits a fresh closure) and
-    pays a full backend compile per call.  Sort-based AUC programs cost tens
-    of seconds to compile on remote-compile backends, so this caching is
-    load-bearing for selector throughput, not a micro-optimization.
+    pays a full backend compile per call.  Sort-based AUC programs are among
+    the slower ones to compile, so this caching is load-bearing for selector
+    throughput, not a micro-optimization.
     """
     return metric_fn(payload, y, w)
 

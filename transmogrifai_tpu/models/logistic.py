@@ -199,7 +199,7 @@ def _device_prepare_fit(x, w, has_intercept: bool, standardize: bool):
     """WEIGHTED standardize + ones-append for a final fit, on device from the
     shared raw placement (padded rows carry w=0, so the moments are exact).
     Returns (xs, mean, std) — mean/std come back to host only as (d,) vectors,
-    instead of shipping a fresh standardized (n, d) block up the transport.
+    instead of shipping a fresh standardized (n, d) block to the device.
     """
     sw = jnp.maximum(w.sum(), 1e-12)
     if standardize:

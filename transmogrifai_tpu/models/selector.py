@@ -189,12 +189,10 @@ class ModelSelector(PredictionEstimatorBase):
         # on the shared placement AND the evaluator can consume device
         # payloads — no (n,)-sized host round trip, just the metric scalars
         # (r5 tail profile: host predict + re-upload was ~1.3s of a 12s fit).
-        # Anything else falls back to the host predict_column path.
-        payload = None
-        try:
-            payload = best_model.eval_payload_device(x)
-        except Exception:
-            payload = None
+        # A model without a device scoring path returns None and takes the
+        # host predict_column path; a device path that FAILS raises (no
+        # catch-all here: it would reroute a broken device silently).
+        payload = best_model.eval_payload_device(x)
         _pred_cache: List[Any] = []
 
         def pred_col():
@@ -286,36 +284,22 @@ class BinaryClassificationModelSelector:
 
     @staticmethod
     def default_models() -> List[Tuple[PredictionEstimatorBase, List[Dict[str, Any]]]]:
+        from .svm import LinearSVC
+        from .trees import GradientBoostedTreesClassifier, RandomForestClassifier
+
         lr_grid = [
             {"reg_param": r, "elastic_net": e}
             for r in (0.001, 0.01, 0.1)
             for e in (0.0, 0.5)
         ]
-        models: List[Tuple[PredictionEstimatorBase, List[Dict[str, Any]]]] = [
+        return [
             (LogisticRegression(), lr_grid),
+            (RandomForestClassifier(),
+             [{"num_trees": 50, "max_depth": d} for d in (3, 6)]),
+            (GradientBoostedTreesClassifier(),
+             [{"num_rounds": 50, "max_depth": 3}]),
+            (LinearSVC(), [{"reg_param": r} for r in (0.01, 0.1)]),
         ]
-        try:
-            from .trees import GradientBoostedTreesClassifier, RandomForestClassifier
-
-            rf_grid = [
-                {"num_trees": t, "max_depth": d}
-                for t in (50,) for d in (3, 6)
-            ]
-            gbt_grid = [
-                {"num_rounds": r, "max_depth": d}
-                for r in (50,) for d in (3,)
-            ]
-            models.append((RandomForestClassifier(), rf_grid))
-            models.append((GradientBoostedTreesClassifier(), gbt_grid))
-        except ImportError:
-            pass
-        try:
-            from .svm import LinearSVC
-
-            models.append((LinearSVC(), [{"reg_param": r} for r in (0.01, 0.1)]))
-        except ImportError:
-            pass
-        return models
 
     @staticmethod
     def with_cross_validation(
@@ -358,24 +342,17 @@ class MultiClassificationModelSelector:
     def default_models():
         """LR, RF, NB, DT — the reference's multiclass candidate set
         (MultiClassificationModelSelector.scala:49-76)."""
+        from .naive_bayes import NaiveBayes
+        from .trees import DecisionTreeClassifier, RandomForestClassifier
+
         grid = [{"reg_param": r} for r in (0.001, 0.01, 0.1)]
-        models = [(MultinomialLogisticRegression(), grid)]
-        try:
-            from .trees import DecisionTreeClassifier, RandomForestClassifier
-
-            models.append((RandomForestClassifier(), [{"num_trees": 50, "max_depth": d}
-                                                      for d in (3, 6)]))
-            models.append((DecisionTreeClassifier(), [{"max_depth": d}
-                                                      for d in (3, 6)]))
-        except ImportError:
-            pass
-        try:
-            from .naive_bayes import NaiveBayes
-
-            models.append((NaiveBayes(), [{"smoothing": 1.0}]))
-        except ImportError:
-            pass
-        return models
+        return [
+            (MultinomialLogisticRegression(), grid),
+            (RandomForestClassifier(),
+             [{"num_trees": 50, "max_depth": d} for d in (3, 6)]),
+            (DecisionTreeClassifier(), [{"max_depth": d} for d in (3, 6)]),
+            (NaiveBayes(), [{"smoothing": 1.0}]),
+        ]
 
     @staticmethod
     def with_cross_validation(
@@ -402,25 +379,18 @@ class RegressionModelSelector:
     def default_models():
         grid = [{"reg_param": r, "elastic_net": e}
                 for r in (0.001, 0.01, 0.1) for e in (0.0, 0.5)]
-        models = [(LinearRegression(), grid)]
-        try:
-            from .trees import GradientBoostedTreesRegressor, RandomForestRegressor
+        from .glm import GeneralizedLinearRegression
+        from .trees import GradientBoostedTreesRegressor, RandomForestRegressor
 
-            models.append((RandomForestRegressor(), [{"num_trees": 50, "max_depth": d}
-                                                     for d in (3, 6)]))
-            models.append((GradientBoostedTreesRegressor(), [{"num_rounds": 50,
-                                                              "max_depth": 3}]))
-        except ImportError:
-            pass
-        try:
-            from .glm import GeneralizedLinearRegression
-
-            models.append((GeneralizedLinearRegression(),
-                           [{"family": "gaussian", "reg_param": r}
-                            for r in (0.0, 0.01)]))
-        except ImportError:
-            pass
-        return models
+        return [
+            (LinearRegression(), grid),
+            (RandomForestRegressor(),
+             [{"num_trees": 50, "max_depth": d} for d in (3, 6)]),
+            (GradientBoostedTreesRegressor(),
+             [{"num_rounds": 50, "max_depth": 3}]),
+            (GeneralizedLinearRegression(),
+             [{"family": "gaussian", "reg_param": r} for r in (0.0, 0.01)]),
+        ]
 
     @staticmethod
     def with_cross_validation(

@@ -947,7 +947,7 @@ class _TreeEnsembleModelBase(PredictionModelBase):
 
     #: batches at or below this row count predict on HOST numpy — a device
     #: dispatch per record is the wrong trade for ms-grade local serving
-    #: (the reference's MLeap role), especially over remote-device transports
+    #: (the reference's MLeap role)
     _HOST_PREDICT_MAX_ROWS = 512
 
     def _margin(self, x: np.ndarray) -> np.ndarray:
@@ -961,8 +961,8 @@ class _TreeEnsembleModelBase(PredictionModelBase):
         # go through the shared content-keyed placement: predicting on the
         # block the model was just fit on (the selector's train-eval pass,
         # model.score right after train) must NOT re-transfer the (n, d)
-        # matrix — over remote transports that copy is tens of seconds,
-        # dwarfing the actual traversal (measured 35-55s vs ~1s at 1M rows)
+        # matrix — a second host->device copy of the block costs more than
+        # the traversal it feeds
         from ..parallel.mesh import place_rows_bucketed_cached
 
         xd, n0 = place_rows_bucketed_cached(x, insert=False)
@@ -1098,8 +1098,7 @@ class _TreeEstimatorBase(PredictionEstimatorBase):
         """(device bin codes (padded rows), edges, n_valid) — bins ON DEVICE
         from the shared raw placement, so a final refit after CV re-uses the
         block the sweep already transferred (no second (n, d) host->device
-        copy; at 1M rows that copy dominates refit wall time over remote
-        transports).  Padded rows carry zero weight downstream."""
+        copy).  Padded rows carry zero weight downstream."""
         x32 = np.asarray(x, np.float32)
         from ..parallel.mesh import place_rows_bucketed_cached
 

@@ -72,7 +72,7 @@ def initialize(coordinator_address: Optional[str] = None,
                 "single-host run. Pass coordinator_address/num_processes/"
                 "process_id explicitly.") from e
         # pod-like markers but genuinely single-worker (e.g. a 1-host slice
-        # behind a tunnel): proceed single-host, but say so.
+        # whose launcher still exports them): proceed single-host, but say so.
         import logging
 
         logging.getLogger(__name__).warning(
@@ -114,15 +114,7 @@ def _pod_environment() -> bool:
 
 
 def is_initialized() -> bool:
-    try:
-        state = getattr(jax.distributed, "global_state", None)
-        if state is None:  # jax >= 0.9 keeps the state in jax._src
-            from jax._src import distributed as _dist
-
-            state = _dist.global_state
-        return state.client is not None
-    except Exception:
-        return False
+    return jax.distributed.is_initialized()
 
 
 def process_info() -> dict:

@@ -284,7 +284,7 @@ def pad_rows_bucketed_for_mesh(*arrays, n: Optional[int] = None):
 # -- shared device placement cache -------------------------------------------
 # One selector fit runs several model families over the SAME feature block;
 # without sharing, every family pays its own host->device transfer of the
-# padded (n, d) matrix (tens of seconds each on slow transports).  The cache
+# padded (n, d) matrix.  The cache
 # keys on a CONTENT fingerprint (shape + dtype + full-buffer checksum), so a
 # family that re-materialises an identical float32 copy still hits.  An
 # in-place mutation of a DIFFERENT object with equal old content misses as
@@ -431,9 +431,9 @@ def place_cached(arr: np.ndarray, axes: tuple,
     For mid-sized row-aligned blocks that several consumers re-derive
     identically per selector fit — fold weight matrices especially: every
     family pads the validator's (k, n) train/val weights to the same content,
-    and without the cache each family pays its own multi-second transfer of
-    ~24 MB over remote transports.  Keyed on (shape, dtype, blake2b, axes,
-    mesh); bounded FIFO shared with the row cache budget."""
+    and without the cache each family pays its own ~24 MB host->device
+    transfer.  Keyed on (shape, dtype, blake2b, axes, mesh); bounded FIFO
+    shared with the row cache budget."""
     mesh = mesh if mesh is not None else current_mesh()
     arr = np.asarray(arr)
     key = (arr.shape, str(arr.dtype), _content_stamp(arr), tuple(axes), mesh)

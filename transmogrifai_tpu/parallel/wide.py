@@ -28,26 +28,12 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .mesh import DATA_AXIS, pad_axis
 
-try:  # jax >= 0.8
-    from jax import shard_map
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 
 def _mark_varying(x, axis: str):
-    """Mark a constant as device-varying over `axis` (scan-carry requirement).
-
-    jax >= 0.9 spells this jax.lax.pcast(..., to='varying'); earlier releases
-    used jax.lax.pvary.
-    """
-    pcast = getattr(jax.lax, "pcast", None)
-    if pcast is not None:
-        return pcast(x, (axis,), to="varying")
-    pvary = getattr(jax.lax, "pvary", None)
-    if pvary is not None:
-        return pvary(x, (axis,))
-    # jax <= 0.5: shard_map has no varying-type tracking — nothing to mark
-    return x
+    """Mark a constant as device-varying over `axis` (scan-carry requirement)."""
+    return jax.lax.pcast(x, (axis,), to="varying")
 
 
 def col_sharding(mesh: Mesh) -> NamedSharding:

@@ -401,11 +401,23 @@ def _family_bench(family: str, dims: Dict[str, int], mode: str
         L, nn, K = dims["lanes"], dims["nodes"], dims["classes"]
         d, n_bins = dims["features"], dims["bins"]
         B = n_bins + 1
-        hg = rng.integers(-20, 20, (L, nn, K, d, B)).astype(np.float32)
-        hh = rng.integers(0, 30, (L, nn, K, d, B)).astype(np.float32)
-        G = jnp.asarray(hg[:, :, :, 0, :].sum(-1))
-        H = jnp.asarray(hh[:, :, :, 0, :].sum(-1))
-        hg, hh = jnp.asarray(hg), jnp.asarray(hh)
+        # a histogram the grower could have built: EVERY feature's bins sum
+        # to the node totals (independent draws per feature leave negative
+        # right-child hessians, where the gain formula's eps guard — and
+        # with it the comparison — degenerates to 1/0 vs 1/1e-12)
+        cells = (L, nn, K, d)
+        even = np.full(B, 1.0 / B)
+
+        def spread(totals):
+            return rng.multinomial(
+                np.broadcast_to(totals[..., None], cells), even)
+
+        pos, neg = (rng.integers(0, 120, (L, nn, K)) for _ in range(2))
+        tot_h = rng.integers(B, 8 * B, (L, nn, K))
+        hg = jnp.asarray((spread(pos) - spread(neg)).astype(np.float32))
+        hh = jnp.asarray(spread(tot_h).astype(np.float32))
+        G = jnp.asarray((pos - neg).astype(np.float32))
+        H = jnp.asarray(tot_h.astype(np.float32))
         mask = jnp.ones((L, d), jnp.float32)
         params_f = tuple(jnp.float32(v) for v in (1.0, 0.5, 0.1, 1.0))
 

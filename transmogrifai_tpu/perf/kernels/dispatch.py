@@ -11,7 +11,9 @@ formulation — with the decision itself made observable and cacheable:
   =============  ==========================================================
   ``TMOG_PALLAS``  effective mode
   =============  ==========================================================
-  unset / ``1`` / ``auto``   ``pallas`` on a TPU backend, ``xla`` elsewhere
+  unset / ``1`` / ``auto``   ``pallas`` on a TPU backend — except under a
+                             multi-device ``use_mesh`` (see below) — and
+                             ``xla`` elsewhere
   ``0`` / ``off`` / ``xla``  ``xla`` everywhere — the escape hatch
   ``interpret``              ``pallas.interpret=True`` emulation (CPU/CI
                              parity tests; jittable, runs anywhere)
@@ -24,11 +26,43 @@ formulation — with the decision itself made observable and cacheable:
   mode can never serve a stale executable compiled for the other mode —
   the same fallback discipline the fused transform planner established
   (``TMOG_FUSED_TRANSFORM``, PR 4).
-- VMEM admission guards (``hist_mode``/``split_mode``/``encode_mode``):
-  compiled Pallas keeps its accumulator and operands resident in VMEM, so a
-  shape whose working set exceeds the budget (``TMOG_PALLAS_VMEM_BUDGET``,
-  default 10 MB of the ~16 MB/core) falls back to the XLA path instead of
-  failing to compile.  Interpret mode has no such limit.
+- VMEM admission guards (``hist_mode``/``split_mode``/``route_mode``/
+  ``encode_mode``): compiled Pallas keeps its accumulator and operands
+  resident in VMEM, so a shape whose working set exceeds the budget
+  (``TMOG_PALLAS_VMEM_BUDGET``, default 10 MiB of the compiler's 16 MiB
+  scoped limit — see ``_DEFAULT_VMEM_BUDGET``) takes the XLA path by this
+  module's DECISION, before the compiler is asked.  Nothing here catches a
+  compiler error and reroutes: a kernel that is selected and then refused
+  fails the program.  Interpret mode has no such limit.
+- What ``auto`` selects on a TPU, per kernel, as established on a v5e with
+  jax 0.9.0 / libtpu 0.0.34 (CHANGES.md PR 21; ``chip_smoke.py`` re-proves
+  it):
+
+  ====================  =================================================
+  kernel                ``auto`` on TPU
+  ====================  =================================================
+  ``split_scan``        selected (all tree levels at d=128, 32 bins).
+                        Repaired in PR 21: the (8, 128) block rule and the
+                        missing ``cumsum`` lowering refused the original.
+  ``row_select_lanes``  selected when the measured scratch model fits
+                        (block 256: up to 128 lanes at d=128).
+  ``onehot_codes``,     selected at every serving width.
+  ``bucketize_right``
+  ``hist_level``        selected only where the working set fits; at
+                        d=128, 33 bins and the default 2048-row chunk the
+                        (chunk, B*d) one-hot alone is 8.6-17 MB, so the
+                        bench/smoke shapes run the XLA scan.  Repaired in
+                        PR 21 for the int8 path (no int8 multiply or
+                        sublane broadcast on the v5e vector unit).
+  ====================  =================================================
+
+  Under a multi-device ambient mesh (``parallel.mesh.use_mesh``) ``auto``
+  selects NONE of them: jax cannot partition a Mosaic kernel automatically
+  and the kernels are not wrapped in ``shard_map`` yet, so the sharded
+  programs keep the XLA formulations (``chip_smoke.py --mesh 2x2``).
+
+  ``kernel_selections()`` counts the decisions this process actually made
+  (at trace time), so a run can show which kernels it really used.
 - ``tuning_int()`` is the one helper every env-overridable tuning knob
   reads through (``TMOG_HIST_CHUNK``, ``TMOG_HIST_UNROLL``, the VMEM
   budget); ``kernel_provenance()`` reports the live values so BENCH rounds
@@ -39,8 +73,9 @@ from __future__ import annotations
 
 import logging
 import os
+import threading
 from contextlib import contextmanager
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Optional, Tuple
 
 log = logging.getLogger(__name__)
 
@@ -58,9 +93,19 @@ _TUNING_TOKEN: str = ""
 #: env.  Same scalar-rebind discipline as _FORCED.
 _FORCED_DONATION: Optional[bool] = None
 
-#: resolved default VMEM budget for compiled kernels (bytes): leave head
-#: room under the ~16 MB/core for double buffering and the epilogue
+#: default VMEM budget for compiled kernels (bytes).  Source: the TPU
+#: compiler's own report for a v5e under libtpu 0.0.34 — "Scoped allocation
+#: with size 25.45M and limit 16.00M exceeded scoped vmem limit" (Mosaic
+#: kernels get a 16 MiB scoped-VMEM stack by default; the chip has more, but
+#: nothing here raises ``vmem_limit_bytes``).  The admission formulas count
+#: blocks and the larger temporaries, not every compiler scratch buffer, so
+#: the budget keeps 6 MiB of that limit in hand.
 _DEFAULT_VMEM_BUDGET = 10 * 1024 * 1024
+
+#: (kernel, chosen mode) -> how many dispatch decisions this process made;
+#: decisions happen at trace time, so this counts traces, not executions
+_SELECTIONS: Dict[Tuple[str, str], int] = {}
+_SELECTIONS_LOCK = threading.Lock()
 
 #: the histogram tuning-knob defaults — ONE definition; models/trees.py and
 #: perf/kernels/histogram.py both resolve their knobs against these
@@ -120,7 +165,18 @@ def kernel_mode() -> str:
         return mode
     import jax
 
-    return "pallas" if jax.default_backend() == "tpu" else "xla"
+    if jax.default_backend() != "tpu":
+        return "xla"
+    # jax refuses to lower a Mosaic kernel into a program that spans more
+    # than one device ("Mosaic kernels cannot be automatically partitioned.
+    # Please wrap the call in a shard_map.") and none of ours is wrapped
+    # yet: under a multi-device ambient mesh ``auto`` keeps the XLA
+    # formulations.  An explicit TMOG_PALLAS=pallas still reaches the
+    # kernels there, and fails loudly.
+    from ...parallel.mesh import current_mesh
+
+    mesh = current_mesh()
+    return "xla" if mesh is not None and mesh.size > 1 else "pallas"
 
 
 @contextmanager
@@ -217,15 +273,25 @@ def vmem_budget() -> int:
     return tuning_int("TMOG_PALLAS_VMEM_BUDGET", _DEFAULT_VMEM_BUDGET)
 
 
-def _admit(working_set_bytes: int) -> Optional[str]:
-    """Mode for a kernel whose VMEM working set is ``working_set_bytes``:
-    None = run the XLA reference path."""
+def _admit(kernel: str, working_set_bytes: int) -> Optional[str]:
+    """Mode for ``kernel`` at a VMEM working set of ``working_set_bytes``:
+    None = run the XLA reference path.  The decision is counted
+    (:func:`kernel_selections`)."""
     mode = kernel_mode()
-    if mode == "xla":
-        return None
-    if mode == "pallas" and working_set_bytes > vmem_budget():
-        return None
+    if mode == "xla" or (mode == "pallas"
+                         and working_set_bytes > vmem_budget()):
+        mode = None
+    with _SELECTIONS_LOCK:
+        key = (kernel, mode or "xla")
+        _SELECTIONS[key] = _SELECTIONS.get(key, 0) + 1
     return mode
+
+
+def kernel_selections() -> Dict[str, int]:
+    """``{"split:pallas": 12, "hist:xla": 9, ...}`` — the dispatch decisions
+    made so far in this process (one per traced call site)."""
+    with _SELECTIONS_LOCK:
+        return {f"{k}:{m}": n for (k, m), n in sorted(_SELECTIONS.items())}
 
 
 def hist_mode(m_rows: int, bd_cols: int, chunk: int, lanes_bytes_per_row: int,
@@ -240,28 +306,30 @@ def hist_mode(m_rows: int, bd_cols: int, chunk: int, lanes_bytes_per_row: int,
           + m_rows * chunk * elem_bytes         # activation
           + chunk * bd_cols * elem_bytes        # bin one-hot
           + chunk * lanes_bytes_per_row)        # local + gh + codes blocks
-    return _admit(ws)
+    return _admit("hist", ws)
 
 
-def split_mode(per_lane_hist_bytes: int) -> Optional[str]:
-    """Dispatch decision for the split-scan kernel (grid over lanes: one
-    (nn, 2K, d, B) histogram block + its cumsums resident per step)."""
-    return _admit(4 * per_lane_hist_bytes)
+def split_mode(block_bytes: int) -> Optional[str]:
+    """Dispatch decision for the split-scan kernel (grid over lane blocks).
+    ``block_bytes`` is one step's grad + hess histogram block AS TILED in
+    VMEM (nodes padded to 8 sublanes, features to 128 lanes); the Pallas
+    pipeline double-buffers it, and the bin loop's running sums and
+    candidates are a few (classes, nodes, features) slabs on top."""
+    return _admit("split", 2 * block_bytes + block_bytes // 2)
 
 
 def route_mode(d: int, lanes: int, block_rows: int = 256) -> Optional[str]:
-    """Dispatch decision for the routing kernel (perf/kernels/routing.py):
-    the VMEM working set per grid step is the (block, d) code tile, the
-    (block, lanes) index/output tiles, and the (block, d, lanes)
-    compare-reduce temporaries — of which up to THREE are live at once
-    (the widened bool compare mask, its f32 cast, and the codes*oh product
-    before the reduce), so that term is charged 3x: undersizing admits a
-    kernel Mosaic then fails to allocate at compile time instead of taking
-    the silent XLA fallback (the hist_mode hazard)."""
-    ws = (block_rows * d * 8                    # codes (int32 in + f32 cast)
-          + 2 * block_rows * lanes * 4          # idx + routed output
-          + 3 * block_rows * d * lanes * 4)     # mask + one-hot + product
-    return _admit(ws)
+    """Dispatch decision for the routing kernel (perf/kernels/routing.py).
+    Its rank-3 (block, d, lanes) compare-reduce puts the lane axis on the
+    128 vector lanes, so VMEM goes by lane TILES, not lanes.  The scratch
+    model is fitted to what the v5e compiler reported (libtpu 0.0.34, d=128:
+    block 512 -> 18.64M at 1 lane tile, 25.48M at 2; block 1024 -> 36.25M
+    and 47.53M): about 192 + 88 * lane_tiles bytes per (row, feature).
+    Undersizing would select a kernel the compiler then refuses."""
+    lane_tiles = -(-lanes // 128)
+    ws = block_rows * d * (192 + 88 * lane_tiles) \
+        + 2 * block_rows * (d + 128 * lane_tiles) * 4   # in/out blocks, x2
+    return _admit("route", ws)
 
 
 def encode_mode(width: int, block_rows: int = 1024) -> Optional[str]:
@@ -270,7 +338,7 @@ def encode_mode(width: int, block_rows: int = 1024) -> Optional[str]:
     a kernel)."""
     if width <= 0:
         return None
-    return _admit(2 * block_rows * (width + 2) * 4)
+    return _admit("encode", 2 * block_rows * (width + 2) * 4)
 
 
 def kernel_provenance() -> Dict[str, Any]:
@@ -287,6 +355,7 @@ def kernel_provenance() -> Dict[str, Any]:
         "hist_unroll": tuning_int("TMOG_HIST_UNROLL", HIST_UNROLL_DEFAULT),
         "pallas_vmem_budget": vmem_budget(),
         "serve_donation": serve_donation(),
+        "selected": kernel_selections(),
     }
     try:
         from ...models import trees as _trees

@@ -4,7 +4,8 @@ Reference capability (SURVEY §2.9): XGBoost's C++ ``hist`` tree method — the
 per-(node, class, feature, bin) gradient/hessian histogram build that
 dominates GBT/RF fit time.  ``models/trees.py`` computes it as a scatter-free
 one-hot GEMM row-chunked under ``lax.scan`` (TPU lowers scatters to slow
-sorts); BENCH_r04 measured that formulation at ~4.3 TFLOPs / 0.06 HBM
+sorts); round 4 (before PRs 1-20, on a set-up that no longer exists — not
+measured on today's code) put that formulation at ~4.3 TFLOPs / 0.06 HBM
 utilization in the unbatched regime — bound by memory layout (constructing
 ``B*n*d`` one-hot elements through HBM-visible operands), not math.
 
@@ -137,9 +138,15 @@ def hist_level_pallas(local: jnp.ndarray, ghT: jnp.ndarray,
         """The shared per-chunk math: node one-hot x gh contraction against
         the joint (feature, bin) one-hot — identical across variants."""
         node_ids = jax.lax.broadcasted_iota(jnp.int32, (1, nn, 1), 1)
-        node_oh = (lb[:, None, :] == node_ids).astype(hdt)
-        acc = (node_oh[:, :, None, :] * gh.astype(hdt)[:, None, :, :]
-               ).reshape(M, chunk)
+        # select in a 32-bit type, then narrow: the v5e vector unit has no
+        # int8 multiply or int8 sublane broadcast (Mosaic: "failed to
+        # legalize 'arith.muli'", "Not implemented: Sublane broadcast"),
+        # and a 0/1 mask times gh IS the select
+        wide = jnp.int32 if int_exact else hdt
+        in_node = lb[:, None, :] == node_ids
+        acc = jnp.where(in_node[:, :, None, :],
+                        gh.astype(wide)[:, None, :, :],
+                        jnp.zeros((), wide)).reshape(M, chunk).astype(hdt)
         bin_ids = jax.lax.broadcasted_iota(jnp.int32, (1, B, 1), 1)
         # (chunk, B, d) layout, matching the reference: the innermost axis
         # stays the 128-lane-aligned feature dim

@@ -12,20 +12,23 @@ This module holds the ONE definition of that math for the TPU port:
   inlined per level, moved here verbatim so the XLA path, the Pallas
   kernel, the parity tests, and the bench baseline all share it;
 - :func:`split_scan_pallas` — the fused kernel: grid over lanes, each step
-  holds one lane's (nn, 2K, d, B) histogram block in VMEM and produces the
-  per-node best split index / gain / missing-direction without any of the
-  intermediate (L, nodes, d, bins) gain tensors touching HBM — the
+  holds one lane block's (B, K, nn, d) histogram block in VMEM and produces
+  the per-node best split index / gain / missing-direction without any of
+  the intermediate (L, nodes, d, bins) gain tensors touching HBM — the
   histogram epilogue fused to its decision;
 - :func:`split_scan` — the dispatcher (``perf.kernels.dispatch`` mode +
   VMEM admission).
 
-Selection parity: the kernel runs the same jnp ops in the same order as the
-reference (cumsum, gain, argmax); the only formulation difference is
-gather-free best-element selection (a masked max picks the identical
-element exactly).  On the exact-int8 histogram path every operand of the
-gain formula is an integer-valued f32, so gains — and therefore split
-decisions — are bitwise-identical across paths (tier-1 pinned,
-tests/test_kernels.py).
+Selection parity: both paths score every candidate with the one
+``_gain_terms`` formula.  The kernel walks the bins with a running sum
+where the reference takes ``cumsum`` + a flattened ``argmax`` (``cumsum``
+has no Pallas TPU lowering on jax 0.9.0), and picks the first maximum in
+the same (feature, bin) order.  On the exact-int8 histogram path every operand of the
+gain formula is an integer-valued f32, so prefix sums are exact in any
+order and gains — and therefore split decisions — are bitwise-identical
+across paths (tier-1 pinned, tests/test_kernels.py).  A NaN gain (NaN
+gradients upstream) is outside the contract: ``argmax`` would return its
+index, the kernel never selects it.
 """
 
 from __future__ import annotations
@@ -119,11 +122,21 @@ def split_scan_pallas(hist_g, hist_h, G, H, level_mask, n_bins: int,
                       ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Fused per-lane split scan; same contract as :func:`split_scan_xla`.
 
+    Layout (what the TPU compiler accepts — CHANGES.md PR 21): the
+    histograms enter as (L, B, K, nn, d), so every in-kernel value is a
+    (nodes, features) tile with the feature axis on the 128 lanes.  The bin
+    prefix sums are a running sum over the LEADING bin axis (no in-kernel
+    ``cumsum``, which Mosaic does not lower), each bin's gains are scored as
+    they appear, and a strict ``>`` keeps the first best bin per feature;
+    the winning feature is the smallest flat (feature, bin) index at the
+    maximum — ``argmax``'s first-occurrence rule, without a flattening
+    reshape or a gather.  Outputs are (L, nn, 1) so a one-lane block is a
+    legal (8, 128)-rule block shape.
+
     ``lane_block`` lanes share one grid step (autotune family ``split``;
-    default 1 = the original schedule).  Lanes padded up to the block
-    multiple carry all-zero histograms, score ``-inf`` everywhere (the
-    min-child-weight guard), and are sliced off — per-lane results are
-    bitwise-independent of the blocking."""
+    default 1).  Lanes padded up to the block multiple carry all-zero
+    histograms, score ``-inf`` everywhere (the min-child-weight guard), and
+    are sliced off — per-lane results are independent of the blocking."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -140,6 +153,11 @@ def split_scan_pallas(hist_g, hist_h, G, H, level_mask, n_bins: int,
         H = jnp.pad(H, ((0, pad), (0, 0), (0, 0)))
         level_mask = jnp.pad(level_mask, ((0, pad), (0, 0)))
     L_p = L + pad
+    hg_t = hist_g.transpose(0, 4, 2, 1, 3)              # (L, B, K, nn, d)
+    hh_t = hist_h.transpose(0, 4, 2, 1, 3)
+    G_t = G.transpose(0, 2, 1)[..., None]               # (L, K, nn, 1)
+    H_t = H.transpose(0, 2, 1)[..., None]
+    mask3 = level_mask[:, None, :]                      # (L, 1, d)
     params = jnp.stack([
         jnp.asarray(reg_lambda, jnp.float32),
         jnp.asarray(alpha, jnp.float32),
@@ -148,73 +166,85 @@ def split_scan_pallas(hist_g, hist_h, G, H, level_mask, n_bins: int,
 
     def kernel(hg_ref, hh_ref, g_ref, h_ref, mask_ref, p_ref,
                best_ref, gain_ref, bml_ref):
-        hg = hg_ref[:]                                  # (lb, nn, K, d, B)
-        hh = hh_ref[:]
-        reg_l, alph = p_ref[0, 0], p_ref[0, 1]
-        gam, mcw = p_ref[0, 2], p_ref[0, 3]
-        gl = jnp.cumsum(hg[..., :n_bins], axis=-1)[..., :-1]
-        hl = jnp.cumsum(hh[..., :n_bins], axis=-1)[..., :-1]
-        g_miss = hg[..., n_bins][..., None]
-        h_miss = hh[..., n_bins][..., None]
-        Gt = g_ref[:][..., None, None]                  # (lb, nn, K, 1, 1)
-        Ht = h_ref[:][..., None, None]
-        args = (reg_l, alph, gam, mcw)
-        gain_mr = _gain_terms(gl, hl, Gt, Ht, *args, class_axis=2)
-        gain_ml = _gain_terms(gl + g_miss, hl + h_miss, Gt, Ht, *args,
-                              class_axis=2)
-        gain = jnp.maximum(gain_mr, gain_ml)
-        gain = jnp.where(mask_ref[:][:, None, :, None] > 0, gain, -jnp.inf)
+        args = (p_ref[0, 0], p_ref[0, 1], p_ref[0, 2], p_ref[0, 3])
+        Gt, Ht = g_ref[:], h_ref[:]                     # (lb, K, nn, 1)
+        g_miss, h_miss = hg_ref[:, n_bins], hh_ref[:, n_bins]
+        masked = mask_ref[:] > 0                        # (lb, 1, d)
 
-        flat = gain.reshape(lb, nn, F)
-        best = flat.argmax(axis=-1).astype(jnp.int32)
-        # gather-free selection: the masked max picks the exact element
-        col = jax.lax.broadcasted_iota(jnp.int32, (lb, nn, F), 2)
-        sel = col == best[..., None]
-        gain_ref[:] = jnp.max(jnp.where(sel, flat, -jnp.inf), axis=-1)
-        sel_ml = jnp.max(jnp.where(sel, gain_ml.reshape(lb, nn, F),
-                                   -jnp.inf), axis=-1)
-        sel_mr = jnp.max(jnp.where(sel, gain_mr.reshape(lb, nn, F),
-                                   -jnp.inf), axis=-1)
-        best_ref[:] = best
-        bml_ref[:] = (sel_ml >= sel_mr).astype(jnp.int8)
+        def score(gl, hl):
+            mr = _gain_terms(gl, hl, Gt, Ht, *args, class_axis=1)
+            ml = _gain_terms(gl + g_miss, hl + h_miss, Gt, Ht, *args,
+                             class_axis=1)
+            gain = jnp.where(masked, jnp.maximum(mr, ml), -jnp.inf)
+            return gain, (ml >= mr).astype(jnp.int32)   # (lb, nn, d) each
 
-    hist_spec = pl.BlockSpec((lb, nn, K, d, B), lambda l: (l, 0, 0, 0, 0),
+        def step(b, carry):
+            gl, hl, best_gain, best_bin, best_ml = carry
+            gl, hl = gl + hg_ref[:, b], hl + hh_ref[:, b]
+            gain, ml = score(gl, hl)
+            better = gain > best_gain       # strict: first best bin wins
+            return (gl, hl, jnp.where(better, gain, best_gain),
+                    jnp.where(better, b, best_bin),
+                    jnp.where(better, ml, best_ml))
+
+        gl0, hl0 = hg_ref[:, 0], hh_ref[:, 0]
+        gain0, ml0 = score(gl0, hl0)
+        _, _, best_gain, best_bin, best_ml = jax.lax.fori_loop(
+            1, n_bins - 1, step,
+            (gl0, hl0, gain0, jnp.zeros_like(ml0), ml0))
+
+        # f32 index arithmetic (exact below 2^24): float lane reductions are
+        # the ones every Mosaic version lowers
+        top = jnp.max(best_gain, axis=-1, keepdims=True)        # (lb, nn, 1)
+        feat = jax.lax.broadcasted_iota(jnp.int32, best_bin.shape, 2)
+        flat = (feat * (n_bins - 1) + best_bin).astype(jnp.float32)
+        first = jnp.min(jnp.where(best_gain == top, flat, float(F - 1)),
+                        axis=-1, keepdims=True)
+        best_ref[:] = first.astype(jnp.int32)
+        gain_ref[:] = top
+        bml_ref[:] = jnp.max(
+            jnp.where(flat == first, best_ml.astype(jnp.float32), 0.0),
+            axis=-1, keepdims=True).astype(jnp.int32)
+
+    hist_spec = pl.BlockSpec((lb, B, K, nn, d), lambda l: (l, 0, 0, 0, 0),
                              memory_space=pltpu.VMEM)
-    gh_spec = pl.BlockSpec((lb, nn, K), lambda l: (l, 0, 0),
+    gh_spec = pl.BlockSpec((lb, K, nn, 1), lambda l: (l, 0, 0, 0),
                            memory_space=pltpu.VMEM)
-    out_spec = pl.BlockSpec((lb, nn), lambda l: (l, 0),
+    out_spec = pl.BlockSpec((lb, nn, 1), lambda l: (l, 0, 0),
                             memory_space=pltpu.VMEM)
     best, best_gain, bml = pl.pallas_call(
         kernel,
         grid=(L_p // lb,),
         in_specs=[
             hist_spec, hist_spec, gh_spec, gh_spec,
-            pl.BlockSpec((lb, d), lambda l: (l, 0),
+            pl.BlockSpec((lb, 1, d), lambda l: (l, 0, 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, 4), lambda l: (0, 0),
                          memory_space=pltpu.SMEM),
         ],
         out_specs=(out_spec, out_spec, out_spec),
         out_shape=(
-            jax.ShapeDtypeStruct((L_p, nn), jnp.int32),
-            jax.ShapeDtypeStruct((L_p, nn), jnp.float32),
-            jax.ShapeDtypeStruct((L_p, nn), jnp.int8),
+            jax.ShapeDtypeStruct((L_p, nn, 1), jnp.int32),
+            jax.ShapeDtypeStruct((L_p, nn, 1), jnp.float32),
+            jax.ShapeDtypeStruct((L_p, nn, 1), jnp.int32),
         ),
         interpret=bool(interpret),
-    )(hist_g, hist_h, G, H, level_mask, params)
-    return best[:L], best_gain[:L], bml[:L] != 0
+    )(hg_t, hh_t, G_t, H_t, mask3, params)
+    return best[:L, :, 0], best_gain[:L, :, 0], bml[:L, :, 0] != 0
 
 
 def split_scan(hist_g, hist_h, G, H, level_mask, n_bins: int,
                reg_lambda, alpha, gamma, min_child_weight
                ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Dispatching split scan (the entry ``models/trees.py`` calls)."""
-    L, nn, K, d, _B = hist_g.shape
+    L, nn, K, d, B = hist_g.shape
     mode0 = _dispatch.kernel_mode()
     lb = _resolve_lane_block(None, int(L), int(nn), int(K), int(d),
                              n_bins, mode0)
-    per_lane = int(hist_g.size // hist_g.shape[0]) * 8 * max(1, lb)
-    mode = _dispatch.split_mode(per_lane)
+    # one step's grad + hess blocks as tiled in VMEM: (nn, d) pads to
+    # (8, 128) multiples under the leading (lb, B, K) axes
+    tiled = max(1, lb) * B * K * (-(-nn // 8) * 8) * (-(-d // 128) * 128) * 4
+    mode = _dispatch.split_mode(2 * tiled)
     if mode is not None:
         return split_scan_pallas(
             hist_g, hist_h, G, H, level_mask, n_bins, reg_lambda, alpha,

@@ -330,49 +330,35 @@ def clear_program_cache() -> None:
 # Persistent compilation cache wiring
 # ---------------------------------------------------------------------------
 
-_PERSISTENT_DIR: Optional[str] = None
+#: where the cache goes when nothing outside says otherwise: ONE fixed path
+#: inside the checkout (never ``~``, a temp name, a pid or a time — every
+#: process of this checkout has to look in the same directory to find what
+#: an earlier one compiled)
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
 
-def enable_persistent_cache(cache_dir: Optional[str] = None,
-                            min_compile_secs: float = 1.0) -> Optional[str]:
-    """Point JAX's persistent compilation cache at a durable directory.
+def enable_persistent_cache() -> Optional[str]:
+    """Turn on JAX's persistent compilation cache; return its directory.
 
-    Honors ``TMOG_PERSISTENT_CACHE=0`` (disable) and ``TMOG_XLA_CACHE_DIR``
-    (location).  A cache dir the user already configured (via
-    ``jax.config.update`` or env) is RESPECTED, never overwritten — only a
-    completely unset config gets the library default.  An explicit
-    ``cache_dir`` argument always applies (callers opting in override the
-    earlier choice).  Entries cheaper than ``min_compile_secs`` stay
-    memory-only so the dir holds the expensive sweep programs, not thousands
-    of tiny kernels.  Returns the directory in use (None when disabled or
-    unsupported by the jax build).
+    The directory is placed from OUTSIDE: when ``JAX_COMPILATION_CACHE_DIR``
+    is set (or the caller already ran ``jax.config.update`` on
+    ``jax_compilation_cache_dir``) JAX holds it and this function sets no
+    other; only a completely unset config gets :data:`DEFAULT_CACHE_DIR`.
+    Every compile is persisted (minimum compile time 0 s, minimum entry
+    size 0), so a second process finds every program the first one built.
+    ``TMOG_PERSISTENT_CACHE=0`` leaves the cache off (returns None).
     """
-    global _PERSISTENT_DIR
     if os.environ.get("TMOG_PERSISTENT_CACHE", "1") == "0":
         return None
-    if _PERSISTENT_DIR is not None and cache_dir in (None, _PERSISTENT_DIR):
-        return _PERSISTENT_DIR
-    try:
-        import jax
+    import jax
 
-        current = getattr(jax.config, "jax_compilation_cache_dir", None)
-        if cache_dir is not None:
-            path = cache_dir
-        elif current:  # user (or a previous call) already picked a dir
-            _PERSISTENT_DIR = current
-            return current
-        else:
-            path = (os.environ.get("TMOG_XLA_CACHE_DIR")
-                    or os.path.expanduser("~/.cache/transmogrifai_tpu/xla"))
-        os.makedirs(path, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", path)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          float(min_compile_secs))
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    except Exception:  # pragma: no cover — older jax without the knobs
-        return None
-    _PERSISTENT_DIR = path
-    return path
+    if not jax.config.jax_compilation_cache_dir:
+        jax.config.update("jax_compilation_cache_dir", DEFAULT_CACHE_DIR)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    return jax.config.jax_compilation_cache_dir
 
 
 # ---------------------------------------------------------------------------

@@ -7,9 +7,10 @@ while a caller's ambient recorder captures the same spans), so the ONE real
 fit yields the per-phase breakdown that ``bench.py`` used to obtain by
 re-running the whole sweep ~2 extra times.
 
-Compile probe: ``jax.monitoring`` emits an event per backend compilation
+Compile probe: ``jax.monitoring`` emits an event per compile request
 (``/jax/core/compile/backend_compile_duration``) and per persistent-cache
-hit/miss.  A module-level listener accumulates them; ``measure_compiles``
+hit/miss.  A module-level listener accumulates them — a request the
+persistent cache answered counts as a hit, not a compile; ``measure_compiles``
 yields a live delta object, which is how tests assert "the second fit of the
 default sweep performs 0 new XLA compilations".
 """
@@ -212,11 +213,16 @@ _EV_CACHE_HIT = "/jax/compilation_cache/cache_hits"
 _EV_CACHE_MISS = "/jax/compilation_cache/cache_misses"
 
 
+#: per-thread "the compile request in flight was a persistent-cache hit"
+_TL = threading.local()
+
+
 def _on_event(name: str, **kw) -> None:
     with _LOCK:
         _GLOBAL.events[name] = _GLOBAL.events.get(name, 0) + 1
         if name == _EV_CACHE_HIT:
             _GLOBAL.persistent_cache_hits += 1
+            _TL.cache_hit = True
         elif name == _EV_CACHE_MISS:
             _GLOBAL.persistent_cache_misses += 1
 
@@ -225,8 +231,15 @@ def _on_duration(name: str, secs: float, **kw) -> None:
     with _LOCK:
         _GLOBAL.events[name] = _GLOBAL.events.get(name, 0) + 1
         if name == _EV_BACKEND_COMPILE:
-            _GLOBAL.backend_compiles += 1
-            _GLOBAL.compile_seconds += secs
+            # jax 0.9 times ``compile_or_get_cached`` as a whole, so this
+            # event also closes a request the persistent cache answered (its
+            # hit event lands first, on the same thread).  A load is not a
+            # compile: only real compilations count here.
+            if getattr(_TL, "cache_hit", False):
+                _TL.cache_hit = False
+            else:
+                _GLOBAL.backend_compiles += 1
+                _GLOBAL.compile_seconds += secs
         elif name in (_EV_TRACE, _EV_LOWER):
             _GLOBAL.trace_seconds += secs
 

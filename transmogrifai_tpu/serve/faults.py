@@ -151,13 +151,15 @@ def is_retryable(exc: BaseException) -> bool:
 
     Explicit :class:`TransientScoringError` is always retryable; anything the
     XLA runtime raises is sniffed for resource-exhaustion/unavailability
-    markers (jaxlib's ``XlaRuntimeError`` carries the gRPC-style status in
+    markers (``jax.errors.JaxRuntimeError`` carries the gRPC-style status in
     its message).  Everything else — type errors, value errors, poison
     payloads — is permanent: retrying cannot fix the input.
     """
     if isinstance(exc, TransientScoringError):
         return True
-    if type(exc).__name__ == "XlaRuntimeError":
+    from jax.errors import JaxRuntimeError
+
+    if isinstance(exc, JaxRuntimeError):
         msg = str(exc).lower()
         return any(m in msg for m in _RETRYABLE_MARKERS)
     return False

@@ -159,7 +159,8 @@ def chunked_transform_epoch(cds: ChunkedDataset, runners: Sequence[Any],
     from ..utils.listener import active_listeners
     from . import resilience
     from .plan import (check_plan_hbm_budget, fused_transforms_enabled,
-                       mesh_aligned_tile, plan_for, run_host_stages)
+                       mesh_aligned_tile, note_planner_fallback, plan_for,
+                       run_host_stages)
 
     runners = list(runners)
     if not runners:
@@ -177,8 +178,7 @@ def chunked_transform_epoch(cds: ChunkedDataset, runners: Sequence[Any],
         try:
             plan, remainder = plan_for(runners, frozenset(cds.names))
         except Exception as e:  # noqa: BLE001 — same fallback contract as fused_transform
-            log.warning("chunked epoch planning failed (%s: %s); running the "
-                        "per-stage host path per chunk", type(e).__name__, e)
+            note_planner_fallback("chunked epoch planning", e)
             plan, remainder = None, runners
 
     templates = _zero_row_templates(cds, runners)
@@ -274,9 +274,7 @@ def chunked_transform_epoch(cds: ChunkedDataset, runners: Sequence[Any],
                             # transient ≠ broken plan: retry the fused path
                             # instead of demoting the rest of the epoch
                             raise
-                        log.warning("chunked fused dispatch failed (%s: %s); "
-                                    "host path for the rest of the epoch",
-                                    type(e).__name__, e)
+                        note_planner_fallback("chunked fused dispatch", e)
                         plan = None
                         return _run_host_chunk(_chunk, runners)
                     if padded is not _chunk:

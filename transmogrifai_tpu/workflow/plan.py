@@ -121,6 +121,41 @@ def fused_transforms_enabled() -> bool:
     return os.environ.get("TMOG_FUSED_TRANSFORM", "1") != "0"
 
 
+#: planner failures that were rerouted to the per-stage host path, process-
+#: wide.  "Nothing fuses" / "listener active" returns are decisions, not
+#: failures, and are not counted.  chip_smoke.py asserts this stays 0 and
+#: ``Workflow.train(strict=True)`` raises when it moves — a device prefix
+#: that stopped compiling must not hide behind a warning (ROADMAP D7).
+_FALLBACKS = 0
+_FALLBACK_LOCK = threading.Lock()
+_LAST_FALLBACK: Optional[BaseException] = None
+
+
+def planner_fallbacks() -> int:
+    """How many plan-build / ``apply_prefix`` failures this process has
+    rerouted to the per-stage host path."""
+    with _FALLBACK_LOCK:
+        return _FALLBACKS
+
+
+def last_planner_fallback() -> Optional[BaseException]:
+    """The exception behind the most recent counted fallback."""
+    with _FALLBACK_LOCK:
+        return _LAST_FALLBACK
+
+
+def note_planner_fallback(what: str, exc: BaseException) -> None:
+    """Count + log one planner failure the caller is about to reroute."""
+    global _FALLBACKS, _LAST_FALLBACK
+    with _FALLBACK_LOCK:
+        _FALLBACKS += 1
+        _LAST_FALLBACK = exc
+        count = _FALLBACKS
+    log.warning("%s failed (%s: %s); falling back to the per-stage host "
+                "path [planner_fallbacks=%d]", what, type(exc).__name__, exc,
+                count, exc_info=exc)
+
+
 # ---------------------------------------------------------------------------
 # Shared partition primitives (serve/plan.py consumes these)
 # ---------------------------------------------------------------------------
@@ -808,8 +843,7 @@ def fused_transform(dataset: Dataset, runners: Sequence[Any],
         if plan is None:
             return None
     except Exception as e:  # noqa: BLE001 — transform must never get flakier
-        log.warning("fused transform planning failed (%s: %s); falling back "
-                    "to the per-stage path", type(e).__name__, e)
+        note_planner_fallback("fused transform planning", e)
         return None
     if hbm_budget is not None:
         # deliberately OUTSIDE the fallback guard: an OpCheckError here is an
@@ -818,8 +852,7 @@ def fused_transform(dataset: Dataset, runners: Sequence[Any],
     try:
         out = plan.apply_prefix(dataset)
     except Exception as e:  # noqa: BLE001 — transform must never get flakier
-        log.warning("fused transform plan failed (%s: %s); falling back to "
-                    "the per-stage path", type(e).__name__, e)
+        note_planner_fallback("fused transform plan", e)
         return None
     # the remainder runs the caller's CURRENT stage objects; its failures are
     # real transform failures and must propagate, not trigger a re-run
@@ -851,8 +884,7 @@ def fused_fold_transforms(dataset: Dataset, during: Sequence[Any],
         if plan0 is None:
             return None
     except Exception as e:  # noqa: BLE001
-        log.warning("fused fold transform planning failed (%s: %s); falling "
-                    "back to the per-fold host loop", type(e).__name__, e)
+        note_planner_fallback("fused fold transform planning", e)
         return None
     vmapped_ok = True
     if hbm_budget is not None:
@@ -888,8 +920,7 @@ def fused_fold_transforms(dataset: Dataset, during: Sequence[Any],
 
         if isinstance(e, OpCheckError):
             raise  # admission refusal, not a planner failure to retry
-        log.warning("fused fold transform failed (%s: %s); falling back to "
-                    "the per-fold host loop", type(e).__name__, e)
+        note_planner_fallback("fused fold transform", e)
         return None
     # host remainders run OUTSIDE the fallback guard: their failures are real
     # transform failures that must propagate, not planner failures to retry

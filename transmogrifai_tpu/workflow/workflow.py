@@ -157,7 +157,10 @@ class Workflow:
 
         ``strict=True`` runs the static validator first and raises
         :class:`OpCheckError` on any error-severity diagnostic, so a broken
-        DAG fails in milliseconds instead of minutes into a TPU job.
+        DAG fails in milliseconds instead of minutes into a TPU job.  It
+        also raises ``RuntimeError`` when a fused transform plan failed and
+        the fit was rerouted to the per-stage host path (otherwise a warning
+        plus the ``workflow.plan.planner_fallbacks()`` counter).
 
         ``hbm_budget`` (bytes) arms the TM601 admission gate on every fused
         transform plan the fit builds: before a fused prefix dispatches, its
@@ -251,12 +254,15 @@ class Workflow:
                host_budget: Optional[float] = None) -> "WorkflowModel":
         if not self.result_features:
             raise ValueError("set_result_features before train()")
+        from .plan import last_planner_fallback, planner_fallbacks
+
         if strict:
             report = self.validate()
             if report.errors():
                 from ..checkers.diagnostics import OpCheckError
 
                 raise OpCheckError(report)
+        fallbacks_before = planner_fallbacks()
         raw = self.generate_raw_data()
 
         blacklist: List[str] = []
@@ -413,6 +419,15 @@ class Workflow:
         # shares OpWorkflowCore state); override with set_reader for a scoring source
         if self._reader is not None:
             model.set_reader(self._reader)
+
+        if strict and planner_fallbacks() > fallbacks_before:
+            # the fit finished on the per-stage host path because a fused
+            # plan failed to build or dispatch — under strict that is an
+            # error, not a warning (workflow/plan.py note_planner_fallback)
+            raise RuntimeError(
+                "strict train: the fused transform planner fell back to the "
+                f"host path {planner_fallbacks() - fallbacks_before} time(s)"
+            ) from last_planner_fallback()
 
         # holdout evaluation on the test reserve (reference HasTestEval semantics)
         if test_ds is not None and test_ds.n_rows > 0:
