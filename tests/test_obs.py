@@ -818,6 +818,79 @@ class TestProfileHook:
         assert json.dumps(profiled, sort_keys=True) \
             == json.dumps(baseline, sort_keys=True)
 
+    def test_one_capture_holds_a_whole_selector_fit(self, tmp_path,
+                                                    monkeypatch):
+        """TMOG_PROFILE captures one whole ``ModelSelector.fit`` (the
+        per-dispatch hook in ``run_cached`` is gone): one ``.xplane.pb``
+        whose host plane holds the fit's spans, first phase to last."""
+        import glob
+        import inspect
+
+        import numpy as np
+        from jax.profiler import ProfileData
+
+        from transmogrifai_tpu import Dataset
+        from transmogrifai_tpu.data.dataset import Column
+        from transmogrifai_tpu.perf import programs
+        from transmogrifai_tpu.types import OPVector, RealNN
+
+        assert "TMOG_PROFILE" not in inspect.getsource(programs.run_cached)
+        assert "maybe_profile" not in inspect.getsource(programs)
+        rng = np.random.default_rng(3)
+        x = rng.normal(size=(256, 4)).astype(np.float32)
+        y = (rng.random(256) < 0.5).astype(np.float64)
+        ds = Dataset({"label": Column(RealNN, y, np.ones(256, np.bool_)),
+                      "v": Column.vector(x)})
+        label = FeatureBuilder.of("label", RealNN).extract_field() \
+            .as_response()
+        vec = FeatureBuilder.of("v", OPVector).extract_field().as_predictor()
+        sel = BinaryClassificationModelSelector.with_cross_validation(
+            num_folds=2,
+            models=[(LogisticRegression(), [{"reg_param": 0.01}])])
+        label.transform_with(sel, vec)
+        plain = sel.fit(ds)
+        prof = tmp_path / "fitprof"
+        monkeypatch.setenv("TMOG_PROFILE", str(prof))
+        profiled = sel.fit(ds)
+        monkeypatch.delenv("TMOG_PROFILE")
+        assert profiled.summary.to_dict()["validationResults"] \
+            == plain.summary.to_dict()["validationResults"]
+        (path,) = glob.glob(str(prof / "plugins" / "profile" / "*"
+                                / "*.xplane.pb"))
+        names = [ev.name for plane in ProfileData.from_file(path).planes
+                 if plane.name == "/host:CPU"
+                 for line in plane.lines for ev in line.events
+                 if dict(ev.stats).get("span") in ("phase", "activity")]
+        recorded = [s.path for s in sel.last_fit_profile.spans]
+        assert sorted(names) == sorted(recorded)
+        assert {"prep", "validate", "refit", "train_eval", "host.launch",
+                "host.device_wait"} <= set(names)
+
+    def test_a_train_and_its_selector_fit_share_one_capture(
+            self, base, tmp_path, monkeypatch):
+        """``Workflow.train`` opens the capture; the selector's own hook
+        finds it in flight and stays out (captures do not nest)."""
+        import glob
+
+        import pandas as pd
+
+        _, train, *_ = base
+        label = FeatureBuilder.RealNN("label").extract_field().as_response()
+        feats = [FeatureBuilder.Real(f"num{j}").extract_field()
+                 .as_predictor() for j in range(3)]
+        sel = BinaryClassificationModelSelector.with_train_validation_split(
+            models=[(LogisticRegression(), [{"reg_param": 0.01}])])
+        pred = label.transform_with(sel, transmogrify(feats))
+        prof = tmp_path / "trainprof"
+        monkeypatch.setenv("TMOG_PROFILE", str(prof))
+        (Workflow().set_result_features(label, pred)
+         .set_reader(DataReaders.Simple.dataframe(pd.DataFrame(train)))
+         ).train()
+        monkeypatch.delenv("TMOG_PROFILE")
+        assert len(glob.glob(str(prof / "plugins" / "profile" / "*"
+                                 / "*.xplane.pb"))) == 1
+        assert len(sel.last_fit_profile.spans) > 0
+
     def test_unset_env_is_noop(self, base, monkeypatch):
         monkeypatch.delenv("TMOG_PROFILE", raising=False)
         from transmogrifai_tpu.obs.profile import maybe_profile, profile_dir

@@ -24,11 +24,13 @@ from transmogrifai_tpu.models.trees import (
 )
 from transmogrifai_tpu.models.tuning import CrossValidator
 from transmogrifai_tpu.perf import (
+    activity,
     cache_key_fingerprint,
     compile_snapshot,
     measure_compiles,
     phase,
     program_cache_stats,
+    recent_fit_profiles,
     record_phases,
     run_cached,
 )
@@ -83,6 +85,290 @@ class TestPhaseTimers:
         assert [s.path for s in outer.spans] == ["p"]
         assert [s.path for s in inner.spans] == ["p"]
 
+
+
+def _tiny_selector(n=512, d=6, seed=0):
+    """(selector wired to a dataset, dataset): LR by IRLS and FISTA + SVC."""
+    from transmogrifai_tpu import Dataset, FeatureBuilder
+    from transmogrifai_tpu.data.dataset import Column
+    from transmogrifai_tpu.models.selector import (
+        BinaryClassificationModelSelector,
+    )
+    from transmogrifai_tpu.types import OPVector, RealNN
+
+    x, y = _binary(n=n, d=d, seed=seed)
+    ds = Dataset({
+        "label": Column(RealNN, y.astype(np.float64), np.ones(n, np.bool_)),
+        "v": Column.vector(x)})
+    label = FeatureBuilder.of("label", RealNN).extract_field().as_response()
+    vec = FeatureBuilder.of("v", OPVector).extract_field().as_predictor()
+    sel = BinaryClassificationModelSelector.with_cross_validation(
+        num_folds=2, models=[
+            (LogisticRegression(), [{"reg_param": 0.01},
+                                    {"reg_param": 0.1, "elastic_net": 0.5}]),
+            (LinearSVC(), [{"reg_param": 0.01}])])
+    label.transform_with(sel, vec)
+    return sel, ds
+
+
+class TestHostActivities:
+    """``activity()``: flat ``host.<name>`` spans from the same source as
+    ``phase()``, into the same sinks."""
+
+    def test_flat_path_with_parent_and_counts_under_any_stack(self):
+        with record_phases() as rec:
+            with activity("pad", nbytes=8):
+                pass
+            with phase("validate"):
+                with phase("cv.dispatch.Fam"):
+                    with activity("stamp", nbytes=3) as span:
+                        span.note(hit=True)
+                with activity("device_wait"):
+                    pass
+        got = {(s.path, s.parent): s for s in rec.spans}
+        assert set(got) == {("host.pad", ""),
+                            ("host.stamp", "validate.cv.dispatch.Fam"),
+                            ("host.device_wait", "validate"),
+                            ("validate.cv.dispatch.Fam", ""),
+                            ("validate", "")}
+        stamp = got[("host.stamp", "validate.cv.dispatch.Fam")]
+        assert stamp.name == "stamp"
+        assert stamp.counts == {"nbytes": 3, "hit": True}
+        assert got[("host.device_wait", "validate")].counts == {}
+        assert got[("validate", "")].counts is None
+        # an activity leaves the phase stack alone and stays out of the
+        # phases' paths: no path starts with or ends in a host segment
+        assert not [p for p, _ in got if ".host." in p or p.endswith(".host")]
+        rep = rec.report()
+        assert set(rep) == {"host.pad", "host.stamp", "host.device_wait",
+                            "validate", "validate.cv.dispatch.Fam"}
+
+    def test_noop_without_recorder_or_tracer(self):
+        with activity("nothing", nbytes=1) as span:
+            span.note(hit=False)       # must not raise nor record anywhere
+        assert span.token is None and span.counts == {"nbytes": 1}
+
+    def test_lands_in_every_recorder_and_in_the_obs_tracer(self):
+        from transmogrifai_tpu.obs import trace as obs_trace
+        from transmogrifai_tpu.obs.trace import Tracer
+
+        obs_trace.uninstall_tracer()
+        tracer = obs_trace.install_tracer(Tracer())
+        try:
+            with record_phases() as outer:
+                with phase("fit.sel"):
+                    with record_phases() as inner:
+                        with phase("refit"):
+                            with activity("launch", label="p"):
+                                pass
+        finally:
+            obs_trace.uninstall_tracer()
+        (a,) = [s for s in inner.spans if s.path == "host.launch"]
+        (b,) = [s for s in outer.spans if s.path == "host.launch"]
+        # the parent is relative to each recorder, as a phase's path is
+        assert (a.parent, b.parent) == ("refit", "fit.sel.refit")
+        assert a.counts == b.counts == {"label": "p"}
+        evs = {e["name"]: e for e in tracer.chrome_trace()["traceEvents"]
+               if e.get("ph") == "X"}
+        assert evs["host.launch"]["cat"] == "train"
+        assert evs["host.launch"]["args"] == {"parent": "fit.sel.refit",
+                                              "label": "p"}
+        assert "fit.sel.refit" in evs
+        # a tracer alone (no recorder) is a sink too
+        tracer = obs_trace.install_tracer(Tracer())
+        try:
+            with activity("pad", nbytes=2):
+                pass
+        finally:
+            obs_trace.uninstall_tracer()
+        assert [e["name"] for e in tracer.chrome_trace()["traceEvents"]
+                if e.get("ph") == "X"] == ["host.pad"]
+
+    def test_recorder_is_stamped_with_its_start_and_end(self):
+        t0 = time.perf_counter()
+        with record_phases() as rec:
+            with phase("p"):
+                pass
+        assert t0 <= rec.start <= rec.spans[0].start
+        assert rec.spans[0].start + rec.spans[0].seconds <= rec.end
+
+
+class TestFitProfiles:
+    def test_spans_land_in_the_profilers_trace_inside_their_parents(
+            self, tmp_path):
+        """A tiny fit under ``jax.profiler``: the phases and the host
+        activities are on the host plane, on the line of the thread that
+        ran the fit, each inside the interval of its parent."""
+        import glob
+
+        import jax
+        from jax.profiler import ProfileData
+
+        sel, ds = _tiny_selector()
+        sel.fit(ds)                                     # compiles
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+        try:
+            with jax.profiler.TraceAnnotation("the_fit"):
+                sel.fit(ds)
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                                / "*.xplane.pb"))
+        (plane,) = [p for p in ProfileData.from_file(path).planes
+                    if p.name == "/host:CPU"]
+        (line,) = [ln for ln in plane.lines
+                   if any(ev.name == "the_fit" for ev in ln.events)]
+        spans = [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns,
+                  dict(ev.stats).get("span")) for ev in line.events]
+        ours = [s for s in spans if s[3] in ("phase", "activity")]
+        names = {s[0] for s in ours}
+        assert {"prep", "validate", "refit", "train_eval",
+                "validate.cv.dispatch.LogisticRegression",
+                "validate.cv.gather.LinearSVC", "host.fold_weights",
+                "host.stamp", "host.pad", "host.launch", "host.program_key",
+                "host.device_wait"} <= names
+        assert {s[3] for s in ours if s[0].startswith("host.")} == {
+            "activity"}
+
+        # one source, two sinks: the trace's events are the recorder's spans,
+        # in the same order on the one thread
+        profile = sel.last_fit_profile
+        ours.sort(key=lambda s: (s[1], -s[2]))
+        recorded = sorted(profile.spans, key=lambda s: (s.start, -s.seconds))
+        assert [s[0] for s in ours] == [s.path for s in recorded]
+        (fit,) = [s for s in spans if s[0] == "the_fit"]
+        for event, span in zip(ours, recorded):
+            around = [p for p in ours if p is not event
+                      and p[1] <= event[1] and event[2] <= p[2]]
+            tightest = min(around, key=lambda p: p[2] - p[1]) if around \
+                else fit
+            assert tightest[1] <= event[1] and event[2] <= tightest[2]
+            if span.path.startswith("host."):
+                want = span.parent or "the_fit"
+            else:
+                want = "validate" if span.path.startswith("validate.") \
+                    else "the_fit"
+            assert tightest[0] == want, (span.path, span.parent)
+
+    def test_a_fit_keeps_to_200_spans_and_leaves_little_unspanned(self):
+        sel, ds = _tiny_selector(n=8192)
+        sel.fit(ds)
+
+        def unspanned_share(profile):
+            host = sorted((s.start, s.start + s.seconds)
+                          for s in profile.spans if s.path.startswith("host."))
+            covered, edge = 0.0, profile.start
+            for lo, hi in host:
+                covered += max(0.0, hi - max(lo, edge))
+                edge = max(edge, hi)
+            fit_s = profile.end - profile.start
+            return (fit_s - covered) / fit_s
+
+        shares = []
+        for _ in range(3):       # a host that is busy elsewhere slows the
+            sel.fit(ds)          # python between the spans: the best counts
+            shares.append(unspanned_share(sel.last_fit_profile))
+        profile = sel.last_fit_profile
+        assert len(profile.spans) <= 200
+        assert min(shares) <= 0.10, shares
+        # every path report() gave before the activities keeps its name
+        rep = profile.report()
+        assert {"prep", "validate", "refit", "train_eval",
+                "validate.cv.dispatch.LinearSVC",
+                "validate.cv.gather.LogisticRegression"} <= set(rep)
+        assert not [p for p in rep if p.startswith("host.")
+                    and p.count(".") != 1]
+
+    def test_recent_fit_profiles_is_bounded_and_ordered(self):
+        from transmogrifai_tpu.perf import timers
+
+        sel, ds = _tiny_selector(n=256)
+        sel.fit(ds)
+        first = sel.last_fit_profile
+        sel.fit(ds)
+        recent = recent_fit_profiles()
+        assert recent[-1] is sel.last_fit_profile and recent[-2] is first
+        assert recent[-2].end <= recent[-1].start
+        for _ in range(timers._RECENT_FITS.maxlen + 5):
+            timers.keep_fit_profile(first)
+        recent = recent_fit_profiles()
+        assert len(recent) == timers._RECENT_FITS.maxlen == 128
+        assert recent[-1] is first
+        # a copy: a reader cannot disturb the ring
+        recent.clear()
+        assert len(recent_fit_profiles()) == 128
+
+    def test_placement_stats_count_a_miss_then_a_hit(self):
+        from transmogrifai_tpu.parallel import mesh as M
+
+        rng = np.random.default_rng(int(time.time_ns()) % 2**32)
+        block = rng.normal(size=(300, 4)).astype(np.float32)
+        vec = rng.normal(size=300).astype(np.float32)
+        before = M.placement_stats()
+        with record_phases() as rec:
+            M.place_rows_bucketed_cached(block)
+            M.place_rows_bucketed_cached(block.copy())    # same content
+            M.place_cached(vec, (M.DATA_AXIS,))
+            M.place_cached(vec.copy(), (M.DATA_AXIS,))
+        after = M.placement_stats()
+        moved = {c: {k: after[c][k] - before[c][k] for k in after[c]}
+                 for c in after}
+        padded = M.bucket_size(300) * 4 * 4
+        assert moved == {
+            "rows": {"hits": 1, "misses": 1,
+                     "bytes_stamped": 2 * block.nbytes,
+                     "bytes_placed": padded},
+            "aux": {"hits": 1, "misses": 1, "bytes_stamped": 2 * vec.nbytes,
+                    "bytes_placed": vec.nbytes}}
+        # a miss records the host side of its transfer; a hit records none
+        paths = [s.path for s in rec.spans]
+        assert paths.count("host.h2d") == 2 and paths.count("host.stamp") == 4
+        assert paths.count("host.pad") == 1
+        assert [s.counts for s in rec.spans if s.path == "host.h2d"] == [
+            {"nbytes": padded}, {"nbytes": vec.nbytes}]
+        assert all(s.counts["hit"] is False for s in rec.spans
+                   if s.path == "host.stamp")
+
+
+@pytest.mark.parametrize("program,scopes", [
+    ("irls", ["irls_hessian", "irls_solve"]),
+    ("fista", ["fista_step"]),
+    ("svc", ["svc_step", "fold_standardize", "eval_sort"]),
+    ("eval", ["eval_sort"]),
+])
+def test_linear_programs_carry_stable_scope_names(program, scopes):
+    """Metadata only: the scope names are in the lowered programs' debug
+    info, for a reader of per-kernel time from a trace's op metadata."""
+    import jax.numpy as jnp
+
+    from transmogrifai_tpu.evaluators import metrics as M
+    from transmogrifai_tpu.models import base, logistic, svm
+
+    n, d, k, g = 64, 4, 2, 2
+    x = jnp.ones((n, d + 1), jnp.float32)
+    y = jnp.ones((n,), jnp.float32)
+    w = jnp.ones((k, n), jnp.float32)
+    regs = jnp.ones((g,), jnp.float32)
+    metric = M.METRICS_BINARY["auPR"]
+    if program == "irls":
+        lowered = logistic._irls_sweep.lower(x, y, w, regs, max_iter=2)
+    elif program == "fista":
+        lowered = logistic._fista_sweep.lower(x, y, w, regs, regs,
+                                              max_iter=2)
+    elif program == "svc":
+        lowered = svm._svc_cv_program.lower(
+            x[:, :d], y, y, w, w, regs, max_iter=2, has_intercept=True,
+            metric_fn=metric)
+    else:
+        lowered = base._eval_linear_sweep_for(None).lower(
+            x, y, jnp.ones((g, k, d + 1), jnp.float32), w, metric_fn=metric,
+            link="sigmoid")
+    text = lowered.as_text(debug_info=True)
+    for scope in scopes:
+        assert scope in text, scope
+    assert not any(scope in lowered.as_text() for scope in scopes)
 
 class TestCompileProbe:
     def test_counts_new_compilations_only(self):

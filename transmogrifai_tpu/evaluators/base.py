@@ -98,7 +98,12 @@ class BinaryClassificationEvaluator(Evaluator):
         1-D device arrays over the padded row block (padded rows carry zero
         weight).  Threshold curves need host arrays, so ``num_thresholds``
         falls back to ``evaluate_arrays``."""
-        vals = np.asarray(M.binary_summary(score_dev, pred_dev, y_dev, w_dev))
+        from ..perf.timers import activity
+
+        with activity("launch", label="BinaryClassificationEvaluator/summary"):
+            vals = M.binary_summary(score_dev, pred_dev, y_dev, w_dev)
+        with activity("device_wait"):
+            vals = np.asarray(vals)
         return dict(zip(("auROC", "auPR", "precision", "recall", "f1", "error",
                          "tp", "fp", "tn", "fn"), (float(v) for v in vals)))
 

@@ -41,25 +41,27 @@ def sweep_placements(x32: np.ndarray, extras, train_w, val_w):
     Returns (xd, [extra_devs...], tw_dev, vw_dev, n_valid).
     """
     from ..parallel.mesh import (
-        DATA_AXIS, pad_rows_bucketed_for_mesh, place_cached,
+        DATA_AXIS, pad_host, pad_rows_bucketed_for_mesh, place_cached,
         place_rows_bucketed_cached)
+    from ..perf.timers import activity
 
     xd, n0 = place_rows_bucketed_cached(x32)
     pad = int(xd.shape[0]) - n0
+
     # extras and fold weights are content-cached: families re-derive the same
     # padded labels/targets/weights per fit, and each repeat would be another
     # multi-MB host->device transfer ahead of the sweep dispatch
-    extra_devs = [
-        place_cached(pad_rows_bucketed_for_mesh(np.asarray(e), n=n0)[0],
-                     (DATA_AXIS,))
-        for e in extras
-    ]
+    extra_devs = []
+    for e in extras:
+        e = np.asarray(e)
+        with activity("pad", nbytes=int(e.nbytes)):
+            e = pad_rows_bucketed_for_mesh(e, n=n0)[0]
+        extra_devs.append(place_cached(e, (DATA_AXIS,)))
     # content-cached: every family pads the validator's identical fold
     # weights, so the (k, n) transfers happen once per fit, not per family
-    tw = place_cached(np.pad(np.asarray(train_w, np.float32),
-                             [(0, 0), (0, pad)]), (None, DATA_AXIS))
-    vw = place_cached(np.pad(np.asarray(val_w, np.float32),
-                             [(0, 0), (0, pad)]), (None, DATA_AXIS))
+    tw, vw = (place_cached(pad_host(np.asarray(w, np.float32),
+                                    [(0, 0), (0, pad)]), (None, DATA_AXIS))
+              for w in (train_w, val_w))
     return xd, extra_devs, tw, vw, n0
 
 
@@ -96,14 +98,16 @@ def gather_scores(pending) -> np.ndarray:
     the host, so an injected fault here models exactly that (the resilient
     sweep wrapper in models/tuning.py re-dispatches through its retry
     ladder)."""
+    from ..perf.timers import activity
     from ..serve.faults import fault_point
 
     fault_point("device_sync",
                 programs=len(pending)
                 if isinstance(pending, (list, tuple)) else 1)
-    if isinstance(pending, (list, tuple)):
-        return np.stack(jax.device_get(list(pending)))
-    return np.asarray(jax.device_get(pending))
+    with activity("device_wait"):
+        if isinstance(pending, (list, tuple)):
+            return np.stack(jax.device_get(list(pending)))
+        return np.asarray(jax.device_get(pending))
 
 
 @partial(jax.jit, static_argnames=("metric_fn",))
@@ -159,7 +163,8 @@ def _eval_linear_sweep_for(mesh):
         scores = jax.nn.sigmoid(margins) if link == "sigmoid" else margins
         scores, yr, vwr = rep(scores), rep(yd), rep(vw)
         per_fold = jax.vmap(lambda s, w_: metric_fn(s, yr, w_), in_axes=(0, 0))
-        return jax.vmap(lambda ps: per_fold(ps, vwr), in_axes=0)(scores)
+        with jax.named_scope("eval_sort"):
+            return jax.vmap(lambda ps: per_fold(ps, vwr), in_axes=0)(scores)
 
     return eval_linear_sweep
 

@@ -82,22 +82,25 @@ def _irls_core(x: jnp.ndarray, y: jnp.ndarray, w: jnp.ndarray, reg: jnp.ndarray,
         z = x @ beta
         p = jax.nn.sigmoid(z)
         g = x.T @ (w * (p - y)) / sw + reg * reg_mask * beta
-        s = jnp.maximum(w * p * (1.0 - p), 1e-10)
-        sx = xf * s[:, None]
-        hxx = jax.lax.dot_general(                      # (d, d) f32-accum
-            xf.T.astype(md), sx.astype(md), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        if has_intercept:
-            hxb = sx.sum(axis=0)                        # Xᵀ S 1 border
-            hbb = s.sum()[None]
-            h = jnp.concatenate([
-                jnp.concatenate([hxx, hxb[:, None]], axis=1),
-                jnp.concatenate([hxb, hbb])[None, :],
-            ], axis=0)
-        else:
-            h = hxx
-        h = h / sw + jnp.diag(reg * reg_mask + 1e-8)
-        return beta - jnp.linalg.solve(h, g)
+        # stable names in the ops' metadata, for per-kernel time from a trace
+        with jax.named_scope("irls_hessian"):
+            s = jnp.maximum(w * p * (1.0 - p), 1e-10)
+            sx = xf * s[:, None]
+            hxx = jax.lax.dot_general(                  # (d, d) f32-accum
+                xf.T.astype(md), sx.astype(md), (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            if has_intercept:
+                hxb = sx.sum(axis=0)                    # Xᵀ S 1 border
+                hbb = s.sum()[None]
+                h = jnp.concatenate([
+                    jnp.concatenate([hxx, hxb[:, None]], axis=1),
+                    jnp.concatenate([hxb, hbb])[None, :],
+                ], axis=0)
+            else:
+                h = hxx
+            h = h / sw + jnp.diag(reg * reg_mask + 1e-8)
+        with jax.named_scope("irls_solve"):
+            return beta - jnp.linalg.solve(h, g)
 
     beta0 = jnp.zeros(d1, dtype=x.dtype)
     return jax.lax.fori_loop(0, max_iter, step, beta0)
@@ -138,9 +141,10 @@ def _fista_elastic(x, y, w, l1, l2, max_iter, has_intercept: bool = True):
 
     def fista(carry, _):
         b, z, t = carry
-        b_new = soft(z - step * grad_smooth(z), step * l1 * pen_mask)
-        t_new = 0.5 * (1.0 + jnp.sqrt(1.0 + 4.0 * t * t))
-        z_new = b_new + ((t - 1.0) / t_new) * (b_new - b)
+        with jax.named_scope("fista_step"):
+            b_new = soft(z - step * grad_smooth(z), step * l1 * pen_mask)
+            t_new = 0.5 * (1.0 + jnp.sqrt(1.0 + 4.0 * t * t))
+            z_new = b_new + ((t - 1.0) / t_new) * (b_new - b)
         return (b_new, z_new, t_new), 0.0
 
     b0 = jnp.zeros(d1, x.dtype)
@@ -220,15 +224,15 @@ def place_fit_arrays(x, y, w):
     """(xd, yd, wd) for a final fit: raw block through the shared placement
     cache (a refit after CV hits the block the sweep already transferred),
     labels/weights zero-padded to match."""
-    from ..parallel.mesh import DATA_AXIS, place_cached, \
+    from ..parallel.mesh import DATA_AXIS, pad_host, place_cached, \
         place_rows_bucketed_cached
 
     x32 = np.asarray(x, np.float32)
     xd, n0 = place_rows_bucketed_cached(x32)
     pad = int(xd.shape[0]) - n0
-    yd = place_cached(np.pad(np.asarray(y, np.float32), (0, pad)),
+    yd = place_cached(pad_host(np.asarray(y, np.float32), (0, pad)),
                       (DATA_AXIS,))
-    wd = place_cached(np.pad(np.asarray(w, np.float32), (0, pad)),
+    wd = place_cached(pad_host(np.asarray(w, np.float32), (0, pad)),
                       (DATA_AXIS,))
     return xd, yd, wd
 
@@ -284,26 +288,32 @@ class LogisticRegression(PredictionEstimatorBase):
         return coef.astype(np.float64), intercept
 
     def _fit_arrays(self, x, y, w):
+        from ..perf.timers import activity
+
         xd, yd, wd = place_fit_arrays(x, y, w)
-        xs, mean_d, std_d = _device_prepare_fit(
-            xd, wd, has_intercept=bool(self.fit_intercept),
-            standardize=bool(self.standardize))
+        with activity("launch", label="LogisticRegression/prepare_fit"):
+            xs, mean_d, std_d = _device_prepare_fit(
+                xd, wd, has_intercept=bool(self.fit_intercept),
+                standardize=bool(self.standardize))
         l1 = float(self.reg_param) * float(self.elastic_net)
         if l1 > 0.0:
             # exact composite objective (Spark OWL-QN role): FISTA prox loop
             l2 = float(self.reg_param) * (1.0 - float(self.elastic_net))
-            beta = np.asarray(_fista_elastic(
-                xs, yd, wd,
-                jnp.float32(l1), jnp.float32(l2), max(10 * self.max_iter, 300),
-                has_intercept=bool(self.fit_intercept)))
+            with activity("launch", label="LogisticRegression/fista_refit"):
+                beta = _fista_elastic(
+                    xs, yd, wd, jnp.float32(l1), jnp.float32(l2),
+                    max(10 * self.max_iter, 300),
+                    has_intercept=bool(self.fit_intercept))
         else:
-            beta = np.asarray(_irls_core(
-                xs, yd, wd,
-                jnp.float32(self._effective_reg()), self.max_iter,
-                has_intercept=bool(self.fit_intercept),
-            ))
-        coef, intercept = self._finalize_beta(
-            beta, np.asarray(mean_d), np.asarray(std_d))
+            with activity("launch", label="LogisticRegression/irls_refit"):
+                beta = _irls_core(
+                    xs, yd, wd,
+                    jnp.float32(self._effective_reg()), self.max_iter,
+                    has_intercept=bool(self.fit_intercept),
+                )
+        with activity("device_wait"):
+            beta, mean, std = (np.asarray(a) for a in (beta, mean_d, std_d))
+        coef, intercept = self._finalize_beta(beta, mean, std)
         return LogisticRegressionModel(coef=coef, intercept=intercept)
 
     # --- device CV sweep ------------------------------------------------------
@@ -331,12 +341,15 @@ class LogisticRegression(PredictionEstimatorBase):
         # via sweep_placements); standardization runs on device.
         from .base import sweep_placements
 
+        from ..perf.timers import activity
+
         x32 = np.asarray(x, np.float32)
         xd_raw, (yd,), train_w, val_w, n0 = sweep_placements(
             x32, [np.asarray(y)], train_w, val_w)
-        xd = _device_prepare(xd_raw, jnp.int32(n0),
-                             has_intercept=bool(self.fit_intercept),
-                             standardize=bool(self.standardize))
+        with activity("launch", label="LogisticRegression/prepare"):
+            xd = _device_prepare(xd_raw, jnp.int32(n0),
+                                 has_intercept=bool(self.fit_intercept),
+                                 standardize=bool(self.standardize))
 
         k, d1 = train_w.shape[0], int(xd.shape[1])
         has_icpt = bool(self.fit_intercept)
@@ -362,9 +375,10 @@ class LogisticRegression(PredictionEstimatorBase):
                 statics=dict(max_iter=max(10 * int(self.max_iter), 300),
                              has_intercept=has_icpt),
                 label="LogisticRegression/fista_sweep")))
-        betas = jnp.zeros((len(grids), k, d1), dtype=jnp.float32)
-        for idx, b in parts:
-            betas = betas.at[jnp.asarray(idx)].set(b)
+        with activity("launch", label="LogisticRegression/gather_betas"):
+            betas = jnp.zeros((len(grids), k, d1), dtype=jnp.float32)
+            for idx, b in parts:
+                betas = betas.at[jnp.asarray(idx)].set(b)
 
         from .base import eval_linear_sweep_program
 
@@ -391,8 +405,11 @@ class LogisticRegressionModel(PredictionModelBase):
         from ..parallel.mesh import place_rows_bucketed_cached
         from .base import _linear_eval_payload
 
+        from ..perf.timers import activity
+
         xd, _ = place_rows_bucketed_cached(np.asarray(x32, np.float32),
                                            insert=False)
-        return _linear_eval_payload(
-            xd, jnp.asarray(self.coef, jnp.float32),
-            jnp.float32(self.intercept), link="sigmoid")
+        with activity("launch", label="LogisticRegression/eval_payload"):
+            return _linear_eval_payload(
+                xd, jnp.asarray(self.coef, jnp.float32),
+                jnp.float32(self.intercept), link="sigmoid")

@@ -127,17 +127,23 @@ class ModelSelector(PredictionEstimatorBase):
         self.train_evaluators = list(train_evaluators)
 
     def fit_columns(self, cols, dataset):
-        from ..perf.timers import PhaseRecorder, phase, record_phases
+        from ..obs.profile import maybe_profile
+        from ..perf.timers import (
+            PhaseRecorder, keep_fit_profile, phase, record_phases)
 
-        # every fit records its own phase profile (a few dozen spans — cheap);
-        # ``last_fit_profile`` is how bench.py reports the per-phase breakdown
-        # of the ONE real fit instead of re-running the sweep in isolation.
+        # every fit records its own phase profile (about a hundred spans —
+        # cheap); ``last_fit_profile`` is how bench.py reports the per-phase
+        # breakdown of the ONE real fit instead of re-running the sweep in
+        # isolation, and the process-wide ring (``recent_fit_profiles``)
+        # keeps the last fits' whole spans for readers that come later.
         # record_phases nests: an ambient recorder (workflow fit) sees the
-        # same spans.
+        # same spans.  TMOG_PROFILE captures the whole fit, from here to
+        # after its last blocking fetch, spans and device programs together.
         profile = PhaseRecorder()
-        with record_phases(profile):
+        with maybe_profile("fit"), record_phases(profile):
             fitted = self._fit_columns_profiled(cols, dataset, phase)
         self.last_fit_profile = profile
+        keep_fit_profile(profile)
         return fitted
 
     def _fit_columns_profiled(self, cols, dataset, phase):
@@ -203,15 +209,15 @@ class ModelSelector(PredictionEstimatorBase):
         def evaluate(ev, w: Optional[np.ndarray]) -> Dict[str, float]:
             if payload is not None and hasattr(ev, "evaluate_device") \
                     and getattr(ev, "num_thresholds", 0) == 0:
-                from ..parallel.mesh import DATA_AXIS, place_cached
+                from ..parallel.mesh import DATA_AXIS, pad_host, place_cached
 
                 # pad labels/weights to the PAYLOAD's row count (bucket+mesh
                 # padding of the shared placement); padded rows get w=0
                 n_pad = int(payload[0].shape[0]) - len(y)
                 w_full = np.ones_like(y) if w is None else \
                     np.asarray(w, np.float32)
-                y_p = np.pad(np.asarray(y, np.float32), (0, n_pad))
-                w_p = np.pad(w_full, (0, n_pad))
+                y_p = pad_host(np.asarray(y, np.float32), (0, n_pad))
+                w_p = pad_host(w_full, (0, n_pad))
                 return ev.evaluate_device(
                     payload[0], payload[1],
                     place_cached(y_p, (DATA_AXIS,)),

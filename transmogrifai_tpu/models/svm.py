@@ -40,12 +40,14 @@ def _svc_body(x: jnp.ndarray, y_pm: jnp.ndarray, w: jnp.ndarray, reg: jnp.ndarra
 
     def step(_, state):
         beta, vel = state
-        z = x @ beta
-        margin = 1.0 - y_pm * z
-        active = jnp.maximum(margin, 0.0)
-        g = x.T @ (w * (-2.0 * y_pm * active)) / sw + reg * reg_mask * beta
-        vel_new = 0.9 * vel - lr * g
-        return beta + vel_new, vel_new
+        with jax.named_scope("svc_step"):
+            z = x @ beta
+            margin = 1.0 - y_pm * z
+            active = jnp.maximum(margin, 0.0)
+            g = x.T @ (w * (-2.0 * y_pm * active)) / sw \
+                + reg * reg_mask * beta
+            vel_new = 0.9 * vel - lr * g
+            return beta + vel_new, vel_new
 
     beta0 = jnp.zeros(d1, dtype=x.dtype)
     beta, _ = jax.lax.fori_loop(0, max_iter, step, (beta0, beta0))
@@ -77,17 +79,20 @@ def _svc_cv_program(x, y, y_pm, train_w, val_w, regs, max_iter: int,
     val_w = constrain_fold_rows(val_w)
 
     def one_fold(w, vw):
-        sw = jnp.maximum(w.sum(), 1e-12)
-        mean = (w[:, None] * x).sum(0) / sw
-        var = (w[:, None] * (x - mean) ** 2).sum(0) / sw
-        std = jnp.where(var > 0, jnp.sqrt(var), 1.0)
-        xs = (x - mean) / std
-        if has_intercept:
-            xs = jnp.concatenate([xs, jnp.ones((x.shape[0], 1), x.dtype)], 1)
+        with jax.named_scope("fold_standardize"):
+            sw = jnp.maximum(w.sum(), 1e-12)
+            mean = (w[:, None] * x).sum(0) / sw
+            var = (w[:, None] * (x - mean) ** 2).sum(0) / sw
+            std = jnp.where(var > 0, jnp.sqrt(var), 1.0)
+            xs = (x - mean) / std
+            if has_intercept:
+                xs = jnp.concatenate(
+                    [xs, jnp.ones((x.shape[0], 1), x.dtype)], 1)
 
         def one_grid(reg):
             beta = _svc_body(xs, y_pm, w, reg, max_iter, has_intercept)
-            return metric_fn(xs @ beta, y, vw)
+            with jax.named_scope("eval_sort"):
+                return metric_fn(xs @ beta, y, vw)
 
         return jax.vmap(one_grid)(regs)
 
@@ -105,16 +110,21 @@ class LinearSVC(PredictionEstimatorBase):
     sweepable_params = ("reg_param",)
 
     def _fit_arrays(self, x, y, w):
+        from ..perf.timers import activity
+
         xd, yd, wd = place_fit_arrays(x, y, w)
-        xs, mean_d, std_d = _device_prepare_fit(
-            xd, wd, has_intercept=bool(self.fit_intercept),
-            standardize=bool(self.standardize))
-        y_pm = jnp.where(yd > 0.5, 1.0, -1.0).astype(jnp.float32)
-        beta = np.asarray(_svc_core(
-            xs, y_pm, wd,
-            jnp.float32(self.reg_param), int(self.max_iter),
-            has_intercept=bool(self.fit_intercept)))
-        mean, std = np.asarray(mean_d), np.asarray(std_d)
+        with activity("launch", label="LinearSVC/prepare_fit"):
+            xs, mean_d, std_d = _device_prepare_fit(
+                xd, wd, has_intercept=bool(self.fit_intercept),
+                standardize=bool(self.standardize))
+            y_pm = jnp.where(yd > 0.5, 1.0, -1.0).astype(jnp.float32)
+        with activity("launch", label="LinearSVC/svc_refit"):
+            beta = _svc_core(
+                xs, y_pm, wd,
+                jnp.float32(self.reg_param), int(self.max_iter),
+                has_intercept=bool(self.fit_intercept))
+        with activity("device_wait"):
+            beta, mean, std = (np.asarray(a) for a in (beta, mean_d, std_d))
         if self.fit_intercept:
             coef_s, b0 = beta[:-1], beta[-1]
         else:
@@ -139,9 +149,14 @@ class LinearSVC(PredictionEstimatorBase):
         regs = place_grid(np.asarray(
             [float(g.get("reg_param", self.reg_param)) for g in grids],
             dtype=np.float32))
+        from ..perf.timers import activity
+
         x32 = np.asarray(x, np.float32)
         y32 = np.asarray(y, np.float32)
-        y_pm = np.where(y32 > 0.5, 1.0, -1.0).astype(np.float32)
+        # two passes over the labels and a float64 temporary: 59 ms of the
+        # svc fit's first idle gap at 4M rows (PERF.md §5)
+        with activity("targets", nbytes=int(y32.nbytes)):
+            y_pm = np.where(y32 > 0.5, 1.0, -1.0).astype(np.float32)
         xd, (yd, ypmd), tw, vw, _ = sweep_placements(
             x32, [y32, y_pm], train_w, val_w)
         from ..perf.programs import run_cached
@@ -170,8 +185,11 @@ class LinearSVCModel(PredictionModelBase):
         from ..parallel.mesh import place_rows_bucketed_cached
         from .base import _linear_eval_payload
 
+        from ..perf.timers import activity
+
         xd, _ = place_rows_bucketed_cached(np.asarray(x32, np.float32),
                                            insert=False)
-        return _linear_eval_payload(
-            xd, jnp.asarray(self.coef, jnp.float32),
-            jnp.float32(self.intercept), link="identity")
+        with activity("launch", label="LinearSVC/eval_payload"):
+            return _linear_eval_payload(
+                xd, jnp.asarray(self.coef, jnp.float32),
+                jnp.float32(self.intercept), link="identity")

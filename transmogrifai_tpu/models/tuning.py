@@ -222,8 +222,11 @@ class CrossValidator:
         y: np.ndarray,
         base_w: Optional[np.ndarray] = None,
     ) -> ValidationResult:
+        from ..perf.timers import activity, phase
+
         base_w = np.ones_like(y, dtype=np.float32) if base_w is None else base_w
-        train_w, val_w = self.fold_weights(y, base_w)
+        with activity("fold_weights"):
+            train_w, val_w = self.fold_weights(y, base_w)
         metric_fn = self.evaluator.metric_fn()
         # NOTE: x is passed through at the caller's dtype — device families
         # cast to float32 themselves and their copies share the placement via
@@ -247,7 +250,6 @@ class CrossValidator:
         import logging
 
         from ..parallel.mesh import current_mesh, mesh_token
-        from ..perf.timers import phase
         from ..serve.faults import fault_point
         from ..workflow import resilience
 
@@ -358,6 +360,12 @@ class CrossValidator:
                     metric_values=[float(v) for v in scores[gi]],
                 ))
         best = self._best_index(evaluations)
+        # the two (k, n) fold weight blocks and the pending sweeps die with
+        # this frame, while the device waits for the refit: 4-10 ms of
+        # unmapping at 4M rows that no python call shows (PERF.md §5), so
+        # let go of them here, where a span can name it
+        with activity("release", nbytes=int(train_w.nbytes + val_w.nbytes)):
+            del train_w, val_w, dispatched
         return ValidationResult(evaluations, best, failed_models)
 
     def _resilient_sweep(self, est, grids, name, x, y, train_w, val_w,

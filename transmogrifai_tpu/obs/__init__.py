@@ -18,8 +18,8 @@ per-subsystem ad-hoc dicts:
   events (backend compiles tagged with plan fingerprints — an unexpected
   warm-path compile raises TM901 — breaker transitions, swap/rollback,
   drift firings, quarantines, injected faults) dumpable to JSON.
-- ``obs.profile`` — the ``TMOG_PROFILE`` jax.profiler hook around fused
-  dispatch.
+- ``obs.profile`` — the ``TMOG_PROFILE`` jax.profiler hook around a whole
+  fit or the serve dispatch.
 - ``obs.reqtrace`` — request-scoped causal tracing (ISSUE 14): per-request
   async tracks linked to their flushed batch, plus the always-on
   per-tenant device-time cost accounting backbone.

@@ -65,6 +65,15 @@ def pad_axis(arr: np.ndarray, axis: int, multiple: int) -> Tuple[np.ndarray, int
     return np.pad(arr, pad_width), n
 
 
+def pad_host(arr: np.ndarray, pad_width) -> np.ndarray:
+    """``np.pad`` with zeros as one ``host.pad`` activity: the padded host
+    copy a placement key is stamped from (a copy even where the width is 0)."""
+    from ..perf.timers import activity
+
+    with activity("pad", nbytes=int(arr.nbytes)):
+        return np.pad(arr, pad_width)
+
+
 def pad_rows(arr: np.ndarray, multiple: int) -> Tuple[np.ndarray, int]:
     """Pad rows to a multiple; returns (padded, n_valid)."""
     return pad_axis(arr, 0, multiple)
@@ -289,7 +298,7 @@ def pad_rows_bucketed_for_mesh(*arrays, n: Optional[int] = None):
 # family that re-materialises an identical float32 copy still hits.  An
 # in-place mutation of a DIFFERENT object with equal old content misses as
 # soon as bytes change; mutating the one memoized source object in place can
-# serve a stale stamp until the memo rolls over (_content_stamp docstring) —
+# serve a stale stamp until the memo rolls over (_stamp_or_memo docstring) —
 # placement sources are frozen by convention.  Bounded strong-ref FIFO: entries survive their
 # source array (a family's temporary copy dying must not evict the shared
 # transfer) but old blocks roll off so device memory stays bounded.
@@ -303,6 +312,29 @@ _PLACED_ROWS_CACHE_MAX = 3
 import threading as _threading
 
 _PLACEMENT_LOCK = _threading.RLock()
+
+#: process-wide cumulative counts of the two placement caches (the twin of
+#: ``perf.programs.program_cache_stats``); ``bytes_stamped`` counts the bytes
+#: hashed in full for the cache's keys — a stamp-memo hit hashes none
+_PLACEMENT_STATS = {
+    cache: {"hits": 0, "misses": 0, "bytes_stamped": 0, "bytes_placed": 0}
+    for cache in ("rows", "aux")}
+
+
+def placement_stats() -> dict:
+    """``{"rows": {...}, "aux": {...}}``: hits, misses, bytes stamped and
+    bytes placed of ``place_rows_bucketed_cached`` and ``place_cached`` since
+    the process started."""
+    with _PLACEMENT_LOCK:
+        return {cache: dict(counts)
+                for cache, counts in _PLACEMENT_STATS.items()}
+
+
+def _count_placement(cache: str, **moved) -> None:
+    with _PLACEMENT_LOCK:
+        counts = _PLACEMENT_STATS[cache]
+        for name, by in moved.items():
+            counts[name] += by
 
 
 _STAMP_MEMO: dict = {}
@@ -336,7 +368,24 @@ def _quick_sig(a: np.ndarray) -> bytes:
 
 
 def _content_stamp(a: np.ndarray) -> bytes:
-    """Full-buffer blake2b-128 content fingerprint (zero-copy via memoryview).
+    """Full-buffer blake2b-128 content fingerprint (zero-copy via memoryview);
+    see :func:`_stamp_bytes`, which also says how many bytes it hashed."""
+    return _stamp_bytes(a)[0]
+
+
+def _stamp_bytes(a: np.ndarray) -> Tuple[bytes, int]:
+    """(full-buffer blake2b-128 content fingerprint, bytes hashed in full for
+    it — 0 on a memo hit).  One ``host.stamp`` activity either way."""
+    from ..perf.timers import activity
+
+    with activity("stamp", nbytes=int(a.nbytes)) as span:
+        stamp, hit = _stamp_or_memo(a)
+        span.note(hit=hit)
+    return stamp, 0 if hit else int(a.nbytes)
+
+
+def _stamp_or_memo(a: np.ndarray) -> Tuple[bytes, bool]:
+    """(stamp, answered by the memo) — zero-copy via memoryview.
 
     Negligible next to the multi-second transfer it deduplicates; unlike a
     sampled checksum it covers every byte, and at 128 bits the collision
@@ -381,7 +430,7 @@ def _content_stamp(a: np.ndarray) -> bytes:
         if hit is not None and hit[0]() is a and frozen_ok \
                 and hit[1] == (a.shape, a.dtype.str) \
                 and hit[2] == _quick_sig(a):
-            return hit[3]
+            return hit[3], True
     raw = a if contiguous else np.ascontiguousarray(a)
     stamp = hashlib.blake2b(memoryview(raw).cast("B"),
                             digest_size=16).digest()
@@ -406,7 +455,7 @@ def _content_stamp(a: np.ndarray) -> bytes:
                 _STAMP_MEMO.pop(k)  # prune entries whose array died
             while len(_STAMP_MEMO) > _STAMP_MEMO_MAX:
                 _evict_stamp(next(iter(_STAMP_MEMO)))
-    return stamp
+    return stamp, False
 
 
 def _evict_stamp(key) -> None:
@@ -434,15 +483,23 @@ def place_cached(arr: np.ndarray, axes: tuple,
     and without the cache each family pays its own ~24 MB host->device
     transfer.  Keyed on (shape, dtype, blake2b, axes, mesh); bounded FIFO
     shared with the row cache budget."""
+    from ..perf.timers import activity
+
     mesh = mesh if mesh is not None else current_mesh()
     arr = np.asarray(arr)
-    key = (arr.shape, str(arr.dtype), _content_stamp(arr), tuple(axes), mesh)
+    stamp, hashed = _stamp_bytes(arr)
+    key = (arr.shape, str(arr.dtype), stamp, tuple(axes), mesh)
     with _PLACEMENT_LOCK:
         hit = _PLACED_AUX_CACHE.pop(key, None)
         if hit is not None:
             _PLACED_AUX_CACHE[key] = hit  # LRU: a hit re-inserts at the back
+            _count_placement("aux", hits=1, bytes_stamped=hashed)
             return hit
-    placed = place(arr, tuple(axes), mesh=mesh)
+    # host side of the transfer only: device_put returns before the copy ends
+    with activity("h2d", nbytes=int(arr.nbytes)):
+        placed = place(arr, tuple(axes), mesh=mesh)
+    _count_placement("aux", misses=1, bytes_stamped=hashed,
+                     bytes_placed=int(arr.nbytes))
     with _PLACEMENT_LOCK:
         _PLACED_AUX_CACHE[key] = placed
         while len(_PLACED_AUX_CACHE) > _PLACED_AUX_CACHE_MAX:
@@ -466,18 +523,26 @@ def place_rows_bucketed_cached(arr: np.ndarray,
     miss places without inserting — chunked scoring of a large table must
     not churn distinct per-chunk entries through the small FIFO and evict
     the fit block it exists to protect."""
+    from ..perf.timers import activity
+
     mesh = mesh if mesh is not None else current_mesh()
     arr = np.asarray(arr)
+    stamp, hashed = _stamp_bytes(arr)
     # key on the Mesh OBJECT (hashable), not id(mesh): a recycled id after GC
     # could otherwise serve arrays sharded under a dead mesh (r3 advisor)
-    key = (arr.shape, str(arr.dtype), _content_stamp(arr), mesh)
+    key = (arr.shape, str(arr.dtype), stamp, mesh)
     with _PLACEMENT_LOCK:
         hit = _PLACED_ROWS_CACHE.pop(key, None)
         if hit is not None:
             _PLACED_ROWS_CACHE[key] = hit  # LRU: a hit re-inserts at the back
+            _count_placement("rows", hits=1, bytes_stamped=hashed)
             return hit
-    padded, n_valid = pad_rows_bucketed_for_mesh(arr)[0], arr.shape[0]
-    placed = place_rows(padded, mesh)
+    with activity("pad", nbytes=int(arr.nbytes)):
+        padded, n_valid = pad_rows_bucketed_for_mesh(arr)[0], arr.shape[0]
+    with activity("h2d", nbytes=int(padded.nbytes)):
+        placed = place_rows(padded, mesh)
+    _count_placement("rows", misses=1, bytes_stamped=hashed,
+                     bytes_placed=int(padded.nbytes))
     if insert:
         with _PLACEMENT_LOCK:
             _PLACED_ROWS_CACHE[key] = (placed, n_valid)

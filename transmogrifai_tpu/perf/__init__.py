@@ -2,7 +2,8 @@
 
 Two pillars:
 
-- ``perf.timers``: nestable phase timers (``record_phases`` / ``phase``) plus
+- ``perf.timers``: nestable phase timers (``record_phases`` / ``phase``), flat
+  host activities (``activity``), the ring of finished fit profiles, plus
   a process-wide XLA compile probe (``compile_snapshot`` /
   ``measure_compiles``) fed by ``jax.monitoring`` events — compiled-program
   count and compile-seconds become first-class, measurable resources.
@@ -18,12 +19,14 @@ Importing this package wires the persistent cache unless
 """
 
 from .timers import (  # noqa: F401
+    activity,
     CompileStats,
     compile_snapshot,
     current_recorder,
     measure_compiles,
     phase,
     PhaseRecorder,
+    recent_fit_profiles,
     record_phases,
 )
 from .programs import (  # noqa: F401

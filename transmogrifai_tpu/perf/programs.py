@@ -40,8 +40,7 @@ from typing import Any, Dict, Optional, Tuple
 import numpy as np
 
 from ..obs import flight as obs_flight
-from ..obs.profile import maybe_profile
-from .timers import measure_compiles, phase
+from .timers import activity, measure_compiles, phase
 
 log = logging.getLogger(__name__)
 
@@ -222,7 +221,9 @@ def run_cached(fn, *args, kwargs: Optional[Dict[str, Any]] = None,
     """
     kwargs = kwargs or {}
     statics = statics or {}
-    key, fp, shapes = _make_key(fn, args, kwargs, statics, key_extras or {})
+    with activity("program_key"):
+        key, fp, shapes = _make_key(fn, args, kwargs, statics,
+                                    key_extras or {})
     with _LOCK:
         compiled = _CACHE.get(key)
         stats = _STATS.get(key)
@@ -260,7 +261,8 @@ def run_cached(fn, *args, kwargs: Optional[Dict[str, Any]] = None,
                 stats.compile_seconds += time.perf_counter() - t0
                 stats.backend_compiles += backend
                 try:
-                    out = compiled(*args, **kwargs)
+                    with activity("launch", label=stats.label):
+                        out = compiled(*args, **kwargs)
                 except TypeError as e:
                     # statics that are NOT static_argnames of fn end up in
                     # the compiled in_tree and the AOT call signature breaks;
@@ -277,7 +279,7 @@ def run_cached(fn, *args, kwargs: Optional[Dict[str, Any]] = None,
                 return out
     with _LOCK:
         stats.hits += 1
-    with maybe_profile("sweep"):  # TMOG_PROFILE hook; unset = one env read
+    with activity("launch", label=stats.label):
         return compiled(*args, **kwargs)
 
 

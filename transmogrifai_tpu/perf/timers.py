@@ -7,6 +7,14 @@ while a caller's ambient recorder captures the same spans), so the ONE real
 fit yields the per-phase breakdown that ``bench.py`` used to obtain by
 re-running the whole sweep ~2 extra times.
 
+One span source, three sinks, one clock: past its early-out a span also
+enters a ``jax.profiler.TraceAnnotation``, so whenever a profiler capture is
+running every span lies in the host plane of the same ``.xplane.pb`` as the
+device ops (outside a capture a ``TraceMe`` is a flag check).  The lower
+layers mark what the host is doing — padding, stamping, placing, launching,
+waiting — with ``activity("name")``: flat ``host.<name>`` spans that recur
+under every phase and are summed by activity (docs/observability.md).
+
 Compile probe: ``jax.monitoring`` emits an event per compile request
 (``/jax/core/compile/backend_compile_duration``) and per persistent-cache
 hit/miss.  A module-level listener accumulates them — a request the
@@ -21,8 +29,11 @@ import contextlib
 import contextvars
 import threading
 import time
+from collections import deque
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 from ..obs import trace as obs_trace
 
@@ -33,12 +44,19 @@ from ..obs import trace as obs_trace
 
 @dataclass
 class Span:
-    """One timed phase execution.  ``path`` is the dotted nesting path."""
+    """One timed phase execution.  ``path`` is the dotted nesting path.
+
+    An activity's ``path`` is the flat ``host.<name>``; its ``parent`` is the
+    dotted phase path that was open when it ran (relative to the recorder,
+    like ``path``) and ``counts`` what its site counted (``nbytes``,
+    ``hit``, ``label``).  Both stay empty on a phase."""
 
     name: str
     path: str
     start: float
     seconds: float
+    parent: str = ""
+    counts: Optional[Dict[str, Any]] = None
 
 
 class PhaseRecorder:
@@ -55,6 +73,9 @@ class PhaseRecorder:
         self.spans: List[Span] = []
         #: phase-stack depth when this recorder was activated
         self._base = 0
+        #: ``perf_counter`` at activation and deactivation (record_phases)
+        self.start: Optional[float] = None
+        self.end: Optional[float] = None
 
     def add(self, span: Span) -> None:
         self.spans.append(span)
@@ -98,20 +119,33 @@ def record_phases(recorder: Optional[PhaseRecorder] = None):
     rec = recorder if recorder is not None else PhaseRecorder()
     rec._base = len(_PHASE_STACK.get())
     token = _RECORDERS.set(_RECORDERS.get() + (rec,))
+    rec.start = time.perf_counter()
     try:
         yield rec
     finally:
+        rec.end = time.perf_counter()
         _RECORDERS.reset(token)
+
+
+#: an open activity's token (a phase's is its stack reset token)
+_FLAT = object()
 
 
 class _Phase:
     """Slotted class-based context manager (cheaper than a generator CM on
-    both the active and no-op paths — phases sit on hot per-batch loops)."""
+    both the active and no-op paths — phases sit on hot per-batch loops).
 
-    __slots__ = ("name", "recorders", "tracer", "token", "parts", "t0")
+    ``counts`` is None on a phase, which nests (its path is the stack of open
+    phases); a dict, possibly empty, on an activity, which is flat: it
+    leaves the stack alone and records as ``host.<name>`` with the open
+    phase path as its parent."""
 
-    def __init__(self, name: str):
+    __slots__ = ("name", "counts", "recorders", "tracer", "token", "parts",
+                 "t0", "annotation")
+
+    def __init__(self, name: str, counts: Optional[Dict[str, Any]] = None):
         self.name = name
+        self.counts = counts
 
     def __enter__(self) -> "_Phase":
         recorders = _RECORDERS.get()
@@ -122,24 +156,55 @@ class _Phase:
             self.token = None
             return self
         stack = _PHASE_STACK.get()
-        self.token = _PHASE_STACK.set(stack + (self.name,))
-        self.parts = stack + (self.name,)
+        # the profiler's clock: inside a capture the span lands on this
+        # thread's line of the host plane, with a ``span`` stat that tells
+        # it from the runtime's own events there; outside a capture this is
+        # a flag check
+        if self.counts is None:
+            self.parts = stack + (self.name,)
+            self.token = _PHASE_STACK.set(self.parts)
+            self.annotation = TraceAnnotation(".".join(self.parts),
+                                              span="phase")
+        else:
+            self.parts = stack
+            self.token = _FLAT
+            self.annotation = TraceAnnotation("host." + self.name,
+                                              span="activity")
+        self.annotation.__enter__()
         self.t0 = time.perf_counter()
         return self
+
+    def note(self, **counts) -> None:
+        """Add counts that are known only once the work is done (a memo hit);
+        nothing to do on the no-op path."""
+        if self.token is not None:
+            self.counts.update(counts)
 
     def __exit__(self, *exc) -> None:
         if self.token is None:
             return
         dt = time.perf_counter() - self.t0
-        _PHASE_STACK.reset(self.token)
+        self.annotation.__exit__(None, None, None)
+        if self.counts is None:
+            _PHASE_STACK.reset(self.token)
+            for rec in self.recorders:
+                rel = self.parts[rec._base:]  # path relative to recorder base
+                if rel:
+                    rec.add(Span(name=self.name, path=".".join(rel),
+                                 start=self.t0, seconds=dt))
+            if self.tracer is not None:
+                self.tracer.add_complete(".".join(self.parts), "train",
+                                         self.t0, dt, {})
+            return
+        path = "host." + self.name
         for rec in self.recorders:
-            rel = self.parts[rec._base:]  # path relative to recorder base
-            if rel:
-                rec.add(Span(name=self.name, path=".".join(rel),
-                             start=self.t0, seconds=dt))
+            rec.add(Span(name=self.name, path=path, start=self.t0, seconds=dt,
+                         parent=".".join(self.parts[rec._base:]),
+                         counts=self.counts))
         if self.tracer is not None:
-            self.tracer.add_complete(".".join(self.parts), "train",
-                                     self.t0, dt, {})
+            self.tracer.add_complete(
+                path, "train", self.t0, dt,
+                {"parent": ".".join(self.parts), **self.counts})
 
 
 def phase(name: str) -> _Phase:
@@ -148,8 +213,40 @@ def phase(name: str) -> _Phase:
     Phases nest: ``phase("fit")`` inside ``phase("validate")`` records as
     path ``validate.fit``.  When an ``obs`` tracer is installed
     (docs/observability.md), every phase additionally lands there as a
-    ``train``-category span under its full dotted path."""
+    ``train``-category span under its full dotted path, and inside a
+    ``jax.profiler`` capture as a host annotation of the same name."""
     return _Phase(name)
+
+
+def activity(name: str, **counts) -> _Phase:
+    """Time what the host is doing, across phases: same class, sinks and
+    early-out as :func:`phase`, but the span's path is the flat
+    ``host.<name>`` whatever the phase stack is, and it carries ``parent``
+    (the open phase path) and ``counts``.  Flat because padding, stamping,
+    placing, launching and waiting recur under ``cv.dispatch``, ``refit`` and
+    ``train_eval`` alike and are summed by activity.  Activities are leaves:
+    nothing nests under one."""
+    return _Phase(name, counts)
+
+
+#: the last finished fit profiles of this process, oldest first
+_RECENT_FITS: "deque[PhaseRecorder]" = deque(maxlen=128)
+_RECENT_FITS_LOCK = threading.Lock()
+
+
+def keep_fit_profile(profile: PhaseRecorder) -> None:
+    """Append one finished fit's recorder (``start``/``end`` stamped by
+    ``record_phases``) to the process-wide ring."""
+    with _RECENT_FITS_LOCK:
+        _RECENT_FITS.append(profile)
+
+
+def recent_fit_profiles() -> List[PhaseRecorder]:
+    """The last (at most 128) finished ``ModelSelector`` fit profiles, oldest
+    first: whole spans with their ``perf_counter`` times, for a reader that
+    is handed ``report()`` totals only."""
+    with _RECENT_FITS_LOCK:
+        return list(_RECENT_FITS)
 
 
 # ---------------------------------------------------------------------------

@@ -201,8 +201,12 @@ class Workflow:
         from contextlib import ExitStack
 
         from ..obs import resolve_telemetry
+        from ..obs.profile import maybe_profile
 
         with ExitStack() as stack:
+            # TMOG_PROFILE: one capture of the whole train (the selector's
+            # own hook finds it in flight and stays out)
+            stack.enter_context(maybe_profile("train"))
             if resume is not None:
                 from ..readers.streaming import OffsetCheckpoint
                 from .checkpoint import StageCheckpointer
