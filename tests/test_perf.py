@@ -321,7 +321,10 @@ class TestFitProfiles:
                      "bytes_stamped": 2 * block.nbytes,
                      "bytes_placed": padded},
             "aux": {"hits": 1, "misses": 1, "bytes_stamped": 2 * vec.nbytes,
-                    "bytes_placed": vec.nbytes}}
+                    "bytes_placed": vec.nbytes},
+            # neither call is a fit's: nothing passed through, none derived
+            "fit": {"passed_through": 0, "bytes_passed": 0, "derived": 0,
+                    "bytes_derived": 0}}
         # a miss records the host side of its transfer; a hit records none
         paths = [s.path for s in rec.spans]
         assert paths.count("host.h2d") == 2 and paths.count("host.stamp") == 4

@@ -35,34 +35,81 @@ def sweep_placements(x32: np.ndarray, extras, train_w, val_w):
 
     Places the raw feature block ONCE per selector fit (cached on the source
     array identity — every family receives the same object from the
-    validator), bucket/mesh-pads the row-aligned ``extras`` (labels, one-hots,
-    sign targets, ...), and pads+places the fold weight matrices.
+    validator), bucket/mesh-pads and places the row-aligned ``extras``
+    (labels, one-hots, ...) through ``place_fit_rows``, and takes the fold
+    weight matrices as they are when the validator derived them on the
+    device; host fold weights are padded and placed as before.
 
     Returns (xd, [extra_devs...], tw_dev, vw_dev, n_valid).
     """
     from ..parallel.mesh import (
-        DATA_AXIS, pad_host, pad_rows_bucketed_for_mesh, place_cached,
+        DATA_AXIS, pad_host, place_cached, place_fit_rows,
         place_rows_bucketed_cached)
-    from ..perf.timers import activity
 
     xd, n0 = place_rows_bucketed_cached(x32)
-    pad = int(xd.shape[0]) - n0
-
-    # extras and fold weights are content-cached: families re-derive the same
-    # padded labels/targets/weights per fit, and each repeat would be another
-    # multi-MB host->device transfer ahead of the sweep dispatch
-    extra_devs = []
-    for e in extras:
-        e = np.asarray(e)
-        with activity("pad", nbytes=int(e.nbytes)):
-            e = pad_rows_bucketed_for_mesh(e, n=n0)[0]
-        extra_devs.append(place_cached(e, (DATA_AXIS,)))
-    # content-cached: every family pads the validator's identical fold
-    # weights, so the (k, n) transfers happen once per fit, not per family
-    tw, vw = (place_cached(pad_host(np.asarray(w, np.float32),
-                                    [(0, 0), (0, pad)]), (None, DATA_AXIS))
-              for w in (train_w, val_w))
+    n_padded = int(xd.shape[0])
+    # inside a selector fit the labels are placed once and every family's
+    # request after the first is answered by identity; what a family derives
+    # afresh (one-hots) is content-cached like every host array
+    extra_devs = [place_fit_rows(e, n_padded) for e in extras]
+    if isinstance(train_w, jax.Array):
+        tw, vw = place_fit_rows(train_w, n_padded), \
+            place_fit_rows(val_w, n_padded)
+    else:
+        # content-cached: every family pads the caller's identical fold
+        # weights, so the (k, n) transfers happen once per fit, not per family
+        tw, vw = (place_cached(pad_host(np.asarray(w, np.float32),
+                                        [(0, 0), (0, n_padded - n0)]),
+                               (None, DATA_AXIS))
+                  for w in (train_w, val_w))
     return xd, extra_devs, tw, vw, n0
+
+
+def derive_on_device(fn, *args, axes, statics=None, label: str):
+    """What a fit computes on the device from inputs it has placed, in place
+    of a host array to build, pad, hash and place: one small program through
+    ``run_cached`` like every other, its outputs laid out as ``place(...,
+    axes)`` lays out a host array (to the letter: the layout is part of the
+    consuming programs' cache keys), counted in ``placement_stats()``."""
+    from ..parallel.mesh import count_derived, place
+    from ..perf.programs import run_cached
+
+    out = jax.tree.map(lambda o: place(o, axes),
+                       run_cached(fn, *args, statics=statics, label=label))
+    count_derived(*jax.tree.leaves(out))
+    return out
+
+
+@partial(jax.jit, static_argnames=("n_padded",))
+def _unit_weights(n_valid, n_padded: int):
+    """1 on the first ``n_valid`` rows of the padded block, 0 after: what
+    zero-padded host ones read."""
+    from ..parallel.mesh import constrain_rows, row_mask
+
+    return constrain_rows(row_mask(n_padded, n_valid))
+
+
+def unit_weights(n_valid: int, n_padded: int):
+    """Placed unit weights over the padded row block, made on the device."""
+    from ..parallel.mesh import DATA_AXIS
+
+    return derive_on_device(_unit_weights, jnp.int32(n_valid),
+                            axes=(DATA_AXIS,), statics=dict(n_padded=n_padded),
+                            label="ModelSelector/unit_weights")
+
+
+def host_fold_weights(train_w, val_w, n: int):
+    """The (k, n) numpy form of fold weights for a family with no device
+    path at these grids: host blocks as they are; for device blocks the host
+    form of the folds they were derived from, else a fetch cut to ``n``."""
+    if not isinstance(train_w, jax.Array):
+        return train_w, val_w
+    from .tuning import folds_of
+
+    folds = folds_of(train_w)
+    if folds is not None:
+        return folds.host()
+    return tuple(np.asarray(w)[:, :n] for w in (train_w, val_w))
 
 
 def place_spec(arr, axes):
@@ -305,7 +352,17 @@ class PredictionEstimatorBase(Estimator):
         pending = self._cv_sweep_device(x, y, train_w, val_w, grids, metric_fn)
         if pending is not None:
             return gather_scores(pending)
-        return self._cv_sweep_generic(x, y, train_w, val_w, grids, metric_fn)
+        return self._cv_sweep_generic(
+            x, y, *host_fold_weights(train_w, val_w, len(y)), grids, metric_fn)
+
+    def takes_device_folds(self) -> bool:
+        """Whether the validator may hand this estimator fold weights it
+        derived on the device: only the sweep protocol as this base class
+        runs it knows what to do with them."""
+        cls, base = type(self), PredictionEstimatorBase
+        return (cls.cv_sweep is base.cv_sweep
+                and cls.cv_sweep_async is base.cv_sweep_async
+                and cls._cv_sweep_device is not base._cv_sweep_device)
 
     def cv_sweep_async(self, x, y, train_w, val_w, grids, metric_fn):
         """Dispatch and return a zero-arg gather -> (g, k) metric ndarray.
@@ -321,7 +378,8 @@ class PredictionEstimatorBase(Estimator):
         pending = self._cv_sweep_device(x, y, train_w, val_w, grids, metric_fn)
         if pending is not None:
             return lambda: gather_scores(pending)
-        scores = self._cv_sweep_generic(x, y, train_w, val_w, grids, metric_fn)
+        scores = self._cv_sweep_generic(
+            x, y, *host_fold_weights(train_w, val_w, len(y)), grids, metric_fn)
         return lambda: scores
 
     def _cv_sweep_generic(self, x, y, train_w, val_w,

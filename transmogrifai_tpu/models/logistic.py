@@ -223,18 +223,15 @@ def _device_prepare_fit(x, w, has_intercept: bool, standardize: bool):
 def place_fit_arrays(x, y, w):
     """(xd, yd, wd) for a final fit: raw block through the shared placement
     cache (a refit after CV hits the block the sweep already transferred),
-    labels/weights zero-padded to match."""
-    from ..parallel.mesh import DATA_AXIS, pad_host, place_cached, \
-        place_rows_bucketed_cached
+    labels/weights zero-padded to match — through ``place_fit_rows``, so
+    inside a selector fit the handles the sweep placed come back as they are."""
+    from ..parallel.mesh import place_fit_rows, place_rows_bucketed_cached
 
     x32 = np.asarray(x, np.float32)
-    xd, n0 = place_rows_bucketed_cached(x32)
-    pad = int(xd.shape[0]) - n0
-    yd = place_cached(pad_host(np.asarray(y, np.float32), (0, pad)),
-                      (DATA_AXIS,))
-    wd = place_cached(pad_host(np.asarray(w, np.float32), (0, pad)),
-                      (DATA_AXIS,))
-    return xd, yd, wd
+    xd, _ = place_rows_bucketed_cached(x32)
+    n_padded = int(xd.shape[0])
+    return (xd, place_fit_rows(y, n_padded, np.float32),
+            place_fit_rows(w, n_padded, np.float32))
 
 
 @partial(jax.jit, static_argnames=("has_intercept", "standardize"))

@@ -128,6 +128,7 @@ class ModelSelector(PredictionEstimatorBase):
 
     def fit_columns(self, cols, dataset):
         from ..obs.profile import maybe_profile
+        from ..parallel.mesh import fit_placements
         from ..perf.timers import (
             PhaseRecorder, keep_fit_profile, phase, record_phases)
 
@@ -139,8 +140,11 @@ class ModelSelector(PredictionEstimatorBase):
         # record_phases nests: an ambient recorder (workflow fit) sees the
         # same spans.  TMOG_PROFILE captures the whole fit, from here to
         # after its last blocking fetch, spans and device programs together.
+        # the fit owns its row-aligned inputs (labels, base weights, fold
+        # ids): each is padded, stamped and placed once, and the sweep's
+        # extras, the refit and the evaluators all get the same handle
         profile = PhaseRecorder()
-        with maybe_profile("fit"), record_phases(profile):
+        with maybe_profile("fit"), record_phases(profile), fit_placements():
             fitted = self._fit_columns_profiled(cols, dataset, phase)
         self.last_fit_profile = profile
         keep_fit_profile(profile)
@@ -200,6 +204,7 @@ class ModelSelector(PredictionEstimatorBase):
         # catch-all here: it would reroute a broken device silently).
         payload = best_model.eval_payload_device(x)
         _pred_cache: List[Any] = []
+        _w_dev: Dict[int, Any] = {}
 
         def pred_col():
             if not _pred_cache:
@@ -209,19 +214,23 @@ class ModelSelector(PredictionEstimatorBase):
         def evaluate(ev, w: Optional[np.ndarray]) -> Dict[str, float]:
             if payload is not None and hasattr(ev, "evaluate_device") \
                     and getattr(ev, "num_thresholds", 0) == 0:
-                from ..parallel.mesh import DATA_AXIS, pad_host, place_cached
+                from ..parallel.mesh import place_fit_rows
+                from .base import unit_weights
 
-                # pad labels/weights to the PAYLOAD's row count (bucket+mesh
-                # padding of the shared placement); padded rows get w=0
-                n_pad = int(payload[0].shape[0]) - len(y)
-                w_full = np.ones_like(y) if w is None else \
-                    np.asarray(w, np.float32)
-                y_p = pad_host(np.asarray(y, np.float32), (0, n_pad))
-                w_p = pad_host(w_full, (0, n_pad))
+                # labels/weights over the PAYLOAD's row count (bucket+mesh
+                # padding of the shared placement); padded rows get w=0.  The
+                # labels are the handle the sweep and the refit used; the
+                # weights are placed once for every evaluator (``w`` outlives
+                # this fit's evaluations, so its id stands for it), and unit
+                # weights are made on the device
+                n_padded = int(payload[0].shape[0])
+                if id(w) not in _w_dev:
+                    _w_dev[id(w)] = unit_weights(len(y), n_padded) \
+                        if w is None else \
+                        place_fit_rows(w, n_padded, np.float32)
                 return ev.evaluate_device(
                     payload[0], payload[1],
-                    place_cached(y_p, (DATA_AXIS,)),
-                    place_cached(w_p, (DATA_AXIS,)))
+                    place_fit_rows(y, n_padded), _w_dev[id(w)])
             return ev.evaluate_arrays(y.astype(np.float64), pred_col(), w=w)
 
         train_eval: Dict[str, float] = {}

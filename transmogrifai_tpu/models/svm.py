@@ -58,6 +58,17 @@ _svc_core = partial(jax.jit,
                     static_argnames=("max_iter", "has_intercept"))(_svc_body)
 
 
+@jax.jit
+def _sign_targets(y, n_valid):
+    """±1 targets from 0/1 labels over the padded row block; 0 on the padded
+    rows, as a zero-padded host vector of them reads."""
+    from ..parallel.mesh import constrain_rows
+
+    y_pm = jnp.where(y > 0.5, 1.0, -1.0).astype(jnp.float32)
+    return constrain_rows(
+        jnp.where(jnp.arange(y.shape[0]) < n_valid, y_pm, 0.0))
+
+
 @partial(jax.jit, static_argnames=("max_iter", "has_intercept", "metric_fn"))
 def _svc_cv_program(x, y, y_pm, train_w, val_w, regs, max_iter: int,
                     has_intercept: bool, metric_fn):
@@ -144,23 +155,22 @@ class LinearSVC(PredictionEstimatorBase):
         if (not self.standardize
                 or any(set(g) - {"reg_param"} for g in grids)):
             return None
-        from .base import place_grid, sweep_placements
+        from .base import derive_on_device, place_grid, sweep_placements
 
         regs = place_grid(np.asarray(
             [float(g.get("reg_param", self.reg_param)) for g in grids],
             dtype=np.float32))
-        from ..perf.timers import activity
-
-        x32 = np.asarray(x, np.float32)
-        y32 = np.asarray(y, np.float32)
-        # two passes over the labels and a float64 temporary: 59 ms of the
-        # svc fit's first idle gap at 4M rows (PERF.md §5)
-        with activity("targets", nbytes=int(y32.nbytes)):
-            y_pm = np.where(y32 > 0.5, 1.0, -1.0).astype(np.float32)
-        xd, (yd, ypmd), tw, vw, _ = sweep_placements(
-            x32, [y32, y_pm], train_w, val_w)
+        from ..parallel.mesh import DATA_AXIS
         from ..perf.programs import run_cached
 
+        x32 = np.asarray(x, np.float32)
+        xd, (yd,), tw, vw, n0 = sweep_placements(
+            x32, [np.asarray(y, np.float32)], train_w, val_w)
+        # the ±1 targets are a function of the placed labels: made there, not
+        # as a second host vector to pad, hash and look up
+        ypmd = derive_on_device(_sign_targets, yd, jnp.int32(n0),
+                                axes=(DATA_AXIS,),
+                                label="LinearSVC/sign_targets")
         return run_cached(
             _svc_cv_program, xd, yd, ypmd, tw, vw, regs,
             statics=dict(max_iter=int(self.max_iter),

@@ -1087,6 +1087,17 @@ class ForestRegressorModel(_TreeEnsembleModelBase):
         return PredictionColumn.regression(self._margin(vec.data)[:, 0] / self.n_trees)
 
 
+def _weights_binary(train_w) -> bool:
+    """Every fold train weight is 0 or 1."""
+    if isinstance(train_w, jax.Array):
+        from .tuning import folds_of
+
+        folds = folds_of(train_w)
+        if folds is not None:
+            return folds.binary
+    return bool(np.all((train_w == 0.0) | (train_w == 1.0)))
+
+
 class _TreeEstimatorBase(PredictionEstimatorBase):
     max_depth = Param(default=5)
     n_bins = Param(default=DEFAULT_BINS)
@@ -1127,7 +1138,9 @@ class _TreeEstimatorBase(PredictionEstimatorBase):
         x32 = np.asarray(x, np.float32)
         # 0/1 fold weights (the unweighted/unbalanced case) let forests run
         # the EXACT int8 histogram path — verified host-side, decided per fit
-        int01 = bool(np.all((train_w == 0.0) | (train_w == 1.0)))
+        # (of device blocks the validator derived, the folds know: an (n,)
+        # test of the base weights)
+        int01 = _weights_binary(train_w)
         xd, _, tw, vw, n0 = sweep_placements(x32, [], train_w, val_w)
         binned, _ = _shared_binned(x32, xd, int(self.n_bins))
         pad = int(xd.shape[0]) - n0

@@ -12,9 +12,13 @@ pool, OpCrossValidation.scala:114-134).
 
 from __future__ import annotations
 
+import contextvars
 from dataclasses import dataclass, field
+from functools import partial
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from ..evaluators.base import Evaluator
@@ -176,6 +180,120 @@ class ValidationResult:
         return self.evaluations[self.best_index]
 
 
+@partial(jax.jit, static_argnames=("num_folds",))
+def _fold_weight_blocks(fold_id, base_w, num_folds: int):
+    """(train_w, val_w), each (k, n): a row's base weight is fold f's
+    validation weight where ``fold_id == f`` and its train weight elsewhere.
+    Padded rows carry base weight 0, so they read 0 in both, whatever their
+    id."""
+    from ..parallel.mesh import constrain_fold_rows
+
+    in_val = fold_id[None, :] == jnp.arange(
+        num_folds, dtype=fold_id.dtype)[:, None]
+    w = base_w[None, :]
+    return (constrain_fold_rows(jnp.where(in_val, 0.0, w)),
+            constrain_fold_rows(jnp.where(in_val, w, 0.0)))
+
+
+#: the fold weights of the ``validate`` call that is dispatching, if any
+_FOLDS: "contextvars.ContextVar[Optional[FoldWeights]]" = \
+    contextvars.ContextVar("transmogrifai_tpu_folds", default=None)
+
+
+def folds_of(train_w) -> Optional["FoldWeights"]:
+    """The ``FoldWeights`` a device ``train_w`` block was derived from by the
+    ``validate`` call now dispatching, else None: how a family handed device
+    blocks asks for what only the host knows (``binary``, ``host()``)."""
+    folds = _FOLDS.get()
+    return folds if folds is not None and folds.derived(train_w) else None
+
+
+class FoldWeights:
+    """One fold assignment's weights in two forms, each made on first use.
+
+    ``host()`` is the pair of (k, n) float32 numpy blocks.  ``device()`` is
+    the same bits as placed (k, n_padded) blocks, derived by one small
+    program from the placed ids and base weights: nothing of size (k, n) is
+    built, padded, hashed or copied on the host for it.  Weights a validator
+    built itself (``of_host``) have the host form only, and ``device()``
+    hands that out: a host array goes the way it always went."""
+
+    def __init__(self, fold_id: Optional[np.ndarray],
+                 base_w: Optional[np.ndarray], num_folds: int):
+        self.fold_id = fold_id
+        self.base_w = None if base_w is None else \
+            np.asarray(base_w, np.float32)
+        self.num_folds = num_folds
+        self._host = self._device = self._binary = self._token = None
+
+    @classmethod
+    def of_host(cls, train_w: np.ndarray, val_w: np.ndarray) -> "FoldWeights":
+        folds = cls(None, None, train_w.shape[0])
+        folds._host = train_w, val_w
+        return folds
+
+    @property
+    def sources(self) -> Tuple[np.ndarray, np.ndarray]:
+        """The host arrays both forms are functions of."""
+        return self._host if self.fold_id is None \
+            else (self.fold_id, self.base_w)
+
+    def __enter__(self) -> "FoldWeights":
+        self._token = _FOLDS.set(self)
+        return self
+
+    def __exit__(self, *exc) -> None:
+        _FOLDS.reset(self._token)
+
+    def host(self) -> Tuple[np.ndarray, np.ndarray]:
+        if self._host is None:
+            k, n = self.num_folds, len(self.fold_id)
+            train_w = np.zeros((k, n), dtype=np.float32)
+            val_w = np.zeros((k, n), dtype=np.float32)
+            for f in range(k):
+                in_val = self.fold_id == f
+                train_w[f] = np.where(in_val, 0.0, self.base_w)
+                val_w[f] = np.where(in_val, self.base_w, 0.0)
+            self._host = train_w, val_w
+        return self._host
+
+    def device(self):
+        if self.fold_id is None:
+            return self.host()
+        if self._device is None:
+            from ..parallel.mesh import (
+                DATA_AXIS, padded_row_count, place_fit_rows)
+            from .base import derive_on_device
+
+            n_padded = padded_row_count(len(self.fold_id))
+            self._device = derive_on_device(
+                _fold_weight_blocks, place_fit_rows(self.fold_id, n_padded),
+                place_fit_rows(self.base_w, n_padded),
+                axes=(None, DATA_AXIS),
+                statics=dict(num_folds=self.num_folds),
+                label="CrossValidator/fold_weights")
+        return self._device
+
+    def derived(self, train_w) -> bool:
+        return self._device is not None and train_w is self._device[0]
+
+    @property
+    def binary(self) -> bool:
+        """Every weight is 0 or 1, which for the blocks is to say every base
+        weight is: an (n,) test where the blocks would take a (k, n) one."""
+        if self._binary is None:
+            w = self.base_w if self.fold_id is not None else self._host[0]
+            self._binary = bool(np.all((w == 0.0) | (w == 1.0)))
+        return self._binary
+
+    @property
+    def host_nbytes(self) -> int:
+        return sum(int(w.nbytes) for w in self._host or ())
+
+    def release(self) -> None:
+        self._host = self._device = None
+
+
 class CrossValidator:
     """k-fold CV over (estimator, grid) pairs.
 
@@ -193,9 +311,9 @@ class CrossValidator:
         self.stratify = stratify
         self.parallelism = parallelism
 
-    def fold_weights(self, y: np.ndarray, base_w: np.ndarray
-                     ) -> Tuple[np.ndarray, np.ndarray]:
-        """(train_w, val_w) of shape (k, n) from fold assignment."""
+    def fold_ids(self, y: np.ndarray) -> np.ndarray:
+        """(n,) fold each row is validated in: the assignment, as small
+        integers (4 MB at 4M rows where the weights it implies are 96 MB)."""
         n = len(y)
         rng = np.random.default_rng(self.seed)
         if self.stratify:
@@ -205,15 +323,16 @@ class CrossValidator:
                 idx = rng.permutation(idx)
                 fold_id[idx] = np.arange(len(idx)) % self.num_folds
         else:
-            fold_id = rng.permutation(n) % self.num_folds
-        k = self.num_folds
-        train_w = np.zeros((k, n), dtype=np.float32)
-        val_w = np.zeros((k, n), dtype=np.float32)
-        for f in range(k):
-            in_val = fold_id == f
-            train_w[f] = np.where(in_val, 0.0, base_w)
-            val_w[f] = np.where(in_val, base_w, 0.0)
-        return train_w, val_w
+            fold_id = rng.permutation(n)
+            fold_id %= self.num_folds   # in place: no second (n,) int64
+        return fold_id.astype(np.int8 if self.num_folds <= 127 else np.int32)
+
+    def fold_weights(self, y: np.ndarray, base_w: np.ndarray
+                     ) -> Tuple[np.ndarray, np.ndarray]:
+        """(train_w, val_w) of shape (k, n) from fold assignment: the host
+        form, for callers with no device sweep (``validate`` derives the same
+        bits on the device from the same ids)."""
+        return FoldWeights(self.fold_ids(y), base_w, self.num_folds).host()
 
     def validate(
         self,
@@ -222,11 +341,26 @@ class CrossValidator:
         y: np.ndarray,
         base_w: Optional[np.ndarray] = None,
     ) -> ValidationResult:
-        from ..perf.timers import activity, phase
+        from ..perf.timers import activity
 
         base_w = np.ones_like(y, dtype=np.float32) if base_w is None else base_w
+        # the assignment is made here, on the host; the (k, n) weights it
+        # implies are derived on the device for the families that sweep there
+        # and built on the host only for those that do not.  A validator with
+        # a ``fold_weights`` of its own has made host blocks, and they are
+        # what every family gets.
         with activity("fold_weights"):
-            train_w, val_w = self.fold_weights(y, base_w)
+            if type(self).fold_weights is _STOCK_FOLD_WEIGHTS:
+                folds = FoldWeights(self.fold_ids(y), base_w, self.num_folds)
+            else:
+                folds = FoldWeights.of_host(*self.fold_weights(y, base_w))
+        with folds:     # what ``folds_of`` answers with while families dispatch
+            return self._validate_folds(models, x, y, folds)
+
+    def _validate_folds(self, models, x, y, folds: "FoldWeights"
+                        ) -> ValidationResult:
+        from ..perf.timers import activity, phase
+
         metric_fn = self.evaluator.metric_fn()
         # NOTE: x is passed through at the caller's dtype — device families
         # cast to float32 themselves and their copies share the placement via
@@ -256,7 +390,9 @@ class CrossValidator:
         log = logging.getLogger(__name__)
         res = resilience.active()
         journal = res.journal if res is not None else None
-        digest = resilience.data_digest(x, y, train_w, val_w) \
+        # the ids and the base weights identify the fold weights: both forms
+        # are functions of them
+        digest = resilience.data_digest(x, y, *folds.sources) \
             if journal is not None else None
         fold_spec = (self.num_folds, self.seed, self.stratify)
         ambient_dp = resilience.dp_size(current_mesh())
@@ -283,8 +419,9 @@ class CrossValidator:
                 with phase(f"cv.dispatch.{name}"):
                     fault_point("sweep_dispatch", family=name, rows=len(y),
                                 dp=ambient_dp, attempt=0)
-                    gather = est.cv_sweep_async(x, y, train_w, val_w, grids,
-                                                metric_fn)
+                    gather = est.cv_sweep_async(
+                        x, y, *(folds.device() if est.takes_device_folds()
+                                else folds.host()), grids, metric_fn)
             except Exception as e:  # robust to failing models (SURVEY §5.3)
                 if res is not None:
                     if not resilience.is_retryable_training(e):
@@ -332,7 +469,7 @@ class CrossValidator:
                     else:
                         n_deg = len(res.degradations)
                         scores = self._resilient_sweep(
-                            est, grids, name, x, y, train_w, val_w,
+                            est, grids, name, x, y, *folds.host(),
                             metric_fn, res, e)
                         if len(res.degradations) > n_deg:
                             # a block completed on a shrunk mesh / capped
@@ -360,12 +497,13 @@ class CrossValidator:
                     metric_values=[float(v) for v in scores[gi]],
                 ))
         best = self._best_index(evaluations)
-        # the two (k, n) fold weight blocks and the pending sweeps die with
-        # this frame, while the device waits for the refit: 4-10 ms of
-        # unmapping at 4M rows that no python call shows (PERF.md §5), so
-        # let go of them here, where a span can name it
-        with activity("release", nbytes=int(train_w.nbytes + val_w.nbytes)):
-            del train_w, val_w, dispatched
+        # the fold weights and the pending sweeps die with this frame, while
+        # the device waits for the refit; where a family made the host form
+        # that is 4-10 ms of unmapping at 4M rows that no python call shows
+        # (PERF.md §5), so let go of them here, where a span can name it
+        with activity("release", nbytes=folds.host_nbytes):
+            folds.release()
+            del dispatched
         return ValidationResult(evaluations, best, failed_models)
 
     def _resilient_sweep(self, est, grids, name, x, y, train_w, val_w,
@@ -416,6 +554,11 @@ class CrossValidator:
         return max(range(len(evaluations)), key=key)
 
 
+#: what ``validate`` knows the ids to stand for; any other ``fold_weights``
+#: (a subclass's, a test's) is asked for its host blocks
+_STOCK_FOLD_WEIGHTS = CrossValidator.fold_weights
+
+
 class TrainValidationSplit(CrossValidator):
     """Single split validator.  Reference: OpTrainValidationSplit.scala:35-130."""
 
@@ -424,10 +567,8 @@ class TrainValidationSplit(CrossValidator):
         super().__init__(evaluator, num_folds=1, seed=seed, stratify=stratify)
         self.train_ratio = train_ratio
 
-    def fold_weights(self, y, base_w):
-        n = len(y)
+    def fold_ids(self, y):
+        """One fold: id 0 where the row is validated on, 1 where trained."""
         rng = np.random.default_rng(self.seed)
-        in_val = rng.random(n) >= self.train_ratio
-        train_w = np.where(in_val, 0.0, base_w)[None, :].astype(np.float32)
-        val_w = np.where(in_val, base_w, 0.0)[None, :].astype(np.float32)
-        return train_w, val_w
+        in_val = rng.random(len(y)) >= self.train_ratio
+        return np.where(in_val, 0, 1).astype(np.int8)

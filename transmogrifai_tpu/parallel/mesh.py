@@ -67,9 +67,12 @@ def pad_axis(arr: np.ndarray, axis: int, multiple: int) -> Tuple[np.ndarray, int
 
 def pad_host(arr: np.ndarray, pad_width) -> np.ndarray:
     """``np.pad`` with zeros as one ``host.pad`` activity: the padded host
-    copy a placement key is stamped from (a copy even where the width is 0)."""
+    copy a placement key is stamped from.  Where every width is 0 the array
+    itself comes back: no copy, no span."""
     from ..perf.timers import activity
 
+    if not np.any(pad_width):
+        return arr
     with activity("pad", nbytes=int(arr.nbytes)):
         return np.pad(arr, pad_width)
 
@@ -281,6 +284,14 @@ def pad_rows_to_bucket(n: int, *arrays):
     return tuple(pad_axis(np.asarray(a), 0, m)[0] for a in arrays)
 
 
+def padded_row_count(n: int, mesh: Optional[Mesh] = None) -> int:
+    """Rows of a block of ``n`` rows once bucket- and mesh-padded, without
+    the block: what :func:`pad_rows_bucketed_for_mesh` pads to."""
+    mesh = mesh if mesh is not None else current_mesh()
+    m = bucket_size(n)
+    return m if mesh is None else m + (-m) % int(mesh.shape[DATA_AXIS])
+
+
 def pad_rows_bucketed_for_mesh(*arrays, n: Optional[int] = None):
     """Bucket-pad then mesh-pad leading axes (that order — bucket sizes are
     powers of two, so the mesh multiple keeps dividing them); returns
@@ -315,16 +326,22 @@ _PLACEMENT_LOCK = _threading.RLock()
 
 #: process-wide cumulative counts of the two placement caches (the twin of
 #: ``perf.programs.program_cache_stats``); ``bytes_stamped`` counts the bytes
-#: hashed in full for the cache's keys — a stamp-memo hit hashes none
+#: hashed in full for the cache's keys — a stamp-memo hit hashes none.
+#: ``fit`` counts what never reached a cache: row-aligned inputs a fit took
+#: as already placed (``place_fit_rows``) and arrays it derived on the device
 _PLACEMENT_STATS = {
-    cache: {"hits": 0, "misses": 0, "bytes_stamped": 0, "bytes_placed": 0}
-    for cache in ("rows", "aux")}
+    **{cache: {"hits": 0, "misses": 0, "bytes_stamped": 0, "bytes_placed": 0}
+       for cache in ("rows", "aux")},
+    "fit": {"passed_through": 0, "bytes_passed": 0,
+            "derived": 0, "bytes_derived": 0}}
 
 
 def placement_stats() -> dict:
-    """``{"rows": {...}, "aux": {...}}``: hits, misses, bytes stamped and
-    bytes placed of ``place_rows_bucketed_cached`` and ``place_cached`` since
-    the process started."""
+    """``{"rows": {...}, "aux": {...}, "fit": {...}}``: hits, misses, bytes
+    stamped and bytes placed of ``place_rows_bucketed_cached`` and
+    ``place_cached``; under ``fit`` the arrays (and their bytes) that
+    ``place_fit_rows`` passed through with no pad, stamp or lookup and those
+    ``count_derived`` was told of, all since the process started."""
     with _PLACEMENT_LOCK:
         return {cache: dict(counts)
                 for cache, counts in _PLACEMENT_STATS.items()}
@@ -509,6 +526,69 @@ def place_cached(arr: np.ndarray, axes: tuple,
 
 _PLACED_AUX_CACHE: dict = {}
 _PLACED_AUX_CACHE_MAX = 8
+
+
+# -- a fit's own row-aligned inputs -------------------------------------------
+# Labels, base weights and fold ids reach the device from several call sites
+# of one fit (the sweep's extras, the refit, every evaluator).  The fit that
+# owns them opens ``fit_placements()``; ``place_fit_rows`` then pads, stamps
+# and places each source object once and answers every later request of the
+# fit by identity.  Nothing outlives the fit: the table dies with the block.
+_FIT_PLACED: "_contextvars.ContextVar[Optional[dict]]" = \
+    _contextvars.ContextVar("transmogrifai_tpu_fit_placed", default=None)
+
+
+class fit_placements:
+    """Context manager: for the length of one fit, ``place_fit_rows``
+    remembers what it placed by source object.  The table holds a strong
+    reference to each source until the fit ends, so no ``id`` it is keyed on
+    can be recycled; an inner fit gets a table of its own."""
+
+    def __enter__(self) -> None:
+        self._token = _FIT_PLACED.set({})
+
+    def __exit__(self, *exc) -> None:
+        _FIT_PLACED.reset(self._token)
+
+
+def _count_passed(arr) -> None:
+    _count_placement("fit", passed_through=1, bytes_passed=int(arr.nbytes))
+
+
+def count_derived(*arrays) -> None:
+    """Count arrays a fit computed on the device from placed inputs (fold
+    weight blocks, sign targets, unit weights) where it used to build, pad,
+    stamp and place a host array."""
+    _count_placement("fit", derived=len(arrays),
+                     bytes_derived=sum(int(a.nbytes) for a in arrays))
+
+
+def place_fit_rows(arr, n_padded: int, dtype=None):
+    """Device handle of a fit's row-aligned input — ``(n,)`` or ``(n, c)``
+    labels, weights, fold ids, one-hots — zero-padded to ``n_padded`` rows
+    and sharded over the data axis.
+
+    The test is on the input: a placed ``jax.Array`` is taken as it is (no
+    pad, no stamp, no lookup).  A host array (cast to ``dtype`` where one is
+    given) goes through ``pad_host`` and the content-keyed ``place_cached``;
+    inside ``fit_placements()`` that happens once per source object, and a
+    second request for the same object in the same fit passes through as
+    well."""
+    if isinstance(arr, jax.Array):
+        _count_passed(arr)
+        return arr
+    arr = np.asarray(arr, dtype)
+    table = _FIT_PLACED.get()
+    key = (id(arr), int(n_padded), current_mesh())
+    if table is not None and key in table:
+        placed = table[key][1]
+        _count_passed(placed)
+        return placed
+    pad_width = [(0, int(n_padded) - arr.shape[0])] + [(0, 0)] * (arr.ndim - 1)
+    placed = place_cached(pad_host(arr, pad_width), (DATA_AXIS,))
+    if table is not None:
+        table[key] = (arr, placed)
+    return placed
 
 
 def place_rows_bucketed_cached(arr: np.ndarray,
