@@ -324,7 +324,10 @@ class TestFitProfiles:
                     "bytes_placed": vec.nbytes},
             # neither call is a fit's: nothing passed through, none derived
             "fit": {"passed_through": 0, "bytes_passed": 0, "derived": 0,
-                    "bytes_derived": 0}}
+                    "bytes_derived": 0},
+            # no mesh is active: nothing sharded, replicated or degraded
+            "mesh": {"bytes_sharded": 0, "bytes_replicated": 0,
+                     "degraded": 0, "bytes_degraded": 0}}
         # a miss records the host side of its transfer; a hit records none
         paths = [s.path for s in rec.spans]
         assert paths.count("host.h2d") == 2 and paths.count("host.stamp") == 4

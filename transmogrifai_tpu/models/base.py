@@ -177,9 +177,16 @@ def _replicator(mesh):
     The sort-based AUC metrics miscompile under GSPMD when the sort dimension
     is sharded over a mesh axis while the batch dimensions stay replicated
     (observed on a (data=4, model=2) mesh: auPR values near -n instead of
-    [0, 1]).  A sort needs the full row axis on every participant anyway, so
-    the eval programs pin their metric inputs to replicated — the all-gather
-    this forces is the collective a correct sharded sort would pay regardless.
+    [0, 1]).  So the eval programs pin their metric inputs to replicated:
+    every device receives all (g, k, n) scores, the labels and the (k, n)
+    validation weights (an all-gather; the bytes are counted at dispatch,
+    ``count_eval_replicas``) and sorts every lane itself.  That is correct
+    and it is the one part of a sweep that more devices make no faster: the
+    g x k lanes are independent sorts, so each needs its whole row axis on
+    ONE device, not on all of them, and dealing the lanes out over the
+    devices would move the same bytes once instead of once a device and sort
+    a share of the lanes on each.  What the copy costs on four chips is in
+    PERF.md (``mesh_eval_device_s``, ``replicated_gb``).
     """
     if mesh is None:
         return lambda a: a
@@ -233,6 +240,19 @@ def _eval_softmax_sweep_for(mesh):
         return jax.vmap(lambda ps: per_fold(ps, vwr), in_axes=0)(probs)
 
     return eval_softmax_sweep
+
+
+def count_eval_replicas(xd, yd, coefs, vw) -> None:
+    """Count, at dispatch, what the ambient mesh's eval program pins to every
+    device (:func:`_replicator`): the scores — ``coefs``' (g, k) lanes over
+    ``xd``'s rows, times the classes of a (g, k, d, C) block — the labels and
+    the validation weights.  From shapes; nothing without a mesh."""
+    from ..parallel.mesh import count_replicated, current_mesh
+
+    scores = jax.ShapeDtypeStruct(
+        tuple(coefs.shape[:2]) + (xd.shape[0],) + tuple(coefs.shape[3:]),
+        jnp.float32)
+    count_replicated(current_mesh(), scores, yd, vw)
 
 
 def eval_linear_sweep_program():

@@ -78,7 +78,9 @@ class LinearRegression(PredictionEstimatorBase):
 
     def _cv_sweep_device(self, x, y, train_w, val_w,
                          grids: List[Dict[str, Any]], metric_fn):
-        from .base import eval_linear_sweep_program, place_grid, sweep_placements
+        from .base import (
+            count_eval_replicas, eval_linear_sweep_program, place_grid,
+            sweep_placements)
 
         regs = place_grid(np.asarray(
             [float(g.get("reg_param", self.reg_param))
@@ -97,6 +99,7 @@ class LinearRegression(PredictionEstimatorBase):
         betas = run_cached(_ridge_sweep, xd, yd, twd, regs,
                            statics=dict(has_intercept=has_icpt),
                            label="LinearRegression/ridge_sweep")
+        count_eval_replicas(xd, yd, betas, vwd)
         return run_cached(eval_linear_sweep_program(), xd, yd, betas, vwd,
                           statics=dict(metric_fn=metric_fn),
                           label="LinearRegression/eval_sweep")

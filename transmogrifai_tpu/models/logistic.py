@@ -377,8 +377,9 @@ class LogisticRegression(PredictionEstimatorBase):
             for idx, b in parts:
                 betas = betas.at[jnp.asarray(idx)].set(b)
 
-        from .base import eval_linear_sweep_program
+        from .base import count_eval_replicas, eval_linear_sweep_program
 
+        count_eval_replicas(xd, yd, betas, val_w)
         return run_cached(
             eval_linear_sweep_program(), xd, yd, betas, val_w,
             statics=dict(metric_fn=metric_fn, link="sigmoid"),

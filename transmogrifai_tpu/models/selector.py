@@ -128,7 +128,7 @@ class ModelSelector(PredictionEstimatorBase):
 
     def fit_columns(self, cols, dataset):
         from ..obs.profile import maybe_profile
-        from ..parallel.mesh import fit_placements
+        from ..parallel.mesh import fit_mesh_record, fit_placements
         from ..perf.timers import (
             PhaseRecorder, keep_fit_profile, phase, record_phases)
 
@@ -144,8 +144,10 @@ class ModelSelector(PredictionEstimatorBase):
         # ids): each is padded, stamped and placed once, and the sweep's
         # extras, the refit and the evaluators all get the same handle
         profile = PhaseRecorder()
-        with maybe_profile("fit"), record_phases(profile), fit_placements():
+        with maybe_profile("fit"), record_phases(profile), fit_placements(), \
+                fit_mesh_record(len(cols[0])) as laid_out:
             fitted = self._fit_columns_profiled(cols, dataset, phase)
+        profile.mesh = laid_out.record
         self.last_fit_profile = profile
         keep_fit_profile(profile)
         return fitted

@@ -93,7 +93,9 @@ class MultinomialLogisticRegression(PredictionEstimatorBase):
                          grids: List[Dict[str, Any]], metric_fn):
         c = self._n_classes(y)
         y_onehot = np.eye(c, dtype=np.float32)[y.astype(np.int32)]
-        from .base import eval_softmax_sweep_program, place_grid, sweep_placements
+        from .base import (
+            count_eval_replicas, eval_softmax_sweep_program, place_grid,
+            sweep_placements)
 
         regs = place_grid(np.asarray(
             [float(g.get("reg_param", self.reg_param))
@@ -114,6 +116,7 @@ class MultinomialLogisticRegression(PredictionEstimatorBase):
             in_axes=(0, None))
         bs = jax.vmap(lambda reg: fit_fold(twd, reg), in_axes=0)(regs)
 
+        count_eval_replicas(xd, yd, bs, vwd)
         return eval_softmax_sweep_program()(
             xd, yd.astype(jnp.int32), bs, vwd, metric_fn=metric_fn)
 

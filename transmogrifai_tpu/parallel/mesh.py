@@ -144,20 +144,60 @@ def place_rows(arr, mesh: Optional[Mesh] = None):
     mesh = mesh if mesh is not None else current_mesh()
     if mesh is None:
         return jnp.asarray(arr)
-    return jax.device_put(np.asarray(arr), row_sharding(mesh))
+    arr = np.asarray(arr)
+    _count_laid_out(int(arr.nbytes), P(DATA_AXIS), mesh)
+    return jax.device_put(arr, row_sharding(mesh))
 
 
-def _effective_spec(shape, axes, mesh) -> P:
+#: a degradation is counted from this many bytes up.  What may degrade by
+#: design is a grid's parameter vector or coefficient block over the model
+#: axis (a one-point grid over a two-way axis): tens of bytes to kilobytes,
+#: 0.7 MB for the widest block the default selectors make (softmax,
+#: (6, 3, 1025, 10) float32).  A row-aligned float32 vector reaches 1 MiB at
+#: 262,144 rows; below that a replica costs nothing worth a counter.
+DEGRADED_MIN_BYTES = 1 << 20
+
+
+def _effective_spec(shape, axes, mesh, nbytes: int = 0) -> P:
     """The ONE degradation rule place()/constrain() share: an axis the mesh
     doesn't know, or whose dimension size doesn't divide the mesh axis,
     degrades to replication (sharding is a layout hint, never semantics — a
-    1-point grid over a 2-way model axis must still run/trace)."""
-    return P(*(
-        a if (a in mesh.axis_names
-              and i < len(shape)
-              and int(shape[i]) % int(mesh.shape[a]) == 0)
-        else None
-        for i, a in enumerate(axes)))
+    1-point grid over a 2-way model axis must still run/trace).
+
+    Legal, and seen: where an axis the mesh HAS is dropped because the size
+    does not divide it, an array of ``nbytes`` >= :data:`DEGRADED_MIN_BYTES`
+    is counted under ``placement_stats()["mesh"]`` (``degraded``,
+    ``bytes_degraded``) — a table or an ``(n,)`` vector that becomes one
+    replica a device is a different deployment from the one asked for."""
+    eff, lost = [], False
+    for i, a in enumerate(axes):
+        known = a in mesh.axis_names and i < len(shape)
+        keep = known and int(shape[i]) % int(mesh.shape[a]) == 0
+        lost = lost or (known and not keep)
+        eff.append(a if keep else None)
+    if lost and nbytes >= DEGRADED_MIN_BYTES:
+        _count_placement("mesh", degraded=1, bytes_degraded=int(nbytes))
+    return P(*eff)
+
+
+def _count_laid_out(nbytes: int, spec: P, mesh: Mesh) -> None:
+    """Count ``nbytes`` laid out over ``mesh`` with ``spec``: sharded where
+    some dimension is split over more than one device, else replicated (every
+    device of the mesh holds the whole array)."""
+    split = any(int(mesh.shape[a]) > 1 for a in spec if a is not None)
+    _count_placement("mesh", **{
+        "bytes_sharded" if split else "bytes_replicated": int(nbytes)})
+
+
+def count_replicated(mesh: Optional[Mesh], *operands) -> None:
+    """Count operands (anything with ``shape`` and ``dtype``) that a program
+    pins to every device of ``mesh`` with a constraint inside its trace —
+    the eval programs' metric inputs (models/base.py ``_replicator``) — from
+    their shapes, at dispatch.  Nothing without a mesh."""
+    if mesh is not None:
+        _count_placement("mesh", bytes_replicated=sum(
+            int(np.prod(o.shape)) * np.dtype(o.dtype).itemsize
+            for o in operands))
 
 
 def place(arr, axes: Tuple[Optional[str], ...], mesh: Optional[Mesh] = None):
@@ -175,7 +215,8 @@ def place(arr, axes: Tuple[Optional[str], ...], mesh: Optional[Mesh] = None):
         return jnp.asarray(arr)
     if not isinstance(arr, jax.Array):
         arr = np.asarray(arr)
-    eff = _effective_spec(arr.shape, axes, mesh)
+    eff = _effective_spec(arr.shape, axes, mesh, int(arr.nbytes))
+    _count_laid_out(int(arr.nbytes), eff, mesh)
     return jax.device_put(arr, NamedSharding(mesh, eff))
 
 
@@ -216,7 +257,11 @@ def constrain(x, *axes, mesh: Optional[Mesh] = None):
     mesh = mesh if mesh is not None else current_mesh()
     if mesh is None:
         return x
-    eff = _effective_spec(getattr(x, "shape", ()), axes, mesh)
+    shape = getattr(x, "shape", ())
+    # under jit this runs when the program is traced: a degradation inside a
+    # program is counted once a trace, a placement's every time
+    eff = _effective_spec(shape, axes, mesh, int(np.prod(shape)) * np.dtype(
+        getattr(x, "dtype", np.float32)).itemsize)
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, eff))
 
 
@@ -328,23 +373,63 @@ _PLACEMENT_LOCK = _threading.RLock()
 #: ``perf.programs.program_cache_stats``); ``bytes_stamped`` counts the bytes
 #: hashed in full for the cache's keys — a stamp-memo hit hashes none.
 #: ``fit`` counts what never reached a cache: row-aligned inputs a fit took
-#: as already placed (``place_fit_rows``) and arrays it derived on the device
+#: as already placed (``place_fit_rows``) and arrays it derived on the device.
+#: ``mesh`` counts what an active mesh did with the bytes handed to it: laid
+#: out split over devices (``bytes_sharded``), whole on every device
+#: (``bytes_replicated``: ``place``/``place_rows`` with nothing split, and the
+#: eval programs' pinned metric inputs), and asked to be split but replicated
+#: because a size does not divide its axis (``degraded``, ``bytes_degraded``;
+#: :func:`_effective_spec`).  All zero while no mesh is active.
 _PLACEMENT_STATS = {
     **{cache: {"hits": 0, "misses": 0, "bytes_stamped": 0, "bytes_placed": 0}
        for cache in ("rows", "aux")},
     "fit": {"passed_through": 0, "bytes_passed": 0,
-            "derived": 0, "bytes_derived": 0}}
+            "derived": 0, "bytes_derived": 0},
+    "mesh": {"bytes_sharded": 0, "bytes_replicated": 0,
+             "degraded": 0, "bytes_degraded": 0}}
 
 
 def placement_stats() -> dict:
-    """``{"rows": {...}, "aux": {...}, "fit": {...}}``: hits, misses, bytes
-    stamped and bytes placed of ``place_rows_bucketed_cached`` and
-    ``place_cached``; under ``fit`` the arrays (and their bytes) that
+    """``{"rows": {...}, "aux": {...}, "fit": {...}, "mesh": {...}}``: hits,
+    misses, bytes stamped and bytes placed of ``place_rows_bucketed_cached``
+    and ``place_cached``; under ``fit`` the arrays (and their bytes) that
     ``place_fit_rows`` passed through with no pad, stamp or lookup and those
-    ``count_derived`` was told of, all since the process started."""
+    ``count_derived`` was told of; under ``mesh`` the bytes an active mesh
+    laid out sharded, replicated and degraded, all since the process
+    started."""
     with _PLACEMENT_LOCK:
         return {cache: dict(counts)
                 for cache, counts in _PLACEMENT_STATS.items()}
+
+
+class fit_mesh_record:
+    """What one fit's recorder says of the mesh it ran under: the mesh's
+    shape, the rows of the padded block each data shard holds, and what the
+    ``mesh`` counters moved by between entry and exit (process-wide counters:
+    a concurrent fit's bytes land in both).  ``record`` stays None without a
+    mesh."""
+
+    def __init__(self, n_rows: int):
+        self.mesh = current_mesh()
+        self.n_rows = int(n_rows)
+        self.record: Optional[dict] = None
+
+    def __enter__(self) -> "fit_mesh_record":
+        if self.mesh is not None:
+            self._before = placement_stats()["mesh"]
+        return self
+
+    def __exit__(self, *exc) -> None:
+        if self.mesh is None:
+            return
+        n_data = int(self.mesh.shape[DATA_AXIS])
+        after = placement_stats()["mesh"]
+        self.record = {
+            "shape": {a: int(self.mesh.shape[a])
+                      for a in self.mesh.axis_names},
+            "rows_per_shard": padded_row_count(self.n_rows, self.mesh)
+            // n_data,
+            **{k: after[k] - self._before[k] for k in after}}
 
 
 def _count_placement(cache: str, **moved) -> None:

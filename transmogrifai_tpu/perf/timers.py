@@ -76,6 +76,10 @@ class PhaseRecorder:
         #: ``perf_counter`` at activation and deactivation (record_phases)
         self.start: Optional[float] = None
         self.end: Optional[float] = None
+        #: a selector fit under ``use_mesh``: the mesh's shape, rows per data
+        #: shard and the fit's ``placement_stats()["mesh"]`` deltas
+        #: (parallel/mesh.py ``fit_mesh_record``); None with no mesh
+        self.mesh: Optional[Dict[str, Any]] = None
 
     def add(self, span: Span) -> None:
         self.spans.append(span)
