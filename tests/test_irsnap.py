@@ -171,7 +171,8 @@ class TestTm705Regression:
     on a minimal reconstruction of the exact pre-PR-4 eval-sweep pattern
     (sort-based AUC over row-sharded scores with replicated (grid, fold)
     batch dims) and stay QUIET on the fixed per-mesh-closure form from
-    models/base.py (metric inputs pinned to replicated)."""
+    models/base.py (the lanes dealt over the devices, each sorting whole
+    rows locally)."""
 
     @pytest.fixture(scope="class")
     def mesh(self):
@@ -218,7 +219,8 @@ class TestTm705Regression:
             "fixed_eval", _eval_linear_sweep_for(mesh),
             [_spec(64, 5), _spec(64), _spec(2, 2, 5), _spec(2, 64)],
             statics=dict(metric_fn=self._metric(), link="sigmoid"))
-        # the fixed form still SORTS (the AUC metric) — but replicated
+        # the fixed form still SORTS (the AUC metric) — but whole rows of its
+        # own share of the lanes, on each device, inside a shard_map region
         assert snap.sorts, "expected the metric's sort in the program"
         assert snap.sharded_sort_hazards() == []
 

@@ -86,17 +86,18 @@ def test_each_of_the_four_devices_holds_a_quarter_of_the_padded_rows(fitted):
 def test_the_fit_shards_and_replicates_what_its_shapes_give(fitted):
     """A warm fit's placements, by row count n of the padded block: the two
     (3, n) fold-weight blocks and the evaluators' unit weights are made on
-    the device and laid out sharded (28 n bytes); the eval program pins the
-    (5, 3, n) scores, the labels and the (3, n) validation weights to every
-    device (76 n bytes), and the seven grid scalars ride a model axis of one
-    (28 bytes).  The table, labels, base weights and fold ids are cache hits:
-    nothing is placed for them.  Nothing degrades."""
+    the device and laid out sharded (28 n bytes), and so are the eval
+    program's scores, its 15 lanes padded to 16 and dealt four to a chip
+    (64 n bytes); the eval program pins the labels and the (3, n) validation
+    weights to every device (16 n bytes), and the seven grid scalars ride a
+    model axis of one (28 bytes).  The table, labels, base weights and fold
+    ids are cache hits: nothing is placed for them.  Nothing degrades."""
     rows, _, _, state, rec = fitted
     n = M.bucket_size(rows)
     moved = rec["counters"]
     assert moved["mesh_degraded"] == 0 and moved["mesh_bytes_degraded"] == 0
-    assert moved["mesh_bytes_sharded"] == (2 * 3 * 4 + 4) * n
-    assert moved["mesh_bytes_replicated"] == (15 + 1 + 3) * 4 * n + 7 * 4
+    assert moved["mesh_bytes_sharded"] == (2 * 3 * 4 + 4) * n + 16 * 4 * n
+    assert moved["mesh_bytes_replicated"] == (1 + 3) * 4 * n + 7 * 4
     # the recorder of the fit says the same
     for name in ("degraded", "bytes_degraded", "bytes_sharded",
                  "bytes_replicated"):

@@ -296,9 +296,11 @@ DIAGNOSTIC_CODES: Dict[str, Tuple[Severity, str, str]] = {
               "a sort op's sort dimension is sharded while its batch "
               "dimensions stay replicated — the exact GSPMD pattern that "
               "miscompiled the eval sweeps (metrics near -n, no error) "
-              "before PR 4 pinned metric inputs to replicated; replicate "
-              "the sort operand (models/base.py:_replicator) or shard a "
-              "batch dimension instead"),
+              "before PR 4 pinned metric inputs to replicated; give each "
+              "device whole rows of a share of the batch lanes inside a "
+              "shard_map region, as the linear eval program does "
+              "(models/base.py:_lane_dealer), or replicate the sort operand "
+              "(models/base.py:_replicator)"),
     # -- continual training (drift-gated warm refit, workflow/continual.py) --
     "TM801": (Severity.WARNING, "covariate drift: PSI beyond threshold",
               "the streamed distribution of this feature diverged from its "

@@ -786,9 +786,10 @@ def _sweep_entries() -> List[CorpusEntry]:
             statics=dict(metric_fn=M.multiclass_error))
 
     def eval_linear_meshed():
-        """The FIXED (PR 4) eval-sweep form under a 4x2 mesh: metric inputs
-        pinned to replicated by the per-mesh closure — the corpus proof that
-        the sharded-sort-dim hazard stays absent from the shipped program."""
+        """The FIXED eval-sweep form under a 4x2 mesh: the per-mesh closure
+        deals the lanes over the devices inside a shard_map region, each
+        sorting whole rows — the corpus proof that the sharded-sort-dim
+        hazard stays absent from the shipped program."""
         from ..models.base import _eval_linear_sweep_for
         from ..parallel.mesh import make_mesh
 

@@ -177,16 +177,12 @@ def _replicator(mesh):
     The sort-based AUC metrics miscompile under GSPMD when the sort dimension
     is sharded over a mesh axis while the batch dimensions stay replicated
     (observed on a (data=4, model=2) mesh: auPR values near -n instead of
-    [0, 1]).  So the eval programs pin their metric inputs to replicated:
-    every device receives all (g, k, n) scores, the labels and the (k, n)
-    validation weights (an all-gather; the bytes are counted at dispatch,
-    ``count_eval_replicas``) and sorts every lane itself.  That is correct
-    and it is the one part of a sweep that more devices make no faster: the
-    g x k lanes are independent sorts, so each needs its whole row axis on
-    ONE device, not on all of them, and dealing the lanes out over the
-    devices would move the same bytes once instead of once a device and sort
-    a share of the lanes on each.  What the copy costs on four chips is in
-    PERF.md (``mesh_eval_device_s``, ``replicated_gb``).
+    [0, 1]).  A program that leaves its metric to the partitioner therefore
+    pins the metric's inputs to replicated: every device receives them whole
+    (an all-gather; the bytes are counted at dispatch,
+    ``count_eval_replicas``) and computes every lane itself.  The multiclass
+    eval program still does (its metric holds no sort and its lanes are
+    cheap); the linear one deals its lanes out instead, :func:`_lane_dealer`.
     """
     if mesh is None:
         return lambda a: a
@@ -196,29 +192,89 @@ def _replicator(mesh):
     return lambda a: jax.lax.with_sharding_constraint(a, rep)
 
 
+def _dealt_over(mesh):
+    """The mesh axes the eval lanes are dealt over, model-major, and the
+    devices that makes."""
+    from ..parallel.mesh import DATA_AXIS, MODEL_AXIS
+
+    over = tuple(a for a in (MODEL_AXIS, DATA_AXIS) if a in mesh.axis_names)
+    return over, int(np.prod([mesh.shape[a] for a in over]))
+
+
+def _lane_dealer(mesh):
+    """The linear eval program's body under ``mesh``: the g x k lanes are
+    independent, and a sort-based metric needs a lane's whole row axis on ONE
+    device, not on all of them.  So the lanes are flattened, padded with
+    zero coefficients to a multiple of the devices and dealt out: a model
+    slice scores its share of the lanes on its row shard, an all-to-all over
+    the data axis trades the lane axis for the row axis, and each device runs
+    ``metric_fn`` over ``lanes / devices`` whole lanes (with fewer lanes than
+    devices some take padding only).  Only the labels and the (k, n)
+    validation weights are gathered to every device.  All of it is one
+    ``shard_map`` region, so the metric's sort is a local one that GSPMD never
+    partitions: the miscompile :func:`_replicator` dodges cannot occur.
+    What it moves is counted at dispatch (``count_eval_replicas``), what it
+    costs on four chips is in PERF.md (``mesh_eval_device_s``).
+    """
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+
+    from ..parallel.mesh import DATA_AXIS, MODEL_AXIS
+
+    over, devices = _dealt_over(mesh)
+    model = MODEL_AXIS if MODEL_AXIS in over else None
+
+    def dealt(xd, yd, betas, vw, metric_fn, link):
+        g, k, d = betas.shape
+        lanes = g * k
+        padded = lanes + (-lanes) % devices
+        flat = jnp.pad(betas.reshape(lanes, d), ((0, padded - lanes), (0, 0)))
+        folds = np.arange(padded, dtype=np.int32) % k
+
+        def local(x, y, b, w, fold):
+            margins = jnp.einsum("nd,ld->ln", x, b)
+            scores = jax.nn.sigmoid(margins) if link == "sigmoid" else margins
+            scores = jax.lax.all_to_all(scores, DATA_AXIS, 0, 1, tiled=True)
+            y = jax.lax.all_gather(y, DATA_AXIS, tiled=True)
+            w = jax.lax.all_gather(w, DATA_AXIS, axis=1, tiled=True)
+            with jax.named_scope("eval_sort"):
+                return jax.vmap(lambda s, f: metric_fn(s, y, w[f]))(
+                    scores, fold)
+
+        metrics = shard_map(
+            local, mesh=mesh,
+            in_specs=(P(DATA_AXIS), P(DATA_AXIS), P(model),
+                      P(None, DATA_AXIS), P(over)),
+            out_specs=P(over))(xd, yd, flat, vw, folds)
+        return metrics[:lanes].reshape(g, k)
+
+    return dealt
+
+
 @functools.lru_cache(maxsize=None)
 def _eval_linear_sweep_for(mesh):
     """Per-mesh jitted linear eval program.
 
-    One closure per mesh: the replication constraint bakes the mesh into the
+    One closure per mesh: the ``shard_map`` region bakes the mesh into the
     trace, so sharing one jitted function across meshes would poison the jit
     trace cache; ``run_cached`` keys on the ambient mesh already, and the
     per-mesh function identity keeps the plain jit cache honest too.
     """
-    rep = _replicator(mesh)
+    dealt = None if mesh is None else _lane_dealer(mesh)
 
     @partial(jax.jit, static_argnames=("metric_fn", "link"))
     def eval_linear_sweep(xd, yd, betas, vw, *, metric_fn, link="identity"):
         """Metric per (grid, fold) for linear-family sweeps — one cached
         program.  betas: (g, k, d); vw: (k, n).  ``link`` maps margins to
         scores ("identity" for regression/SVM margins, "sigmoid" for logistic
-        probs)."""
+        probs).  Under a mesh the lanes are dealt over its devices."""
+        if dealt is not None:
+            return dealt(xd, yd, betas, vw, metric_fn, link)
         margins = jnp.einsum("nd,gkd->gkn", xd, betas)
         scores = jax.nn.sigmoid(margins) if link == "sigmoid" else margins
-        scores, yr, vwr = rep(scores), rep(yd), rep(vw)
-        per_fold = jax.vmap(lambda s, w_: metric_fn(s, yr, w_), in_axes=(0, 0))
+        per_fold = jax.vmap(lambda s, w_: metric_fn(s, yd, w_), in_axes=(0, 0))
         with jax.named_scope("eval_sort"):
-            return jax.vmap(lambda ps: per_fold(ps, vwr), in_axes=0)(scores)
+            return jax.vmap(lambda ps: per_fold(ps, vw), in_axes=0)(scores)
 
     return eval_linear_sweep
 
@@ -243,16 +299,26 @@ def _eval_softmax_sweep_for(mesh):
 
 
 def count_eval_replicas(xd, yd, coefs, vw) -> None:
-    """Count, at dispatch, what the ambient mesh's eval program pins to every
-    device (:func:`_replicator`): the scores — ``coefs``' (g, k) lanes over
-    ``xd``'s rows, times the classes of a (g, k, d, C) block — the labels and
-    the validation weights.  From shapes; nothing without a mesh."""
-    from ..parallel.mesh import count_replicated, current_mesh
+    """Count, at dispatch, what the ambient mesh's eval program does with its
+    metric inputs.  Both programs pin the labels and the validation weights
+    to every device.  The linear one (``coefs`` (g, k, d)) lays its scores
+    out split: ``xd``'s rows by the g x k lanes, padded to a multiple of the
+    devices they are dealt over (:func:`_lane_dealer`).  The multiclass one
+    (``coefs`` (g, k, d, C)) pins its (g, k, n, C) probabilities to every
+    device too (:func:`_replicator`).  From shapes; nothing without a mesh."""
+    from ..parallel.mesh import count_replicated, count_sharded, current_mesh
 
-    scores = jax.ShapeDtypeStruct(
-        tuple(coefs.shape[:2]) + (xd.shape[0],) + tuple(coefs.shape[3:]),
-        jnp.float32)
-    count_replicated(current_mesh(), scores, yd, vw)
+    mesh = current_mesh()
+    if mesh is None:
+        return
+    lanes, n = int(np.prod(coefs.shape[:2])), xd.shape[0]
+    count_replicated(mesh, yd, vw)
+    if coefs.ndim == 3:
+        lanes += (-lanes) % _dealt_over(mesh)[1]
+        count_sharded(mesh, jax.ShapeDtypeStruct((lanes, n), jnp.float32))
+    else:
+        count_replicated(mesh, jax.ShapeDtypeStruct(
+            (lanes, n) + tuple(coefs.shape[3:]), jnp.float32))
 
 
 def eval_linear_sweep_program():

@@ -189,15 +189,26 @@ def _count_laid_out(nbytes: int, spec: P, mesh: Mesh) -> None:
         "bytes_sharded" if split else "bytes_replicated": int(nbytes)})
 
 
+def _count_in_program(mesh: Optional[Mesh], how: str, operands) -> None:
+    if mesh is not None:
+        _count_placement("mesh", **{how: sum(
+            int(np.prod(o.shape)) * np.dtype(o.dtype).itemsize
+            for o in operands)})
+
+
 def count_replicated(mesh: Optional[Mesh], *operands) -> None:
     """Count operands (anything with ``shape`` and ``dtype``) that a program
-    pins to every device of ``mesh`` with a constraint inside its trace —
-    the eval programs' metric inputs (models/base.py ``_replicator``) — from
-    their shapes, at dispatch.  Nothing without a mesh."""
-    if mesh is not None:
-        _count_placement("mesh", bytes_replicated=sum(
-            int(np.prod(o.shape)) * np.dtype(o.dtype).itemsize
-            for o in operands))
+    pins to every device of ``mesh`` inside its trace — the eval programs'
+    labels and validation weights, the multiclass one's probabilities
+    (models/base.py ``count_eval_replicas``) — from their shapes, at
+    dispatch.  Nothing without a mesh."""
+    _count_in_program(mesh, "bytes_replicated", operands)
+
+
+def count_sharded(mesh: Optional[Mesh], *operands) -> None:
+    """:func:`count_replicated`'s twin for what a program lays out split over
+    the devices inside its trace: the linear eval program's dealt scores."""
+    _count_in_program(mesh, "bytes_sharded", operands)
 
 
 def place(arr, axes: Tuple[Optional[str], ...], mesh: Optional[Mesh] = None):
@@ -375,7 +386,8 @@ _PLACEMENT_LOCK = _threading.RLock()
 #: ``fit`` counts what never reached a cache: row-aligned inputs a fit took
 #: as already placed (``place_fit_rows``) and arrays it derived on the device.
 #: ``mesh`` counts what an active mesh did with the bytes handed to it: laid
-#: out split over devices (``bytes_sharded``), whole on every device
+#: out split over devices (``bytes_sharded``: ``place``/``place_rows``, and
+#: the linear eval program's dealt scores), whole on every device
 #: (``bytes_replicated``: ``place``/``place_rows`` with nothing split, and the
 #: eval programs' pinned metric inputs), and asked to be split but replicated
 #: because a size does not divide its axis (``degraded``, ``bytes_degraded``;
