@@ -2,9 +2,9 @@
 
 The quickest proof that the system still starts on the chip.  One process
 drives the main path through the entry points a user calls, at the full
-width of the configuration the repo headlines (``bench.py``: D = 128
-post-transmogrify columns, 32 bins, 3 folds, LR x6 + LinearSVC x2 + RF 50
-trees depth 3/6 + GBT 50 rounds depth 3 = 33 fold-models):
+width of the configuration the repo headlines (``BASELINE.json`` config 4:
+D = 128 post-transmogrify columns, 32 bins, 3 folds, LR x6 + LinearSVC x2 +
+RF 50 trees depth 3/6 + GBT 50 rounds depth 3 = 33 fold-models):
 
   seeded raw table (Real + PickList columns)
     -> FeatureBuilder -> transmogrify -> sanity_check
@@ -46,7 +46,7 @@ from typing import Any, Dict, List, Optional, Sequence
 
 import numpy as np
 
-#: post-sanity-check feature width — the bench's D (bench.py)
+#: post-sanity-check feature width (``BASELINE.json`` config 4)
 WIDTH = 128
 FOLDS = 3
 BINS = 32
@@ -139,7 +139,7 @@ def build_workflow(df, rf_trees: int, gbt_rounds: int, rf_depths=(3, 6)):
         FeatureBuilder.PickList(f"p{j}").extract_field().as_predictor()
         for j in range(len(PICKLISTS))]
     checked = label.sanity_check(transmogrify(predictors))
-    # the bench grids (bench.py LR_GRIDS / SVC_GRIDS / RF_GRIDS / GBT_GRIDS)
+    # the headline grids (``BASELINE.json`` config 4)
     models = [
         (LogisticRegression(), [{"reg_param": r, "elastic_net": e}
                                 for r in (0.001, 0.01, 0.1)

@@ -115,16 +115,38 @@ class TestStoreRobustness:
 
 
 class TestSweepOnce:
-    def test_sweep_persists_then_fresh_state_reads_cached(self, store):
-        swept = autotune.sweep("encode", store=store, reps=1)
+    @pytest.mark.parametrize("family", sorted(autotune.FAMILIES))
+    def test_sweep_persists_then_fresh_state_reads_cached(self, store,
+                                                          family):
+        swept = autotune.sweep(family, store=store, reps=1)
         assert swept.source == "swept" and swept.verified
+        assert swept.candidates >= 1
         assert autotune.sweep_count() == 1
         autotune.reset()
-        dec = autotune.ensure_tuned("encode", sweep_on_miss=False,
+        dec = autotune.ensure_tuned(family, sweep_on_miss=False,
                                     store=store)
         assert dec.source == "cached"
         assert dec.params == swept.params
         assert autotune.sweep_count() == 0  # the warm store swept NOTHING
+
+    def test_one_store_answers_every_family_after_one_sweep_each(self,
+                                                                 store):
+        """Every kernel family sweeps ONCE into one store, every winner is
+        verified, and a fresh adoption state answers all of them from the
+        store at zero further sweeps."""
+        assert set(autotune.FAMILIES) == {"hist", "split", "encode", "route"}
+        decisions = {f: autotune.sweep(f, store=store, reps=1)
+                     for f in autotune.FAMILIES}
+        assert autotune.sweep_count() == len(autotune.FAMILIES)
+        assert all(d.verified for d in decisions.values()), decisions
+        assert len(autotune.winners(store)) == len(autotune.FAMILIES)
+        autotune.reset()
+        warm = {f: autotune.ensure_tuned(f, store=store, sweep_on_miss=False)
+                for f in autotune.FAMILIES}
+        assert autotune.sweep_count() == 0
+        for f, dec in warm.items():
+            assert dec.source == "cached", (f, dec)
+            assert dec.params == decisions[f].params
 
     def test_concurrent_first_contact_sweeps_once(self, store):
         barrier = threading.Barrier(2)

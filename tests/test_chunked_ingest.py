@@ -388,6 +388,41 @@ class TestZeroCompileAcrossChunks:
         assert sum(s.hits for s in entries.values()) - hits0 == cds.n_chunks
 
 
+    def test_streamed_table_epoch_spills_every_chunk_at_zero_compiles(self):
+        """A table streamed into the chunk store and never resident whole:
+        after one warm dispatch at the chunk tile, the chunked epoch visits
+        every chunk once, spills the vector column out-of-core, and compiles
+        nothing across the chunk boundaries (padded tail included)."""
+        from transmogrifai_tpu.workflow.dag import compute_dag
+
+        n, chunk_rows = 1900, 512
+        ds = _fixture(n)
+        w = ChunkedDatasetWriter(chunk_rows=chunk_rows)
+        for lo in range(0, n, chunk_rows):
+            w.append(ds.take(np.arange(lo, min(lo + chunk_rows, n))))
+        cds = w.finish()
+        assert cds.n_chunks == 4 and cds.n_rows == n
+        label, checked = _features()
+        head = cds.take(np.arange(chunk_rows))
+        m = (Workflow().set_input_dataset(head)
+             .set_result_features(label, checked)).train()
+        # warm the chunk-tile executable
+        transform_dag(head, m.result_features, m.fitted)
+        runners = [m.fitted.get(s.uid, s)
+                   for layer in compute_dag(m.result_features)
+                   for s in layer]
+        stats = EpochStats()
+        with measure_compiles() as c:
+            out = chunked_transform_epoch(cds, runners, stats=stats)
+        assert c.backend_compiles == 0
+        assert stats.chunks_total == stats.chunks_processed == cds.n_chunks
+        assert stats.chunks_skipped == 0
+        assert stats.prefetch["chunks"] == cds.n_chunks
+        assert checked.name in out.spilled_names
+        assert stats.bytes_spilled > 0
+        assert out.n_rows == n
+
+
 class TestCrashAndResume:
     def _prep(self, tmp_path, n=1500):
         ds = _fixture(n, seed=21)

@@ -140,6 +140,36 @@ class TestRegistryLifecycle:
             assert fleet.score("beta", recs_a[0], timeout=30) == \
                 plan_a.score([recs_a[0]])[0]
 
+    def test_every_tenant_past_the_first_registers_at_zero_compiles(
+            self, fleet_models):
+        """Four tenants on one model behind one batcher: tenants - 1 shared
+        registrations, none of them compiles, every tenant's rows are the
+        solo plan's bitwise and every tenant has a p99 on record."""
+        model_a, recs_a, plan_a = fleet_models["A"]
+        tenants = [("t_gold", "gold"), ("t_silver", "silver"),
+                   ("t_bronze", "bronze"), ("t_bulk", "bronze")]
+        with FleetServer(max_batch=32, max_wait_ms=2, min_bucket=MIN_BUCKET,
+                         max_bucket=MAX_BUCKET,
+                         max_queue=4 * 24 + 1) as fleet:
+            fleet.register(tenants[0][0], model_a, slo=tenants[0][1])
+            with measure_compiles() as probe:
+                for t, slo in tenants[1:]:
+                    fleet.register(t, model_a, slo=slo)
+            assert probe.backend_compiles == 0
+            assert fleet.metrics()["fleet"]["shared_prefix_registrations"] \
+                == len(tenants) - 1
+            futs = {t: [fleet.submit(t, r) for r in recs_a[:24]]
+                    for t, _slo in tenants}
+            out = {t: [f.result(timeout=30) for f in fs]
+                   for t, fs in futs.items()}
+            m = fleet.metrics()
+        ref = plan_a.score(recs_a[:24])
+        for t, _slo in tenants:
+            assert out[t] == ref
+            assert m["tenants"][t]["scored_records"] == 24
+            assert m["tenants"][t]["latency_p99_ms"] is not None
+        assert m["batcher"]["shed"] == 0
+
     def test_unregister_prunes_labeled_series(self, fleet_models):
         model_a, recs_a, _ = fleet_models["A"]
         with FleetServer(max_batch=8, max_wait_ms=1) as fleet:

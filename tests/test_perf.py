@@ -1,12 +1,11 @@
 """perf/ subsystem tests: phase timers, compile probe, the content-addressed
-executable cache for training sweeps, bucket-padding numerics, and the bench
-smoke path (ISSUE 3 tentpole + satellites).
+executable cache for training sweeps and bucket-padding numerics (ISSUE 3
+tentpole + satellites).
 
 Key discipline mirrored from tests/test_serve.py: compile-at-most-once per
 (program, bucket) and zero new XLA compilations on a warm refit.
 """
 
-import json
 import os
 import subprocess
 import sys
@@ -601,10 +600,23 @@ class TestSweepCacheOnSelector:
         # compile-at-most-once per (program, operand-signature) key
         for key, s in program_cache_entries().items():
             assert s.compiles <= 1, (s.label, s.shapes, s.compiles)
-        # the phase profile of the fit is recorded (bench reads this)
+        # the phase profile of the fit is recorded (chipbench's span readers
+        # and chip_smoke.py read it)
         rep = sel.last_fit_profile.report()
         assert any(p.startswith("validate") for p in rep)
         assert "refit" in rep
+        # ... and it splits the validate phase by family: one dispatch and
+        # one gather span per family of the sweep, read off the ONE real fit
+        # (nothing is re-run in isolation to get a per-family number)
+        fams = {type(est).__name__ for est, _grids in _small_models()}
+        for step in ("dispatch", "gather"):
+            got = {p.split(".")[3] for p in rep
+                   if p.startswith(f"validate.cv.{step}.")}
+            assert got == fams, (step, got)
+        # the process-wide counters saw the sweep's programs
+        assert compile_snapshot().backend_compiles >= 1
+        stats = program_cache_stats()
+        assert stats["programs_compiled"] >= 1 and stats["cache_hits"] >= 1
 
     def test_bucket_padding_numerics_match_exact_fit(self):
         """Acceptance: padded-bucket sweep results match unpadded fits —
@@ -646,244 +658,3 @@ class TestSweepCacheOnSelector:
         flat_b = np.concatenate([v.ravel() for v in bucketed.values()])
         flat_e = np.concatenate([v.ravel() for v in exact.values()])
         assert int(np.nanargmax(flat_b)) == int(np.nanargmax(flat_e))
-
-
-class TestBenchSmoke:
-    def test_bench_smoke_every_section_lands(self):
-        """Satellite: the tiny-rows smoke mode exercises every bench section
-        end-to-end and always emits a parseable JSON line with the compile
-        section — bench-path regressions fail here instead of eating the
-        driver budget."""
-        env = {**os.environ, "JAX_PLATFORMS": "cpu", "BENCH_SMOKE": "1",
-               "BENCH_ROWS": "1500", "BENCH_BUDGET_S": "240",
-               "XLA_FLAGS": "--xla_force_host_platform_device_count=8"}
-        out = subprocess.run([sys.executable, "bench.py", "--smoke"],
-                             cwd=REPO, env=env, capture_output=True,
-                             text=True, timeout=420)
-        assert out.returncode == 0, out.stderr[-3000:]
-        line = out.stdout.strip().splitlines()[-1]
-        parsed = json.loads(line)
-        assert parsed["value"] is not None
-        assert parsed["compile"]["backend_compiles"] >= 1
-        assert "sweep_programs_compiled" in parsed["compile"]
-        secs = parsed["sections"]
-        assert secs["selector"]["status"] == "ok"
-        for name, sec in secs.items():
-            assert sec["status"] in ("ok", "skipped"), (name, sec)
-        # the breakdown came from recorded phases, not isolated re-runs
-        assert "families_secs" in parsed["phase_breakdown"]
-        assert parsed["warm_fit_backend_compiles"] == 0
-        # fused transform planner section: >= 3x interpreted prep throughput
-        # on the wide fixture, steady state compiles nothing (ISSUE 4)
-        assert secs["transform"]["status"] == "ok", secs["transform"]
-        tr = parsed["transform"]
-        assert tr["speedup"] >= 3.0, tr
-        assert tr["gate_3x"] is True
-        assert tr["warm_transform_backend_compiles"] == 0
-        # out-of-core chunked ingestion (ISSUE 13): the ingest section
-        # streams a table bigger than the armed host budget into the chunk
-        # store and runs a chunked fused epoch — prefetch overlap > 0.5,
-        # zero backend compiles across chunk boundaries, and peak RSS under
-        # the budget while the table itself exceeds it
-        assert secs["ingest"]["status"] == "ok", secs["ingest"]
-        ing = parsed["ingest"]
-        assert ing["table_exceeds_budget"] is True, ing
-        assert ing["gate_overlap"] is True, ing
-        assert ing["overlap_fraction"] > 0.5, ing
-        assert ing["warm_chunk_backend_compiles"] == 0, ing
-        assert ing["gate_zero_chunk_compiles"] is True, ing
-        if ing["rss_peak_delta_bytes"] is not None:
-            assert ing["gate_rss_under_budget"] is True, ing
-        assert ing["ingest_gbs"] > 0 and ing["epoch_rows_per_sec"] > 0
-        assert ing["chunks"] >= 2, ing
-        # serving fault-tolerance section: zero quarantines/breaker trips/
-        # deadline evictions on the clean fixture, and the degraded-mode
-        # (breaker-open, host-path) replay compiles nothing (ISSUE 5)
-        assert secs["serve"]["status"] == "ok", secs["serve"]
-        sv = parsed["serve"]
-        assert sv["clean_fixture_gate"] is True, sv
-        assert sv["quarantined"] == 0 and sv["breaker_opened_clean"] == 0
-        assert sv["degraded_backend_compiles"] == 0, sv
-        assert sv["degraded_host_rps"] > 0 and sv["throughput_rps"] > 0
-        assert sv["degraded_fallback_records"] == sv["records"], sv
-        # unified telemetry (ISSUE 11): enabled-vs-disabled serve overhead
-        # at identical fixtures gates < 5% (paired-median protocol), and a
-        # warm replay with the flight recorder attached logs ZERO backend
-        # compile events
-        assert secs["obs"]["status"] == "ok", secs["obs"]
-        ob = parsed["obs"]
-        assert ob["gate_overhead_lt_5pct"] is True, ob
-        assert ob["gate_zero_warm_compiles"] is True, ob
-        assert ob["warm_serve_backend_compiles"] == 0, ob
-        assert ob["flight_compile_events"] == 0, ob
-        assert ob["unexpected_compiles"] == 0, ob
-        assert ob["disabled_rps"] > 0 and ob["enabled_rps"] > 0
-        assert ob["trace_events"] > 0  # the tracer actually recorded spans
-        # ISSUE 14: per-request causal tracing (detail="requests") must
-        # stay under the same <5% overhead contract on the real
-        # submit->flush->response path, and actually record request tracks
-        assert ob["gate_requests_overhead_lt_5pct"] is True, ob
-        assert ob["request_trace_events"] > 0, ob
-        # continual control plane (ISSUE 9): the stream section pushes
-        # records through drift-check + shadow-score, and the frozen-prep
-        # warm refit must recompile NOTHING (plan cache + sweep executable
-        # cache) while the swap shares the prefix executables
-        assert secs["stream"]["status"] == "ok", secs["stream"]
-        st = parsed["stream"]
-        assert st["warm_refit_backend_compiles"] == 0, st
-        assert st["zero_refit_compile_gate"] is True
-        assert st["prefix_reused"] is True
-        assert st["swap_shared_prefix"] is True
-        assert st["records_per_sec"] > 0
-        assert st["shadow_mirrored"] == st["records"], st
-        assert st["shadow_failures"] == 0, st
-        # multi-tenant fleet (ISSUE 12): N tenants behind one SLO-tiered
-        # batcher — registrations past the first share the content-addressed
-        # executables at zero compiles, per-tenant p99s are recorded, and
-        # induced overload sheds ONLY the bronze tier while the gold burst
-        # completes in full
-        assert secs["fleet"]["status"] == "ok", secs["fleet"]
-        fl = parsed["fleet"]
-        assert fl["gate_shared_prefix_dedup"] is True, fl
-        assert fl["dedup_backend_compiles"] == 0, fl
-        assert fl["fleet_shared_prefix_compiles"] == fl["tenants"] - 1, fl
-        assert fl["aggregate_rps"] > 0
-        assert fl["gate_per_tenant_p99"] is True, fl
-        assert len(fl["per_tenant_p99_ms"]) == fl["tenants"]
-        assert fl["gate_shed_lowest_tier_first"] is True, fl
-        assert fl["overload"]["shed_by_tier"]["bronze"] > 0
-        assert fl["overload"]["shed_by_tier"]["gold"] == 0
-        assert fl["overload"]["gold_completed"] == \
-            fl["overload"]["gold_submitted"]
-        # AOT artifact store (ISSUE 17): the deploy section packs the
-        # serving fixture, cold-boots a fleet from the artifact dir at ZERO
-        # backend compiles (register + first score under the probe), rolls
-        # out every further tenant from the same dir, and the artifact-path
-        # scores are bitwise-equal to the live-compiled reference; the
-        # compile section reports the artifact traffic beside the
-        # persistent-cache counters
-        assert secs["deploy"]["status"] == "ok", secs["deploy"]
-        dp = parsed["deploy"]
-        assert dp["gate_zero_compile_boot"] is True, dp
-        assert dp["boot_backend_compiles"] == 0, dp
-        assert dp["total_backend_compiles"] == 0, dp
-        assert dp["gate_bitwise_equal"] is True, dp
-        assert dp["gate_no_refusals"] is True, dp
-        assert dp["store"]["hits"] > 0 and dp["store"]["refusals"] == 0
-        assert dp["cold_start_to_first_score_s"] > 0, dp
-        assert dp["pack_seconds"] > 0 and dp["artifact_bytes"] > 0
-        assert parsed["compile"]["artifact_hits"] >= dp["store"]["hits"]
-        assert parsed["compile"]["artifact_refusals"] == 0
-        # static cost model (ISSUE 6): predicted FLOPs/bytes recorded beside
-        # the measured transform/sweep numbers, calibration within the band
-        assert tr["predicted_flops"] > 0, tr
-        assert tr["predicted_bytes"] > 0, tr
-        assert tr["predicted_peak_hbm_bytes"] > 0, tr
-        # program identity (ISSUE 7): the BENCH artifact names the exact
-        # fused programs it timed — content + IR-corpus fingerprints in the
-        # transform and serve sections, so round-over-round throughput
-        # shifts can be told apart from program changes
-        assert len(tr["ir_fingerprint"]) == 32, tr
-        assert tr["plan_fingerprint"], tr
-        assert len(sv["ir_fingerprint"]) == 32, sv
-        assert sv["plan_fingerprint"], sv
-        if secs.get("irls_mfu", {}).get("status") == "ok":
-            assert parsed["irls_sweep_predicted_flops"] > 0
-            cal = parsed["irls_sweep_flops_calibration"]
-            assert 0.2 <= cal <= 5.0, \
-                f"static FLOP model drifted from the analytic count: {cal}"
-        # pod-scale dp x mp sweeps (ISSUE 15): the multihost section emits
-        # in --smoke with ZERO warm sharded backend compiles, bitwise
-        # sharded-vs-single parity, a per-host-clean collective certificate,
-        # and self-describing mesh/topology provenance
-        assert secs["multihost"]["status"] == "ok", secs["multihost"]
-        mh = parsed["multihost"]
-        assert mh["warm_sharded_backend_compiles"] == 0, mh
-        assert mh["gate_zero_warm_sharded_compiles"] is True, mh
-        assert mh["sharded_parity_ok"] is True, mh
-        assert mh["gate_collectives_not_rows_proportional"] is True, mh
-        assert mh["sharded_fold_models_per_sec"] > 0
-        assert mh["single_fold_models_per_sec"] > 0
-        prov = mh["provenance"]
-        assert prov["mesh_shape"] == {"data": 4, "model": 2}, prov
-        assert prov["process_count"] == 1 and prov["global_devices"] == 8
-        assert "analyzer_collective_bytes_per_step" in prov
-        # Pallas kernel dispatch section (ISSUE 10): runs in interpret mode
-        # under --smoke, always emits, inline exact-int8 parity must hold,
-        # and the JSON carries the tuning provenance of the run
-        assert secs["pallas"]["status"] == "ok", secs["pallas"]
-        pz = parsed["pallas"]
-        assert pz["measured"] in ("pallas", "interpret")
-        assert pz["interpret_parity_ok"] is True, pz
-        assert pz["gate_hist_ge_xla"] is True, pz
-        assert pz["hist_kernel_gbs"] > 0 and pz["hist_xla_gbs"] > 0
-        assert pz["split_scan_kernel_nodes_per_sec"] > 0
-        tuning = parsed["tuning"]
-        assert tuning["kernel_mode"] in ("xla", "pallas", "interpret")
-        assert tuning["hist_chunk"] >= 1 and tuning["hist_unroll"] >= 1
-        # persistent kernel autotuner (ISSUE 19): every family sweeps ONCE
-        # into the bench-local store, every candidate that won is verified,
-        # and a fresh adoption state re-answers entirely from the warm
-        # store at zero further sweeps
-        assert secs["autotune"]["status"] == "ok", secs["autotune"]
-        at = parsed["autotune"]
-        assert at["gate_sweep_once_then_cached"] is True, at
-        assert at["gate_all_verified"] is True, at
-        assert at["sweeps_warm_store"] == 0, at
-        assert set(at["families"]) == {"hist", "split", "encode", "route"}
-        for fam, rec in at["families"].items():
-            assert rec["verified"] is True, (fam, rec)
-            assert rec["candidates"] >= 1, (fam, rec)
-        # training resilience (PR 20): journaling must be ~free (attributed
-        # durable-write time under 3% of the fit), an injected mid-sweep
-        # failure must leave a journal block behind, and the resumed fit
-        # must replay it (journal hit) at ZERO additional backend compiles
-        assert secs["trainres"]["status"] == "ok", secs["trainres"]
-        tr = parsed["trainres"]
-        assert tr["gate_overhead_lt_3pct"] is True, tr
-        assert tr["gate_zero_resume_compiles"] is True, tr
-        assert tr["gate_journal_hit_on_resume"] is True, tr
-        assert tr["failed_as_expected"] is True, tr
-        assert tr["journal_blocks_after_kill"] >= 1, tr
-        assert tr["resume_extra_backend_compiles"] == 0, tr
-        assert tr["resume_journal_hits"] >= 1, tr
-        assert tr["recovery_seconds"] > 0, tr
-        # reduced-precision scoring classes (ISSUE 19): the serve section's
-        # bf16 twin scores the same records within the TM511 class bound
-        # and forks the fingerprint (no executable/artifact aliasing)
-        assert sv["gate_bf16_within_bound"] is True, sv
-        assert sv["gate_precision_forks_fingerprint"] is True, sv
-        assert sv["bf16_plan_rps"] > 0 and sv["f32_plan_rps"] > 0
-        assert sv["bf16_max_prediction_delta"] is not None
-        assert sv["bf16_max_prediction_delta"] <= 1e-2, sv
-
-    def test_bench_emits_json_under_sigterm_mid_section(self):
-        """Regression for the PR 3 signal handlers (the BENCH_r05 rc=124 run
-        predated them and recorded NOTHING): a SIGTERM delivered mid-section
-        must still flush the one JSON line, tagged with the signal name."""
-        import signal
-
-        env = {**os.environ, "JAX_PLATFORMS": "cpu", "BENCH_SMOKE": "1",
-               # big enough that the selector section far outlives the kill
-               "BENCH_ROWS": "60000", "BENCH_BUDGET_S": "600",
-               "XLA_FLAGS": "--xla_force_host_platform_device_count=8"}
-        proc = subprocess.Popen([sys.executable, "bench.py", "--smoke"],
-                                cwd=REPO, env=env, stdout=subprocess.PIPE,
-                                stderr=subprocess.PIPE, text=True)
-        try:
-            # handlers install before the heavy jax import; 12s lands the
-            # signal well inside the (minutes-long at 60k CPU rows) selector
-            time.sleep(12.0)
-            proc.send_signal(signal.SIGTERM)
-            stdout, stderr = proc.communicate(timeout=120)
-        finally:
-            if proc.poll() is None:
-                proc.kill()
-                proc.communicate()
-        lines = [ln for ln in stdout.strip().splitlines() if ln.strip()]
-        assert lines, f"no stdout at all; stderr: {stderr[-2000:]}"
-        parsed = json.loads(lines[-1])
-        assert parsed["interrupted"] == "SIGTERM"
-        assert parsed["metric"] == "selector_cv_models_per_sec_1m_rows"
-        # the handler exits 0 after flushing — the JSON is the contract
-        assert proc.returncode == 0, (proc.returncode, stderr[-500:])

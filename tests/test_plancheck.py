@@ -210,6 +210,33 @@ class TestTraceCost:
 # full model analysis + TM6xx wiring
 # ---------------------------------------------------------------------------
 
+    def test_static_flops_within_band_of_the_analytic_irls_count(self):
+        """The static FLOP model against a count made by hand: the IRLS
+        fold x grid sweep at the headline width does, per (grid, fold,
+        iteration), the bordered Hessian X^T S X (2 n d^2), the scalings,
+        borders and matvecs (~6 n d1) and the solve (2/3 d1^3).  The traced
+        count must sit within 0.2-5.0 of it, or every roofline and MFU
+        figure that rests on the model is off."""
+        import jax
+
+        from transmogrifai_tpu.models.logistic import _irls_sweep
+
+        n, d, folds, iters = 2048, 128, 3, 30
+        d1 = d + 1
+        regs = 8
+        f32 = np.dtype("float32")
+        seg = trace_cost(
+            lambda a, b, c, r: _irls_sweep(a, b, c, r, iters),
+            jax.ShapeDtypeStruct((n, d), f32),
+            jax.ShapeDtypeStruct((n,), f32),
+            jax.ShapeDtypeStruct((folds, n), f32),
+            jax.ShapeDtypeStruct((regs,), f32), name="irls_sweep")
+        analytic = regs * folds * iters * (
+            2.0 * n * d * d + 6.0 * n * d1 + (2 / 3) * d1 ** 3)
+        assert seg.flops > 0
+        assert 0.2 <= seg.flops / analytic <= 5.0, seg.flops / analytic
+
+
 class TestCostValidate:
     def test_cost_report_nonzero_and_zero_compiles(self, fitted_model):
         with measure_compiles() as c:

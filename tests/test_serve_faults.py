@@ -660,6 +660,38 @@ class TestServerWiring:
         assert m["resilience"]["breaker"]["state"] == "closed"
         assert m["resilience"]["quarantined"] == 0
 
+    def test_clean_replay_counts_nothing_then_forced_open_serves_host(
+            self, model_and_records):
+        """A clean replay through submit() moves no failure counter; the same
+        replay with the breaker pinned open is served record for record from
+        the host path, bitwise, at ZERO backend compiles."""
+        model, records, *_ = model_and_records
+        recs = records[:96]
+        with ScoringServer(model, max_batch=16, max_wait_ms=1.0,
+                           max_queue=len(recs) + 1) as server:
+            clean_out = [f.result(timeout=60)
+                         for f in [server.submit(r) for r in recs]]
+            clean = server.metrics()
+            host_ref = server.plan.score_host(recs)
+            server.resilience.breaker.force_open()
+            with measure_compiles() as probe:
+                degraded_out = [f.result(timeout=60)
+                                for f in [server.submit(r) for r in recs]]
+            server.resilience.breaker.force_close()
+            m = server.metrics()
+        res, bat = clean["resilience"], clean["batcher"]
+        assert res["quarantined"] == 0 and res["retries"] == 0
+        assert res["breaker"]["opened"] == 0
+        assert res["fallback_records"] == 0
+        assert bat["deadline_expired"] == 0 and bat["failed"] == 0
+        assert bat["cancelled"] == 0
+        assert bat["completed"] == bat["submitted"] == len(recs)
+        assert clean_out == server.plan.score(recs)
+        assert probe.backend_compiles == 0
+        assert m["resilience"]["fallback_records"] == len(recs)
+        assert degraded_out == host_ref
+        assert m["batcher"]["failed"] == 0
+
     def test_resilience_opt_out(self, model_and_records):
         model, records, *_ = model_and_records
         with ScoringServer(model, max_batch=8, max_wait_ms=1, warm=False,

@@ -128,6 +128,45 @@ class TestScorePathParity:
                               np.asarray(s2[pred.name].score))
 
 
+    def test_warm_transform_dag_compiles_nothing(self, trained):
+        """Steady state: a second fused ``transform_dag`` over the same fitted
+        DAG and table comes out of the plan and executable caches — ZERO
+        backend compiles."""
+        model, ds, _checked, _pred = trained
+        transform_dag(ds, model.result_features, model.fitted)  # warm
+        with measure_compiles() as probe:
+            transform_dag(ds, model.result_features, model.fitted)
+        assert probe.backend_compiles == 0, \
+            f"warm fused transform recompiled {probe.backend_compiles}"
+
+    def test_fused_plan_names_and_costs_itself(self, trained):
+        """The plan ``transform_dag`` dispatches carries its own identity and
+        cost: a content fingerprint, the canonical-IR fingerprint of the
+        fused prefix, and nonzero predicted FLOPs / bytes / peak HBM from the
+        abstract trace — all at zero backend compiles."""
+        from transmogrifai_tpu.checkers.irsnap import snapshot_transform_plan
+        from transmogrifai_tpu.checkers.plancheck import \
+            analyze_transform_plan
+        from transmogrifai_tpu.workflow.plan import plan_for_features
+
+        model, ds, _checked, _pred = trained
+        with measure_compiles() as probe:
+            plan = plan_for_features(ds, model.result_features, model.fitted)
+            snap = snapshot_transform_plan(plan, ds)
+            cost = analyze_transform_plan(plan, ds)
+        assert probe.backend_compiles == 0
+        assert plan.fingerprint
+        assert len(snap.ir_fingerprint) == 32
+        # the same plan, looked up again, is the same program
+        again = plan_for_features(ds, model.result_features, model.fitted)
+        assert again.fingerprint == plan.fingerprint
+        assert snapshot_transform_plan(again, ds).ir_fingerprint \
+            == snap.ir_fingerprint
+        b = cost.buckets[-1]
+        assert b.flops > 0 and b.bytes_read + b.bytes_written > 0
+        assert b.peak_hbm_bytes > 0
+
+
 class TestTrainPathParity:
     def test_fused_train_matches_interpreted_train(self):
         """Whole-train parity: the fused fit path must select the same model

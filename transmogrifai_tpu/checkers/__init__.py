@@ -34,7 +34,6 @@ from .plancheck import (
     RecompileHazard,
     SegmentCost,
     analyze_scoring_plan,
-    analyze_transform,
     analyze_transform_plan,
     check_plan_cost,
     cost_diagnostics,
@@ -65,7 +64,6 @@ __all__ = [
     "analyze_files",
     "analyze_scoring_plan",
     "analyze_source",
-    "analyze_transform",
     "analyze_transform_plan",
     "build_corpus",
     "canonicalize_stablehlo",

@@ -279,7 +279,7 @@ DIAGNOSTIC_CODES: Dict[str, Tuple[Severity, str, str]] = {
     "TM702": (Severity.WARNING, "IR fusion/layout change",
               "the op histogram of a lowered program shifted (ops "
               "added/removed/recounted); performance and fusion structure "
-              "drifted — re-run the bench sections covering this family "
+              "drifted — re-run the chipbench cells covering this family "
               "before re-goldening"),
     "TM703": (Severity.WARNING, "IR collective/resharding drift",
               "cross-device collective or resharding ops were added or "

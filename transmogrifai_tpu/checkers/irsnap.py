@@ -1235,8 +1235,8 @@ def check_ir_corpus(goldens_dir: Optional[str] = None,
                     ) -> Tuple[CorpusDiff, Dict[str, IRSnapshot]]:
     """Snapshot the current program families and diff them against the
     golden corpus.  The main ``cli lint --ir`` entry point; returns the
-    structured diff plus the freshly built snapshots (for --update-goldens
-    and bench consumers)."""
+    structured diff plus the freshly built snapshots (for
+    --update-goldens)."""
     import jax
 
     goldens_dir = goldens_dir or default_goldens_dir()

@@ -18,7 +18,7 @@ trace's baked constants) and walks the jaxpr to produce a
   counts; elementwise/reduction ops at one flop per element),
 - **bytes read/written** per primitive (operand and result aval sizes — an
   upper bound: XLA fusion keeps many temporaries in registers, so the
-  measured traffic is lower; the bench calibration ratio quantifies this),
+  measured traffic is lower),
 - **arithmetic intensity** per fused segment (the Pallas-kernel worklist:
   a segment under the threshold is bandwidth-bound on any accelerator),
 - **peak live-buffer HBM estimate** per row bucket (linear-scan liveness
@@ -64,9 +64,8 @@ _ANALYZE_MEMO_LOCK = threading.Lock()
 _ANALYZE_MEMO_MAX = 128
 
 #: default arithmetic-intensity threshold (FLOPs per byte of HBM traffic)
-#: below which a segment is reported memory-bound (TM604).  Chosen from the
-#: bench evidence: the tree-hist thin path sits at ~0.06 HBM util / ~1 F/B,
-#: while the batched matmul regime runs >10 F/B.
+#: below which a segment is reported memory-bound (TM604): the thin
+#: tree-histogram path sits near 1 F/B, the batched matmul regime over 10.
 MEMORY_BOUND_INTENSITY = 2.0
 
 #: cross-device collective / resharding primitives (TM603 inventory)
@@ -519,8 +518,7 @@ class PlanCostReport:
     @property
     def collective_bytes_per_step(self) -> int:
         """Modeled cross-device collective traffic of one dispatch at the
-        largest analyzed bucket (the bench ``multihost`` section's
-        analyzer-predicted number)."""
+        largest analyzed bucket."""
         return self.buckets[-1].collective_bytes if self.buckets else 0
 
     def memory_bound_segments(self) -> List[SegmentCost]:
@@ -830,8 +828,8 @@ def analyze_program(fn, specs_per_bucket, label: str = "program"
 
     ``specs_per_bucket`` is ``[(bucket, [specs...]), ...]``; statics bind
     via ``functools.partial``/lambda before the call.  This is the entry the
-    TM608/TM609 scalability pass and the bench ``multihost`` section use to
-    cost the sharded fold x grid sweep programs (collective bytes per step,
+    TM608/TM609 scalability tests (tests/test_multihost.py) use to cost the
+    sharded fold x grid sweep programs (collective bytes per step,
     replicated operand bytes) at ZERO backend compiles."""
     return _analyze_fused(fn, list(specs_per_bucket), None, label)
 
@@ -884,18 +882,6 @@ def scalability_diagnostics(report: PlanCostReport,
             f"per-host budget — replication cannot be sharded away by "
             f"adding hosts"))
     return diags
-
-
-def analyze_transform(dataset, result_features, fitted) -> Optional[PlanCostReport]:
-    """Cost report of the fused transform plan ``transform_dag`` would run
-    over ``dataset`` (None when nothing fuses).  Bench cross-checks its
-    recorded FLOPs/bytes against this."""
-    from ..workflow.plan import plan_for_features
-
-    plan = plan_for_features(dataset, result_features, fitted)
-    if plan is None:
-        return None
-    return analyze_transform_plan(plan, dataset)
 
 
 # ---------------------------------------------------------------------------

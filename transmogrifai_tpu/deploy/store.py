@@ -16,8 +16,8 @@ the full ladder resident: ``boot_backend_compiles == 0``.
 
 Every decision is observable: ``artifact_hydrated`` / ``artifact_miss`` /
 ``artifact_refused`` flight events (obs/flight.py), process-wide hit/miss/
-refusal counters (``artifact_store_stats`` — the bench ``compile`` section
-reports them beside the persistent-cache traffic), and TM510 diagnostics
+refusal counters (``artifact_store_stats`` — ``cli deploy boot`` reports
+them in its summary), and TM510 diagnostics
 for every refusal.
 """
 
@@ -46,8 +46,7 @@ from .bundle import (
 log = logging.getLogger(__name__)
 
 #: process-wide warm-start accounting: where did executables come from?
-#: Reported by the bench ``compile`` section beside the persistent-cache
-#: hits/misses so BENCH artifacts show the deploy story end to end.
+#: Reported by ``cli deploy boot`` (``artifact_store``).
 _STATS: Dict[str, int] = {"hits": 0, "misses": 0, "refusals": 0, "packed": 0}
 _STATS_LOCK = threading.Lock()
 

@@ -133,9 +133,9 @@ class ModelSelector(PredictionEstimatorBase):
             PhaseRecorder, keep_fit_profile, phase, record_phases)
 
         # every fit records its own phase profile (about a hundred spans —
-        # cheap); ``last_fit_profile`` is how bench.py reports the per-phase
-        # breakdown of the ONE real fit instead of re-running the sweep in
-        # isolation, and the process-wide ring (``recent_fit_profiles``)
+        # cheap); ``last_fit_profile`` is how chipbench's entries report the
+        # per-phase breakdown of the ONE real fit (no sweep is re-run in
+        # isolation), and the process-wide ring (``recent_fit_profiles``)
         # keeps the last fits' whole spans for readers that come later.
         # record_phases nests: an ambient recorder (workflow fit) sees the
         # same spans.  TMOG_PROFILE captures the whole fit, from here to

@@ -71,8 +71,8 @@ DEFAULT_BINS = 32
 #: histogram-accumulation row-chunk size (see _grow_tree); module-level so
 #: tests can shrink it to exercise the chunked path on small data, and
 #: env-overridable (``TMOG_HIST_CHUNK``, read through the one tuning-knob
-#: helper ``perf.kernels.dispatch.tuning_int`` and recorded in the bench
-#: JSON provenance so BENCH rounds are self-describing about their tuning).
+#: helper ``perf.kernels.dispatch.tuning_int``; ``kernel_provenance()``
+#: reports the bound value and ``chip_smoke.py`` prints it).
 #: 2048 measured 3.8x faster than 8192 on v5e at 1M x 128 (64 bins): the
 #: per-step (chunk, B*d) bin one-hot operand is small enough for XLA to keep
 #: the one-hot -> matmul pipeline on-chip instead of spilling through HBM.
@@ -87,11 +87,6 @@ _HIST_CHUNK = _kdispatch.tuning_int("TMOG_HIST_CHUNK",
 #: step's matmul is small
 _HIST_UNROLL = _kdispatch.tuning_int("TMOG_HIST_UNROLL",
                                      _kdispatch.HIST_UNROLL_DEFAULT)
-
-#: forest-CV lane layout: True = vmap over folds with the T tree lanes
-#: folded into each fold's GEMM (k small batched GEMMs of M=T*nn*2K);
-#: False = all k*T lanes in ONE GEMM.  Measured on v5e (r5).
-_RF_FOLD_VMAP = False
 
 #: boosting reuses ONE materialized int8 bin one-hot across all rounds and
 #: levels instead of regenerating it per histogram pass — GBT's measured
@@ -892,29 +887,19 @@ def _forest_cv_program(binned, y, y_cols, train_w, val_w, feat_masks, boot_w,
     k, n = train_w.shape
     n_trees, _ = feat_masks.shape
     K = y_cols.shape[1]
-    if _RF_FOLD_VMAP:
-        def one_fold(w_):
-            return _fit_forest_impl(binned, y_cols, w_, max_depth, n_bins,
-                                    reg_lambda, min_child_weight,
-                                    feat_masks, boot_w, int_exact=int_exact)
-
-        trees, nodes = jax.vmap(one_fold)(train_w)       # (k, T, ...)
-        vals = jax.vmap(_node_lookup_l)(trees.value, nodes)  # (k, T, n, K)
-        mean = vals.sum(axis=1) / n_trees                # (k, n, K)
-    else:
-        wt = (train_w[:, None, :] * boot_w[None, :, :]
-              ).reshape(k * n_trees, n)
-        grad = -wt[:, :, None] * y_cols[None]
-        hess = wt[:, :, None] * jnp.ones((1, 1, K), jnp.float32)
-        masks = jnp.tile(feat_masks, (k, 1))
-        trees, nodes = _grow_trees(
-            binned, grad, hess, masks, jax.random.PRNGKey(0), max_depth,
-            n_bins, reg_lambda, 0.0, 0.0, min_child_weight, 1.0, 0.0,
-            int_exact=int_exact)
-        # in-sample votes read each lane's final row->leaf assignment from
-        # the grower — no re-traversal of the whole forest
-        vals = _node_lookup_l(trees.value, nodes)            # (k*T, n, K)
-        mean = vals.reshape(k, n_trees, n, K).sum(axis=1) / n_trees
+    # all k*T (fold, tree) lanes grow in ONE channel-batched GEMM
+    wt = (train_w[:, None, :] * boot_w[None, :, :]).reshape(k * n_trees, n)
+    grad = -wt[:, :, None] * y_cols[None]
+    hess = wt[:, :, None] * jnp.ones((1, 1, K), jnp.float32)
+    masks = jnp.tile(feat_masks, (k, 1))
+    trees, nodes = _grow_trees(
+        binned, grad, hess, masks, jax.random.PRNGKey(0), max_depth,
+        n_bins, reg_lambda, 0.0, 0.0, min_child_weight, 1.0, 0.0,
+        int_exact=int_exact)
+    # in-sample votes read each lane's final row->leaf assignment from
+    # the grower — no re-traversal of the whole forest
+    vals = _node_lookup_l(trees.value, nodes)            # (k*T, n, K)
+    mean = vals.reshape(k, n_trees, n, K).sum(axis=1) / n_trees
     if classification:
         if K == 1:
             payload = mean[..., 0]
@@ -1410,7 +1395,7 @@ class _ForestBase(_TreeEstimatorBase):
                          # targets: exact int8 when fold weights are 0/1 and
                          # targets are class indicators
                          int_exact=weights01 and self.classification),
-            key_extras=dict(fold_vmap=_RF_FOLD_VMAP, hist_chunk=_HIST_CHUNK,
+            key_extras=dict(hist_chunk=_HIST_CHUNK,
                             hist_unroll=_HIST_UNROLL),
             label=f"{type(self).__name__}/cv_program")
 

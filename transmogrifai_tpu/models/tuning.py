@@ -304,12 +304,11 @@ class CrossValidator:
     """
 
     def __init__(self, evaluator: Evaluator, num_folds: int = 3, seed: int = 42,
-                 stratify: bool = False, parallelism: int = 8):
+                 stratify: bool = False):
         self.evaluator = evaluator
         self.num_folds = num_folds
         self.seed = seed
         self.stratify = stratify
-        self.parallelism = parallelism
 
     def fold_ids(self, y: np.ndarray) -> np.ndarray:
         """(n,) fold each row is validated in: the assignment, as small
@@ -440,8 +439,8 @@ class CrossValidator:
         # after all programs are in flight.  The per-family gather span is the
         # family's residual device time after every earlier family drained —
         # in-order queue semantics make the SUM of dispatch+gather spans the
-        # true device-side cost of the sweep (bench reads these spans instead
-        # of re-running each family in isolation).
+        # true device-side cost of the sweep (chipbench's ``cv_dispatch_s``
+        # reads these spans; no family is re-run in isolation).
         evaluations: List[ModelEvaluation] = []
         failed_models: List[str] = []
         for est, grids, key, gather in dispatched:

@@ -10,8 +10,8 @@ locking discipline, which this one class provides:
 - ``load_seconds``  — total producer time spent staging work,
 - ``wait_seconds``  — total consumer time blocked on the hand-off buffer,
 - ``overlap_fraction`` — the share of producer time hidden behind the
-  consumer's own work (``1 - wait/load``); the bench ``ingest`` and
-  ``serve`` sections both gate on it.
+  consumer's own work (``1 - wait/load``); the chunk prefetcher and the
+  serve pipeline both report it.
 
 Two threads read-modify-write these fields (TM312) and the overlap ratio
 reads two of them together (TM314: a torn read of ``wait`` against a newer

@@ -13,9 +13,7 @@ tenant's p99 request slow?" from one ``trace.json``:
   export as ONE Chrome-trace ring slot (``Tracer.add_request_batch``; the
   per-request ``b``/``e`` async pairs and queue/total timing math
   materialize at export), each end event linking to its batch via
-  ``batch_seq`` — the per-request hot-path cost is one small tuple, which
-  is what keeps ``detail="requests"`` inside the bench's <5% overhead
-  gate.
+  ``batch_seq`` — the per-request hot-path cost is one small tuple.
 - :class:`BatchTrace` — ALWAYS minted by the batcher flusher (a slotted
   object plus a handful of phase marks per batch — the cost-accounting
   backbone works with telemetry off).  ``CompiledScoringPlan.score`` and

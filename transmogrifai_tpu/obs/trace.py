@@ -66,8 +66,7 @@ class Tracer:
         # HOT PATH is lock-free: events append as raw tuples (a bounded
         # deque append is GIL-atomic) and materialize into Chrome-trace
         # dicts only at export — on slow hosts the dict-per-event version
-        # measured ~4x the cost, which is what the <5% enabled-overhead
-        # bench gate polices
+        # measured ~4x the cost
         self._events: "deque[tuple]" = deque(maxlen=int(capacity))
         self._tids: Dict[int, str] = {}
         #: atomic append counter (itertools.count consumes in C under the
@@ -133,8 +132,8 @@ class Tracer:
 
         ``rows`` is ``[(rid, t_enqueue_mono, tenant, slo, outcome), ...]``.
         This is THE per-request hot path (it runs once per flushed batch
-        inside the serve loop the bench ``obs`` <5% requests-detail gate
-        polices), so the per-request cost is one small tuple append — all
+        inside the serve loop), so the per-request cost is one small tuple
+        append — all
         dict building, b/e pairing, and queue/total timing math happen at
         export time.
         """
@@ -272,8 +271,7 @@ def enabled() -> bool:
 class _Span:
     """Slotted class-based span context manager: ~2x cheaper than a
     generator-based ``@contextmanager`` on both the enabled and disabled
-    paths — this sits on the per-batch serve hot path, which the bench
-    ``obs`` section gates at <5% enabled overhead."""
+    paths — this sits on the per-batch serve hot path."""
 
     __slots__ = ("name", "cat", "args", "tracer", "token", "stack", "t0")
 

@@ -176,29 +176,6 @@ def global_mesh(n_model: int = 1, devices: Optional[Sequence] = None,
     return make_mesh(n_data=devs.size // n_model, n_model=n_model, devices=devs)
 
 
-def mesh_topology(mesh=None) -> dict:
-    """Self-describing topology block for bench/log provenance: the mesh
-    factoring plus the process layout it rides on (the ``multihost`` bench
-    section's provenance contract)."""
-    from .mesh import DATA_AXIS as _D
-    from .mesh import MODEL_AXIS as _M
-    from .mesh import current_mesh
-
-    mesh = mesh if mesh is not None else current_mesh()
-    out = {
-        "processCount": jax.process_count(),
-        "processIndex": jax.process_index(),
-        "localDevices": len(jax.local_devices()),
-        "globalDevices": jax.device_count(),
-        "platform": jax.default_backend(),
-    }
-    if mesh is not None:
-        out["meshShape"] = {a: int(mesh.shape[a]) for a in mesh.axis_names}
-        if _D in mesh.axis_names and _M in mesh.axis_names:
-            out["dp"], out["mp"] = int(mesh.shape[_D]), int(mesh.shape[_M])
-    return out
-
-
 def host_local_rows(n_global_rows: int) -> slice:
     """This process's contiguous row range for host-sharded ingest: each host
     reads only its slice of the input (the readers' multi-host contract)."""

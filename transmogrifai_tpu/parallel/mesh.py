@@ -48,11 +48,6 @@ def replicated(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, P())
 
 
-def model_sharding(mesh: Mesh) -> NamedSharding:
-    """Shard the leading (model/grid) axis over the model axis."""
-    return NamedSharding(mesh, P(MODEL_AXIS))
-
-
 def pad_axis(arr: np.ndarray, axis: int, multiple: int) -> Tuple[np.ndarray, int]:
     """Zero-pad one axis to a multiple (sharding needs even splits);
     returns (padded, n_valid along that axis)."""
@@ -75,25 +70,6 @@ def pad_host(arr: np.ndarray, pad_width) -> np.ndarray:
         return arr
     with activity("pad", nbytes=int(arr.nbytes)):
         return np.pad(arr, pad_width)
-
-
-def pad_rows(arr: np.ndarray, multiple: int) -> Tuple[np.ndarray, int]:
-    """Pad rows to a multiple; returns (padded, n_valid)."""
-    return pad_axis(arr, 0, multiple)
-
-
-def shard_rows(arr: np.ndarray, mesh: Optional[Mesh] = None):
-    """Place an array on device with its rows sharded over the data axis.
-
-    Pads rows to the data-axis size; returns (device_array, n_valid_rows).  Callers mask
-    with ``row_mask(n_padded, n_valid)`` so padded rows never contaminate statistics.
-    """
-    if mesh is None:
-        return jax.numpy.asarray(arr), arr.shape[0]
-    n_data = mesh.shape[DATA_AXIS]
-    padded, n_valid = pad_rows(np.asarray(arr), n_data)
-    out = jax.device_put(padded, row_sharding(mesh))
-    return out, n_valid
 
 
 def row_mask(n_padded: int, n_valid: int):

@@ -34,8 +34,8 @@ Contracts (acceptance: ISSUE 19):
   truncated JSON file, a schema-version mismatch, or a foreign device_kind
   all read as "no winner"; ``clear()`` removes entries.
 
-Sweeping is explicit or armed: ``ensure_tuned(..., sweep_on_miss=True)``,
-``cli tune run``, and the bench ``autotune`` section sweep directly;
+Sweeping is explicit or armed: ``ensure_tuned(..., sweep_on_miss=True)``
+and ``cli tune run`` sweep directly;
 setting ``TMOG_AUTOTUNE=1`` arms first-contact sweeps in ``ensure_tuned``.
 The kernel dispatchers themselves only ever consume cached winners (via
 ``kernel_param``) — a production trace never pays sweep time.

@@ -51,7 +51,7 @@ formulation — with the decision itself made observable and cacheable:
   ``hist_level``        selected only where the working set fits; at
                         d=128, 33 bins and the default 2048-row chunk the
                         (chunk, B*d) one-hot alone is 8.6-17 MB, so the
-                        bench/smoke shapes run the XLA scan.  Repaired in
+                        headline shapes run the XLA scan.  Repaired in
                         PR 21 for the int8 path (no int8 multiply or
                         sublane broadcast on the v5e vector unit).
   ====================  =================================================
@@ -65,8 +65,8 @@ formulation — with the decision itself made observable and cacheable:
   (at trace time), so a run can show which kernels it really used.
 - ``tuning_int()`` is the one helper every env-overridable tuning knob
   reads through (``TMOG_HIST_CHUNK``, ``TMOG_HIST_UNROLL``, the VMEM
-  budget); ``kernel_provenance()`` reports the live values so BENCH rounds
-  are self-describing about the tuning they ran under.
+  budget); ``kernel_provenance()`` reports the live values, so a run
+  (``chip_smoke.py`` prints them) says what tuning it ran under.
 """
 
 from __future__ import annotations
@@ -210,10 +210,9 @@ def serve_donation() -> bool:
 
 @contextmanager
 def force_serve_donation(flag: bool):
-    """Pin the serve-donation choice for a ``with`` block (parity tests and
-    the bench lockstep-vs-pipelined comparison run both variants in one
-    process).  Not re-entrant across threads — test-only, like
-    ``force_kernel_mode``."""
+    """Pin the serve-donation choice for a ``with`` block (parity tests run
+    both variants in one process).  Not re-entrant across threads —
+    test-only, like ``force_kernel_mode``."""
     global _FORCED_DONATION
     prev = _FORCED_DONATION
     _FORCED_DONATION = bool(flag)
@@ -342,7 +341,7 @@ def encode_mode(width: int, block_rows: int = 1024) -> Optional[str]:
 
 
 def kernel_provenance() -> Dict[str, Any]:
-    """Dispatch + tuning snapshot for BENCH JSON provenance.
+    """Dispatch + tuning snapshot (``chip_smoke.py`` prints it).
 
     ``hist_chunk``/``hist_unroll`` report the values BOUND into
     models/trees.py (import-time env resolution, the values traced programs

@@ -25,8 +25,8 @@ reference scan.
 
 :func:`hist_level_xla` is the standalone always-available reference — the
 same math as ``models/trees.py``'s in-place chunk scan (without the
-growth-loop-specific operand pre-chunking), used by the parity tests and
-``bench.py``'s ``pallas`` section as the comparison baseline.
+growth-loop-specific operand pre-chunking), used by the parity tests, the
+autotuner's verification and ``chip_smoke.py`` as the comparison baseline.
 """
 
 from __future__ import annotations
@@ -210,7 +210,7 @@ def hist_level_xla(local: jnp.ndarray, ghT: jnp.ndarray, binned: jnp.ndarray,
                    unroll: int = 1) -> jnp.ndarray:
     """The always-available XLA reference: the one-hot GEMM chunk scan of
     ``models/trees.py`` as a standalone function (same shapes/semantics as
-    :func:`hist_level_pallas`), for parity tests and the bench baseline."""
+    :func:`hist_level_pallas`), for parity tests and the autotuner."""
     L, n = local.shape
     two_k = ghT.shape[1]
     d = binned.shape[1]

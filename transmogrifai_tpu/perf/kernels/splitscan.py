@@ -10,7 +10,7 @@ This module holds the ONE definition of that math for the TPU port:
 
 - :func:`split_scan_xla` — the formulation ``models/trees.py`` historically
   inlined per level, moved here verbatim so the XLA path, the Pallas
-  kernel, the parity tests, and the bench baseline all share it;
+  kernel, the parity tests, and the autotuner all share it;
 - :func:`split_scan_pallas` — the fused kernel: grid over lanes, each step
   holds one lane block's (B, K, nn, d) histogram block in VMEM and produces
   the per-node best split index / gain / missing-direction without any of

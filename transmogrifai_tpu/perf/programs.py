@@ -16,9 +16,9 @@ This module makes that budget explicit and durable:
   processes — paired with JAX's persistent compilation cache
   (``enable_persistent_cache``) a warm process pays zero backend compiles.
 - ``program_cache_stats()`` exposes per-program compile counts, compile
-  seconds, and hits — the numbers ``bench.py`` reports in its ``compile``
-  section and tests assert against (compile-at-most-once-per-(family,
-  bucket)).
+  seconds, and hits — the numbers ``chipbench``'s selector entry snapshots
+  around a window and tests assert against (compile-at-most-once-per-
+  (family, bucket)).
 
 Shape discipline: callers pad sweep row counts to power-of-two buckets
 (``parallel.mesh.bucket_size`` — the serve/plan.py idea applied to training),
@@ -202,8 +202,8 @@ def run_cached(fn, *args, kwargs: Optional[Dict[str, Any]] = None,
     ``fn`` must be a ``jax.jit``-wrapped callable; ``statics`` are its
     static_argnames kwargs, ``kwargs`` its dynamic keyword operands.
     ``key_extras`` ride the cache key only — call sites thread module-level
-    lane-layout flags (``_RF_FOLD_VMAP``, ``_GBT_MAT_BINOH``) through here so
-    flipping a layout knob invalidates the cached executables it shaped.
+    layout and tuning flags (``_GBT_MAT_BINOH``, ``_HIST_CHUNK``) through here
+    so flipping one invalidates the cached executables it shaped.
     First call per key lowers + AOT-compiles (under the persistent
     compilation cache a warm process deserializes instead of compiling);
     later calls dispatch straight into the cached executable.  Falls back to
@@ -304,7 +304,8 @@ def evict_program_entries(fns) -> int:
 
 
 def program_cache_stats() -> Dict[str, Any]:
-    """Aggregate + per-program cache counters (bench ``compile`` section)."""
+    """Aggregate + per-program cache counters (``chipbench``'s entries and
+    ``chip_smoke.py`` read them)."""
     with _LOCK:
         entries = [s.to_dict() for s in _STATS.values()]
     return {

@@ -4,8 +4,8 @@ Phase timers: the selector fit, the cross-validator, and the workflow fit
 loop wrap their phases in ``phase("name")``.  Spans land in every active
 ``PhaseRecorder`` (recorders nest — the selector records its own fit profile
 while a caller's ambient recorder captures the same spans), so the ONE real
-fit yields the per-phase breakdown that ``bench.py`` used to obtain by
-re-running the whole sweep ~2 extra times.
+fit yields the per-phase breakdown (``last_fit_profile``); nothing is re-run
+in isolation to get a per-phase or per-family number.
 
 One span source, three sinks, one clock: past its early-out a span also
 enters a ``jax.profiler.TraceAnnotation``, so whenever a profiler capture is
@@ -65,8 +65,8 @@ class PhaseRecorder:
     Paths are RELATIVE to the recorder's activation point: a recorder opened
     inside ``phase("fit.modelSelector")`` records the selector's "validate"
     span as ``validate``, while an outer recorder sees the same span as
-    ``fit.modelSelector.validate`` — so consumers (bench's selector
-    breakdown) parse stable paths regardless of how deep the fit ran.
+    ``fit.modelSelector.validate`` — so consumers (``chipbench``'s span
+    readers) parse stable paths regardless of how deep the fit ran.
     """
 
     def __init__(self):

@@ -14,7 +14,7 @@ consumer, one being staged).  :class:`PrefetchStats` records, per run,
 - ``load_seconds``  — total worker time spent producing chunks,
 - ``wait_seconds``  — total consumer time blocked on the queue,
 - ``overlap_fraction`` — the share of ingest time hidden behind compute
-  (``1 - wait/load``); the bench ``ingest`` section gates on it.
+  (``1 - wait/load``); ``EpochStats.prefetch`` carries it per epoch.
 """
 
 from __future__ import annotations
@@ -47,7 +47,8 @@ def prefetch_depth() -> int:
 
 
 class PrefetchStats(OverlapStats):
-    """Counters of one prefetched iteration (bench ``ingest`` section).
+    """Counters of one prefetched iteration (``EpochStats.prefetch``,
+    ``CompiledScoringPlan.last_prefetch``).
 
     The shared accumulator lives in :class:`~..obs.overlap.OverlapStats`
     (the serve pipeline reports the same metric through the same class):
@@ -118,7 +119,7 @@ class ChunkPrefetcher:
         ci, item, err = self._q.get()
         wait = time.perf_counter() - t0
         # the device-dispatch side outran the ingest side: record the
-        # starvation (the bench ingest overlap gate's runtime twin).
+        # starvation (``stalls``, and a ``prefetch_stall`` flight event).
         # Error/end-of-stream rows are excluded — a wait for the
         # sentinel is not a stall on any real chunk.
         stalled = empty and wait > _STALL_THRESHOLD_S and err is None \

@@ -6,8 +6,7 @@ PR 13 reproduced the pattern for ingest (readers/prefetch.py), and this
 module applies it to the serving hot path (ISSUE 18): while batch N's
 device dispatch + host remainder FINALIZE on a dedicated thread, the
 flusher thread ENCODES batch N+1 and fires its async device dispatch — the
-device hides the host time that BENCH_r06 showed dominating each lockstep
-flush.
+device hides the host time of each lockstep flush.
 
 Pieces:
 

@@ -13,7 +13,7 @@ This module is that registry for the compiled serving engine:
   content-addressed executable cache (serve/plan.py): identical plans
   across tenants compile ONCE — the registry counts registrations whose
   plan fingerprint was already resident (``shared_prefix_registrations``,
-  the fleet-wide compile-amortization figure the bench gates on).
+  the fleet-wide compile-amortization figure).
 - **HBM admission/eviction** — on ``register()``/``stage_candidate()`` the
   registry sums TM601-style static peak-HBM estimates
   (checkers/plancheck.py, zero backend compiles) across every DISTINCT

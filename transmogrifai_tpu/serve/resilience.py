@@ -143,7 +143,7 @@ class CircuitBreaker:
             if self.state == self.OPEN:
                 self._host_since_open += 1
 
-    # -- operator overrides (bench degraded-mode measurement, drills) --------
+    # -- operator overrides (degraded-mode drills) ---------------------------
     def force_open(self) -> None:
         """Pin the breaker open (no half-open probes) until force_close()."""
         with self._lock:

@@ -239,7 +239,7 @@ def check_precision_parity(f32_plan, candidate_plan, *,
     ``register()``/``stage_candidate()`` admission and refuses on error,
     fail-closed: a class whose bound is unknown is refused too.  The
     measured delta lands on the report (``max_precision_delta``) so
-    statusz/bench can surface it.
+    statusz can surface it.
     """
     import numpy as np
 

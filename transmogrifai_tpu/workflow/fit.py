@@ -116,8 +116,8 @@ def fit_stage_list(dataset: Dataset, stages, fitted: Dict[str, Transformer],
     ``TMOG_FUSED_TRANSFORM=0`` / an active listener keep the per-stage path.
 
     Each stage's fit/transform also lands as a perf phase span (no-op unless
-    a ``perf.timers.record_phases`` recorder is active — bench and callers
-    profiling a train get per-stage wall time from the one real fit).
+    a ``perf.timers.record_phases`` recorder is active — callers profiling
+    a train get per-stage wall time from the one real fit).
 
     A :class:`~..data.chunked.ChunkedDataset` routes to the out-of-core
     twin (workflow/ooc.py): fused epochs per chunk with spilled outputs,

@@ -45,7 +45,8 @@ log = logging.getLogger(__name__)
 
 @dataclass
 class EpochStats:
-    """What one chunked epoch did (bench ``ingest`` section evidence)."""
+    """What one chunked epoch did: chunk counts, bytes spilled, and the
+    prefetcher's overlap accounting."""
 
     chunks_total: int = 0
     chunks_skipped: int = 0

@@ -212,8 +212,8 @@ def run_host_stages(dataset: Dataset, runners: Sequence[Any],
 
     ``phases=False`` skips the per-stage phase spans: the serving hot path
     passes it when a tracer is installed at the default ``batch`` detail,
-    keeping the per-flush telemetry cost inside the bench ``obs`` gate
-    (the enclosing ``serve.host`` span still times the whole remainder).
+    keeping the per-flush telemetry cost down (the enclosing
+    ``serve.host`` span still times the whole remainder).
     """
     from ..perf.timers import phase
 
@@ -776,9 +776,9 @@ def plan_for_features(dataset: Dataset, result_features, fitted
                       ) -> Optional[ColumnarTransformPlan]:
     """The fused transform plan ``transform_dag`` would dispatch for a
     fitted workflow over ``dataset`` (None when nothing fuses or any stage
-    is unfitted).  The one derivation shared by the static analyzers
-    (plancheck/irsnap) and bench, so they all cost/fingerprint the SAME
-    program the planner runs."""
+    is unfitted): the continual refit's prefix-reuse check and the tests
+    that cost or fingerprint a plan derive it here, so they all see the
+    SAME program the planner runs."""
     from .dag import compute_dag
     from .fit import _resolve
 
