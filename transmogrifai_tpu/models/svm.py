@@ -25,33 +25,48 @@ from .logistic import _device_prepare_fit, place_fit_arrays  # noqa: F401
 from .prediction import PredictionColumn
 
 
-def _svc_body(x: jnp.ndarray, y_pm: jnp.ndarray, w: jnp.ndarray, reg: jnp.ndarray,
-              max_iter: int, has_intercept: bool = True) -> jnp.ndarray:
-    """Squared-hinge descent; y in {-1, +1}.  With ``has_intercept`` the
-    trailing ones column is exempt from L2 (it IS the intercept); without it
-    every column is a real feature and all are regularized."""
-    n, d1 = x.shape
-    sw = jnp.maximum(w.sum(), 1e-12)
+def _momentum_descent(loss_grad, curvature, d1: int, reg, max_iter: int,
+                      has_intercept: bool, dtype) -> jnp.ndarray:
+    """The update rule the refit and the sweep share: ``max_iter`` steps of
+    momentum 0.9 from zero on ``loss + reg/2 ||beta||^2``, the intercept slot
+    (last, with ``has_intercept``) exempt from the L2 term, step size
+    ``1 / max(curvature + reg, 1e-6)``.  ``loss_grad(beta)`` is the averaged
+    loss's gradient; ``curvature()`` its Lipschitz bound, a callable so the
+    trace keeps the refit's op order (its module stays byte-equal)."""
     reg_mask = (jnp.ones(d1).at[-1].set(0.0) if has_intercept
                 else jnp.ones(d1))
-    # Lipschitz bound for the step size: squared hinge curvature <= 2 ||x||^2
-    lip = 2.0 * (w[:, None] * x * x).sum() / sw + reg
+    lip = curvature() + reg
     lr = 1.0 / jnp.maximum(lip, 1e-6)
 
     def step(_, state):
         beta, vel = state
         with jax.named_scope("svc_step"):
-            z = x @ beta
-            margin = 1.0 - y_pm * z
-            active = jnp.maximum(margin, 0.0)
-            g = x.T @ (w * (-2.0 * y_pm * active)) / sw \
-                + reg * reg_mask * beta
+            g = loss_grad(beta) + reg * reg_mask * beta
             vel_new = 0.9 * vel - lr * g
             return beta + vel_new, vel_new
 
-    beta0 = jnp.zeros(d1, dtype=x.dtype)
+    beta0 = jnp.zeros(d1, dtype=dtype)
     beta, _ = jax.lax.fori_loop(0, max_iter, step, (beta0, beta0))
     return beta
+
+
+def _svc_body(x: jnp.ndarray, y_pm: jnp.ndarray, w: jnp.ndarray, reg: jnp.ndarray,
+              max_iter: int, has_intercept: bool = True) -> jnp.ndarray:
+    """Squared-hinge descent; y in {-1, +1}.  With ``has_intercept`` the
+    trailing ones column is exempt from L2 (it IS the intercept); without it
+    every column is a real feature and all are regularized."""
+    sw = jnp.maximum(w.sum(), 1e-12)
+
+    def hinge_grad(beta):
+        z = x @ beta
+        margin = 1.0 - y_pm * z
+        active = jnp.maximum(margin, 0.0)
+        return x.T @ (w * (-2.0 * y_pm * active)) / sw
+
+    # Lipschitz bound for the step size: squared hinge curvature <= 2 ||x||^2
+    return _momentum_descent(
+        hinge_grad, lambda: 2.0 * (w[:, None] * x * x).sum() / sw,
+        x.shape[1], reg, max_iter, has_intercept, x.dtype)
 
 
 _svc_core = partial(jax.jit,
@@ -69,45 +84,106 @@ def _sign_targets(y, n_valid):
         jnp.where(jnp.arange(y.shape[0]) < n_valid, y_pm, 0.0))
 
 
+def _svc_shared_block_fit(x, y_pm, train_w, val_w, regs, max_iter: int,
+                          has_intercept: bool):
+    """Every (fold, reg) lane of the sweep fitted over ONE standardised block.
+
+    Returns ``(xg, mean, std, coefs)``: ``xg`` (n, d+1) is ``x`` standardised
+    once with the unit-weight moments ``mean``, ``std`` of the rows some fold
+    reads, ones column last; ``coefs`` (k, g, d+1) are the lanes' solutions in
+    ``xg``'s space, so a lane's margins are ``xg @ coefs[f, r]``.
+
+    Fold ``f`` standardises with its own train-weighted moments, as
+    ``_fit_arrays`` does.  That is an affine map of ``xg``'s columns,
+    ``xs_f = (xg - c_f) * a_f``, so it sits on a lane's d+1 coefficients and
+    not on a copy of the table: ``xs_f @ beta = xg @ T_f(beta)`` and
+    ``xs_f.T @ r = T_f'(xg.T @ r)``, the ones column carrying the offset
+    ``-c_f . (a_f * beta)`` one way and ``sum(r)`` the other.  The iterates
+    are ``_svc_body``'s on ``xs_f`` in exact arithmetic.  Centring once keeps
+    the MXU's bfloat16 operand rounding where a per-fold copy had it.
+    """
+    from ..parallel.mesh import constrain_rows
+
+    d = x.shape[1]
+    d1 = d + 1 if has_intercept else d
+    some_fold = ((train_w.sum(0) + val_w.sum(0)) > 0).astype(x.dtype)
+    xg, mean, std = _device_prepare_fit(x, some_fold, has_intercept=True,
+                                        standardize=True)
+    xg = constrain_rows(xg)
+
+    def fold_moments(w):
+        sw = jnp.maximum(w.sum(), 1e-12)
+        mean_f = (w[:, None] * x).sum(0) / sw
+        return mean_f, (w[:, None] * (x - mean_f) ** 2).sum(0) / sw
+
+    with jax.named_scope("fold_standardize"):
+        # the fold's moments of the RAW columns, fold by fold (no (k, n, d)
+        # value): a column constant on a fold's train rows reads a variance
+        # of exactly 0 there, which moments of the centred block would not
+        mean_f, var_f = (jnp.stack(m) for m in
+                         zip(*[fold_moments(w) for w in train_w]))
+        scaled = var_f > 0
+        a = std / jnp.where(scaled, jnp.sqrt(var_f), 1.0)
+        c = (mean_f - mean) / std
+        # sum_i w_i xs_ij^2 / sw is 1 for a column the fold scaled, 0 for one
+        # it could not, 1 for the ones column: 2 ||xs||^2 without a pass
+        curvature = 2.0 * (scaled.sum(1) + (1.0 if has_intercept else 0.0))
+
+    def one_fold(w, a, c, curvature):
+        sw = jnp.maximum(w.sum(), 1e-12)
+
+        def to_block(beta):
+            ab = a * beta[:d]
+            b = beta[d] if has_intercept else 0.0
+            return jnp.concatenate([ab, (b - (c * ab).sum())[None]])
+
+        def from_block(gx):
+            g = a * (gx[:d] - c * gx[d])
+            return jnp.concatenate([g, gx[d:]]) if has_intercept else g
+
+        def hinge_grad(beta):
+            z = xg @ to_block(beta)
+            active = jnp.maximum(1.0 - y_pm * z, 0.0)
+            return from_block(xg.T @ (w * (-2.0 * y_pm * active))) / sw
+
+        def one_grid(reg):
+            return to_block(_momentum_descent(
+                hinge_grad, lambda: curvature, d1, reg, max_iter,
+                has_intercept, x.dtype))
+
+        return jax.vmap(one_grid)(regs)
+
+    return xg, mean, std, jax.vmap(one_fold)(train_w, a, c, curvature)
+
+
 @partial(jax.jit, static_argnames=("max_iter", "has_intercept", "metric_fn"))
 def _svc_cv_program(x, y, y_pm, train_w, val_w, regs, max_iter: int,
                     has_intercept: bool, metric_fn):
     """The whole (grid x fold) SVC sweep in one XLA program.
 
-    Standardization happens per fold ON DEVICE with the fold's train weights
-    (matching _fit_arrays), then the grid vmaps over regs and folds vmap over
-    weights; metrics evaluate on the fold margins without leaving the chip.
-    Mirrors the reference's all-fold concurrency (OpCrossValidation.scala:114).
+    All lanes read one standardised block; each fold's own standardisation
+    (its train weights, matching _fit_arrays) is an affine map on the lane's
+    coefficients (:func:`_svc_shared_block_fit`).  Metrics evaluate on the
+    fold margins without leaving the chip.  Mirrors the reference's all-fold
+    concurrency (OpCrossValidation.scala:114).
 
     dp x mp sharding rides ambient ``with_sharding_constraint`` annotations
     (identity off-mesh): row operands pin to the data axis so the per-fold
-    standardization/descent psums carry only (d,)-sized statistics.
+    moments and the descent's psums carry only (d,)-sized statistics.
     """
     from ..parallel.mesh import constrain_fold_rows, constrain_rows
 
     x, y, y_pm = constrain_rows(x), constrain_rows(y), constrain_rows(y_pm)
     train_w = constrain_fold_rows(train_w)
     val_w = constrain_fold_rows(val_w)
+    xg, _, _, coefs = _svc_shared_block_fit(x, y_pm, train_w, val_w, regs,
+                                            max_iter, has_intercept)
 
-    def one_fold(w, vw):
-        with jax.named_scope("fold_standardize"):
-            sw = jnp.maximum(w.sum(), 1e-12)
-            mean = (w[:, None] * x).sum(0) / sw
-            var = (w[:, None] * (x - mean) ** 2).sum(0) / sw
-            std = jnp.where(var > 0, jnp.sqrt(var), 1.0)
-            xs = (x - mean) / std
-            if has_intercept:
-                xs = jnp.concatenate(
-                    [xs, jnp.ones((x.shape[0], 1), x.dtype)], 1)
+    def one_fold(fold_coefs, vw):
+        return jax.vmap(lambda bg: metric_fn(xg @ bg, y, vw))(fold_coefs)
 
-        def one_grid(reg):
-            beta = _svc_body(xs, y_pm, w, reg, max_iter, has_intercept)
-            with jax.named_scope("eval_sort"):
-                return metric_fn(xs @ beta, y, vw)
-
-        return jax.vmap(one_grid)(regs)
-
-    return jax.vmap(one_fold)(train_w, val_w).T  # (grids, folds)
+    with jax.named_scope("eval_sort"):
+        return jax.vmap(one_fold)(coefs, val_w).T  # (grids, folds)
 
 
 class LinearSVC(PredictionEstimatorBase):
@@ -146,8 +222,10 @@ class LinearSVC(PredictionEstimatorBase):
 
     def _cv_sweep_device(self, x, y, train_w, val_w,
                          grids: List[Dict[str, Any]], metric_fn):
-        """Fold-vmapped sweep: the whole (grid x fold) program runs on device
-        (per-fold standardization included), one compile keyed on the metric.
+        """The whole (grid x fold) sweep as one device program, every lane
+        over one shared block (per-fold standardization included, on the
+        lanes' coefficients), one compile keyed on the metric; the launch
+        span counts the lanes that shared the block.
 
         The vectorized program only varies reg_param; grids touching any other
         param (max_iter, fit_intercept, ...) take the generic per-grid path so
@@ -176,7 +254,8 @@ class LinearSVC(PredictionEstimatorBase):
             statics=dict(max_iter=int(self.max_iter),
                          has_intercept=bool(self.fit_intercept),
                          metric_fn=metric_fn),
-            label="LinearSVC/cv_program")
+            label="LinearSVC/cv_program",
+            counts=dict(shared_block_lanes=len(grids) * int(tw.shape[0])))
 
 
 class LinearSVCModel(PredictionModelBase):

@@ -195,7 +195,8 @@ def cache_key_fingerprint(fn, *args, kwargs: Optional[Dict[str, Any]] = None,
 def run_cached(fn, *args, kwargs: Optional[Dict[str, Any]] = None,
                statics: Optional[Dict[str, Any]] = None,
                key_extras: Optional[Dict[str, Any]] = None,
-               label: Optional[str] = None):
+               label: Optional[str] = None,
+               counts: Optional[Dict[str, Any]] = None):
     """Dispatch ``fn(*args, **kwargs, **statics)`` through the process-wide
     AOT cache.
 
@@ -204,6 +205,7 @@ def run_cached(fn, *args, kwargs: Optional[Dict[str, Any]] = None,
     ``key_extras`` ride the cache key only — call sites thread module-level
     layout and tuning flags (``_GBT_MAT_BINOH``, ``_HIST_CHUNK``) through here
     so flipping one invalidates the cached executables it shaped.
+    ``counts`` ride the dispatch's ``host.launch`` span beside its label.
     First call per key lowers + AOT-compiles (under the persistent
     compilation cache a warm process deserializes instead of compiling);
     later calls dispatch straight into the cached executable.  Falls back to
@@ -221,6 +223,7 @@ def run_cached(fn, *args, kwargs: Optional[Dict[str, Any]] = None,
     """
     kwargs = kwargs or {}
     statics = statics or {}
+    counts = counts or {}
     with activity("program_key"):
         key, fp, shapes = _make_key(fn, args, kwargs, statics,
                                     key_extras or {})
@@ -261,7 +264,7 @@ def run_cached(fn, *args, kwargs: Optional[Dict[str, Any]] = None,
                 stats.compile_seconds += time.perf_counter() - t0
                 stats.backend_compiles += backend
                 try:
-                    with activity("launch", label=stats.label):
+                    with activity("launch", label=stats.label, **counts):
                         out = compiled(*args, **kwargs)
                 except TypeError as e:
                     # statics that are NOT static_argnames of fn end up in
@@ -279,7 +282,7 @@ def run_cached(fn, *args, kwargs: Optional[Dict[str, Any]] = None,
                 return out
     with _LOCK:
         stats.hits += 1
-    with activity("launch", label=stats.label):
+    with activity("launch", label=stats.label, **counts):
         return compiled(*args, **kwargs)
 
 
