@@ -9,6 +9,7 @@ NCCL-style calls.
 
 from __future__ import annotations
 
+import contextlib
 import os
 from typing import Optional, Sequence, Tuple
 
@@ -622,6 +623,35 @@ class fit_placements:
 
     def __exit__(self, *exc) -> None:
         _FIT_PLACED.reset(self._token)
+
+
+@contextlib.contextmanager
+def ensure_fit_placements():
+    """The open fit's table, or one for the length of the block where none
+    is open: a family's sweep called from a selector's fit shares the fit's,
+    called on its own it has one for its grid points."""
+    if _FIT_PLACED.get() is not None:
+        yield
+        return
+    with fit_placements():
+        yield
+
+
+def fit_shared(kind, source, build):
+    """What a fit derives on the device from a placed ``source`` and hands to
+    several of its programs (the tree families' bin one-hot: every grid
+    point's sweep and the winner's refit read one).  ``build()`` runs at the
+    first request of a fit; later requests for the same ``kind`` and source
+    OBJECT get the same array, which dies with the fit's table.  Outside any
+    fit every request builds."""
+    table = _FIT_PLACED.get()
+    key = ("shared", kind, id(source))
+    if table is not None and key in table:
+        return table[key][1]
+    built = build()
+    if table is not None:
+        table[key] = (source, built)
+    return built
 
 
 def _count_passed(arr) -> None:

@@ -272,17 +272,20 @@ def vmem_budget() -> int:
     return tuning_int("TMOG_PALLAS_VMEM_BUDGET", _DEFAULT_VMEM_BUDGET)
 
 
-def _admit(kernel: str, working_set_bytes: int) -> Optional[str]:
+def _admit(kernel: str, working_set_bytes: int, counted: bool = True
+           ) -> Optional[str]:
     """Mode for ``kernel`` at a VMEM working set of ``working_set_bytes``:
     None = run the XLA reference path.  The decision is counted
-    (:func:`kernel_selections`)."""
+    (:func:`kernel_selections`) unless the caller only asks what a trace
+    would decide (``counted=False``: a dispatch site's span counts)."""
     mode = kernel_mode()
     if mode == "xla" or (mode == "pallas"
                          and working_set_bytes > vmem_budget()):
         mode = None
-    with _SELECTIONS_LOCK:
-        key = (kernel, mode or "xla")
-        _SELECTIONS[key] = _SELECTIONS.get(key, 0) + 1
+    if counted:
+        with _SELECTIONS_LOCK:
+            key = (kernel, mode or "xla")
+            _SELECTIONS[key] = _SELECTIONS.get(key, 0) + 1
     return mode
 
 
@@ -294,7 +297,7 @@ def kernel_selections() -> Dict[str, int]:
 
 
 def hist_mode(m_rows: int, bd_cols: int, chunk: int, lanes_bytes_per_row: int,
-              elem_bytes: int = 1) -> Optional[str]:
+              elem_bytes: int = 1, counted: bool = True) -> Optional[str]:
     """Dispatch decision for the histogram kernel: the VMEM working set is
     the (M, B*d) accumulator + the per-chunk (M, chunk) activation +
     (chunk, B*d) bin one-hot + streamed operand blocks.  ``elem_bytes`` is
@@ -305,7 +308,7 @@ def hist_mode(m_rows: int, bd_cols: int, chunk: int, lanes_bytes_per_row: int,
           + m_rows * chunk * elem_bytes         # activation
           + chunk * bd_cols * elem_bytes        # bin one-hot
           + chunk * lanes_bytes_per_row)        # local + gh + codes blocks
-    return _admit("hist", ws)
+    return _admit("hist", ws, counted)
 
 
 def split_mode(block_bytes: int) -> Optional[str]:
