@@ -407,7 +407,8 @@ def phase_serve(model, df, prediction, scored_pred):
 
 def phase_kernels(mode: str, rf_depth: int) -> Dict[str, str]:
     """Compile each Pallas kernel ``auto`` can select at the shapes this
-    run's train produced and compare it with its XLA twin (the autotuner's
+    run's train produced, and the routing kernel the grower no longer
+    reaches (PR 33), and compare it with its XLA twin (the autotuner's
     kernel + reference pairs).  Bitwise on the integer fixtures."""
     from transmogrifai_tpu.perf import autotune
 
@@ -532,9 +533,13 @@ def run(rows: int = ROWS, rf_trees: int = 50, gbt_rounds: int = 50,
         result["kernels"] = phase_kernels(mode, max(rf_depths))
         # ... and the train/serve programs really contained the kernels the
         # dispatch table says this mode selects at these shapes
-        for kernel in ("split", "route", "encode"):
+        for kernel in ("split", "encode"):
             check(selected.get(f"{kernel}:{mode}", 0) > 0,
                   f"{kernel} kernel was never selected as {mode}: {selected}")
+        # ... but for routing, which the grower runs as the XLA compare-reduce
+        # in every mode (phase_kernels compared the kernel it names itself)
+        check(selected.get("route:xla", 0) > 0,
+              f"the grower's routing was never counted as xla: {selected}")
     snap = compile_snapshot()
     facts.update({
         "total_wall_s": round(time.perf_counter() - t_start, 1),

@@ -32,10 +32,13 @@ class TestSmokeBody:
         facts = out["setup_facts"]
         assert facts["second_train_backend_compiles"] == 0
         assert facts["serve_vs_score_max_delta"] == 0.0   # bitwise on CPU
-        # the interpret-mode Pallas kernels really ran, and matched
+        # the interpret-mode Pallas kernels really ran, and matched; the
+        # grower's routing is the XLA compare-reduce in every mode (PR 33)
         picked = out["kernels_selected"]
-        for kernel in ("split", "route", "encode", "hist"):
+        for kernel in ("split", "encode", "hist"):
             assert picked.get(f"{kernel}:interpret", 0) > 0, picked
+        assert picked.get("route:xla", 0) > 0, picked
+        assert picked.get("route:interpret", 0) == 0, picked
         assert len(out["kernels"]) == 6
         assert set(out["kernels"].values()) == {"matches"}
         # the verdict the driver parses: these keys and no others
@@ -169,11 +172,35 @@ def test_kernel_cross_lowers_for_tpu(name):
 
 
 # ---------------------------------------------------------------------------
-# ... and the TPU compiler itself accepts them.  libtpu can compile for a v5e
-# without one (a compile-only topology), which is where Mosaic's refusals
-# surface: the int8 multiply, the scoped-VMEM limit.  A child process, so
-# libtpu never loads into the test session; skipped where libtpu cannot
-# describe the topology.
+# The grower's routing entry is NOT among them (PR 33): with compiled Pallas
+# forced it lowers for TPU to plain XLA at the boosted cell's shapes (2^20 x
+# 128 codes; the sweep's three lanes, the refit's one) and at the 128 lanes
+# the kernel used to be admitted for, and counts itself as ``route:xla``.
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("lanes", [chip_smoke.FOLDS, 1, 128])
+def test_grower_routing_lowers_to_plain_xla_for_tpu(lanes):
+    from transmogrifai_tpu.perf.kernels import dispatch as KD
+    from transmogrifai_tpu.perf.kernels.routing import row_select_lanes
+
+    specs = (S((2 ** 20, D), I32), S((lanes, 2 ** 20), I32))
+    before = KD.kernel_selections()
+    with KD.force_kernel_mode("pallas"):
+        text = jax.jit(row_select_lanes).trace(*specs).lower(  # opcheck: allow(TM303) test
+            lowering_platforms=("tpu",)).as_text()
+    assert "tpu_custom_call" not in text
+    moved = {k: v - before.get(k, 0) for k, v in KD.kernel_selections().items()
+             if k.startswith("route:") and v != before.get(k, 0)}
+    assert moved == {"route:xla": 1}
+
+
+# ---------------------------------------------------------------------------
+# ... and the TPU compiler itself accepts the kernels of TPU_KERNELS.  libtpu
+# can compile for a v5e without one (a compile-only topology), which is where
+# Mosaic's refusals surface: the int8 multiply, the scoped-VMEM limit.  A
+# child process, so libtpu never loads into the test session; skipped where
+# libtpu cannot describe the topology.
 # ---------------------------------------------------------------------------
 
 _MOSAIC_SCRIPT = r"""

@@ -501,16 +501,24 @@ class TestRoutingParity:
             np.testing.assert_array_equal(ker, ref)
 
     def test_dispatcher_honors_mode_and_trees_alias(self):
+        """The grower's entry is the XLA form in EVERY mode (PR 33), and
+        says so: one ``route:xla`` a call, never a kernel selection."""
         from transmogrifai_tpu.perf.kernels import routing as KR
 
         binned, idx = self._fixture(seed=3)
         ref = np.asarray(KR.row_select_lanes_xla(jnp.asarray(binned),
                                                  jnp.asarray(idx)))
-        with KD.force_kernel_mode("interpret"):
-            out = np.asarray(KR.row_select_lanes(jnp.asarray(binned),
-                                                 jnp.asarray(idx)))
-        np.testing.assert_array_equal(out, ref)
-        # trees' sweep fold-take path routes through the ONE dispatcher
+        for mode in ("xla", "interpret", "pallas"):
+            before = KD.kernel_selections()
+            with KD.force_kernel_mode(mode):
+                out = np.asarray(KR.row_select_lanes(jnp.asarray(binned),
+                                                     jnp.asarray(idx)))
+            np.testing.assert_array_equal(out, ref, err_msg=mode)
+            moved = {k: v - before.get(k, 0)
+                     for k, v in KD.kernel_selections().items()
+                     if k.startswith("route:") and v != before.get(k, 0)}
+            assert moved == {"route:xla": 1}, (mode, moved)
+        # trees' sweep fold-take path routes through the ONE entry
         assert T._row_select_l is KR.row_select_lanes
         assert T._row_select is KR.row_select_xla
 
@@ -536,12 +544,22 @@ class TestRoutingParity:
         np.testing.assert_array_equal(np.asarray(nx), np.asarray(ni))
 
     def test_vmem_admission_falls_back(self, monkeypatch):
+        """``route_mode`` still answers a caller that names the kernel; the
+        grower's entry no longer asks it, at a shape it admits or refuses."""
+        from transmogrifai_tpu.perf.kernels import routing as KR
         from transmogrifai_tpu.perf.kernels.dispatch import route_mode
 
         monkeypatch.setenv("TMOG_PALLAS", "pallas")
         assert route_mode(8, 2) == "pallas"
         # a lane/feature product far past any VMEM budget must fall back
         assert route_mode(4096, 4096) is None
+        monkeypatch.setattr(KR, "row_select_lanes_pallas", None)  # unreached
+        for d, L in ((8, 2), (40, 130)):
+            binned, idx = self._fixture(seed=d, n=64, d=d, L=L)
+            np.testing.assert_array_equal(
+                np.asarray(KR.row_select_lanes(jnp.asarray(binned),
+                                               jnp.asarray(idx))),
+                np.stack([binned[np.arange(64), idx[l]] for l in range(L)]))
 
 
 @pytest.mark.slow

@@ -73,7 +73,7 @@ def test_a_boosted_fit_records_its_bin_span_and_launch_counts(small_chunks):
     launches = _launches(profile)
     assert set(launches) == {CV, REFIT}
     want = {"rounds": ROUNDS, "levels": DEPTH, "hist_kernel": "xla",
-            "binoh_bytes": rows * 33 * D}
+            "route_kernel": "xla", "binoh_bytes": rows * 33 * D}
     assert rows % 128 == 0 and rows >= N
     assert launches[CV] == {"label": CV, "lanes": 3, **want}
     assert launches[REFIT] == {"label": REFIT, "lanes": 1, **want}
@@ -118,11 +118,13 @@ def test_the_counts_name_the_pallas_kernel_where_it_is_admitted():
     with KD.force_kernel_mode("interpret"):
         counts = est._launch_counts(codes, 3, 1)
     assert counts["hist_kernel"] == "hist_level_pallas"
+    assert counts["route_kernel"] == "xla"  # in every mode
     assert counts["binoh_bytes"] == 0       # the kernel builds its own
     with KD.force_kernel_mode("xla"):
         counts = est._launch_counts(codes, 3, 1)
     assert counts == {"lanes": 3, "rounds": 2, "levels": 2,
-                      "hist_kernel": "xla", "binoh_bytes": 8192 * 33 * 4}
+                      "hist_kernel": "xla", "route_kernel": "xla",
+                      "binoh_bytes": 8192 * 33 * 4}
 
 
 def test_the_shared_one_hot_changes_no_bit_and_is_built_once_a_sweep(

@@ -355,11 +355,10 @@ def _node_lookup(tbl: jnp.ndarray, node: jnp.ndarray) -> jnp.ndarray:
     return (oh[:, :, None] * tbl[None, :, :]).sum(axis=1)            # (n, K)
 
 
-#: binned[i, idx[l, i]] per lane — the sweep fold-take routing pass, now the
-#: DISPATCHED entry of perf/kernels/routing.py: compiled Pallas on TPU (VMEM
-#: admission guarded), the shared XLA compare-reduce elsewhere; interpret
-#: mode pins bitwise parity in CI.  The dispatch mode rides cache_token(), so
-#: routing-kernel executables never alias across modes.
+#: binned[i, idx[l, i]] per lane — the grower's routing pass, through the one
+#: entry of perf/kernels/routing.py: the shared XLA compare-reduce in every
+#: dispatch mode (PR 33: the Pallas kernel cost a boosted fit 22.3 of its
+#: 26.3 s), counted as ``route:xla``.
 _row_select_l = _krout.row_select_lanes
 
 
@@ -1252,8 +1251,8 @@ class _GBTBase(_TreeEstimatorBase):
         (the counts of its ``host.launch`` span): the lanes it boosts
         jointly, rounds, levels a tree, the bytes of the int8 bin one-hot it
         reads at every level (0 where there is none: a small block, one
-        over the cap, or the Pallas kernel admitted), and what builds the
-        deepest level's histogram."""
+        over the cap, or the Pallas kernel admitted), what builds the
+        deepest level's histogram, and what routes the rows."""
         n, d = (int(v) for v in binned.shape)
         kmode = _deep_hist_mode(lanes, num_class, int(self.max_depth),
                                 int(self.n_bins), d)
@@ -1262,7 +1261,8 @@ class _GBTBase(_TreeEstimatorBase):
             lanes=lanes, rounds=int(self.num_rounds),
             levels=int(self.max_depth),
             binoh_bytes=_binoh_bytes(n, d, int(self.n_bins)) if mat else 0,
-            hist_kernel=_khist.hist_level_pallas.__name__ if kmode else "xla")
+            hist_kernel=_khist.hist_level_pallas.__name__ if kmode else "xla",
+            route_kernel=_krout.ROUTE_KERNEL)
 
     def _shared_bin_onehot(self, binned, counts: Dict[str, Any]
                            ) -> Dict[str, Any]:

@@ -44,8 +44,13 @@ formulation — with the decision itself made observable and cacheable:
   ``split_scan``        selected (all tree levels at d=128, 32 bins).
                         Repaired in PR 21: the (8, 128) block rule and the
                         missing ``cumsum`` lowering refused the original.
-  ``row_select_lanes``  selected when the measured scratch model fits
-                        (block 256: up to 128 lanes at d=128).
+  ``row_select_lanes``  never (PR 33): the grower's entry runs the XLA
+                        compare-reduce in every mode.  The kernel pads the
+                        lane axis to 128, so it took 74.5 ms a call at
+                        2^20 x 128 codes for three lanes and for one (22.3
+                        of a boosted fit's 26.3 s) where the XLA form
+                        takes 2.3 and 0.9 ms; ``route_mode`` only answers
+                        callers that name the kernel themselves.
   ``onehot_codes``,     selected at every serving width.
   ``bucketize_right``
   ``hist_level``        selected only where the working set fits; at
@@ -283,10 +288,14 @@ def _admit(kernel: str, working_set_bytes: int, counted: bool = True
                          and working_set_bytes > vmem_budget()):
         mode = None
     if counted:
-        with _SELECTIONS_LOCK:
-            key = (kernel, mode or "xla")
-            _SELECTIONS[key] = _SELECTIONS.get(key, 0) + 1
+        count_selection(kernel, mode or "xla")
     return mode
+
+
+def count_selection(kernel: str, mode: str) -> None:
+    """One more traced call site of ``kernel`` ran as ``mode``."""
+    with _SELECTIONS_LOCK:
+        _SELECTIONS[kernel, mode] = _SELECTIONS.get((kernel, mode), 0) + 1
 
 
 def kernel_selections() -> Dict[str, int]:
@@ -321,7 +330,9 @@ def split_mode(block_bytes: int) -> Optional[str]:
 
 
 def route_mode(d: int, lanes: int, block_rows: int = 256) -> Optional[str]:
-    """Dispatch decision for the routing kernel (perf/kernels/routing.py).
+    """Admission of the routing kernel (perf/kernels/routing.py) for a
+    caller that names it: the grower's entry ``row_select_lanes`` no longer
+    asks (PR 33: it runs the XLA form in every mode).
     Its rank-3 (block, d, lanes) compare-reduce puts the lane axis on the
     128 vector lanes, so VMEM goes by lane TILES, not lanes.  The scratch
     model is fitted to what the v5e compiler reported (libtpu 0.0.34, d=128:

@@ -18,9 +18,12 @@ gap the ROADMAP autotuning item called out):
   blocks, each step holds one (block, d) code tile, the (block, L) lane
   indices, and the (block, d, L) one-hot product in VMEM, emitting the
   routed (block, L) codes in one pass — the one-hot never touches HBM;
-- :func:`row_select_lanes` — the dispatcher (``perf.kernels.dispatch`` mode
-  + VMEM admission; the mode rides ``cache_token()`` so executables never
-  alias across dispatch modes).
+- :func:`row_select_lanes` — the grower's one entry.  It returns the XLA
+  compare-reduce at every shape and in every dispatch mode (PR 33), and
+  counts that (``kernel_selections()["route:xla"]``): on a v5e the kernel
+  took 74.5 ms a call at 2^20 x 128 codes, the same for three lanes and for
+  one, where the XLA form takes 2.3 and 0.9 ms.  The kernel stays for
+  callers that name it (parity tests, the autotuner, ``chip_smoke.py``).
 
 Selection parity: the products are exact 0.0/code floats (codes < 2^24) and
 the reduce sums exactly one nonzero per row, so the result is BITWISE
@@ -132,19 +135,18 @@ def row_select_lanes_pallas(binned: jnp.ndarray, idx: jnp.ndarray, *,
     return out[:n].T
 
 
+#: what :func:`row_select_lanes` runs, whatever the shape and the dispatch
+#: mode: the kernel's rank-3 (block, d, L) one-hot pads the lane axis to a
+#: 128-lane tile, so a row costs it d x 128 compare-multiply-adds for the
+#: d x L useful ones, and past 128 lanes VMEM admission refused it anyway.
+#: The ``host.launch`` spans of the boosting programs carry it as
+#: ``route_kernel`` (models/trees.py ``_launch_counts``).
+ROUTE_KERNEL = "xla"
+
+
 def row_select_lanes(binned: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
-    """Dispatched lane-batched routing — the entry ``models/trees.py`` calls
-    from the sweep fold-take path.  Mode resolves at trace time
-    (``dispatch.kernel_mode`` + VMEM admission) and is baked into the traced
-    program; ``cache_token()`` keys every executable on it."""
-    n, d = int(binned.shape[0]), int(binned.shape[1])
-    L = int(idx.shape[0])
-    mode = _dispatch.kernel_mode()
-    block = _resolve_block(None, n, d, L, mode)
-    mode = _dispatch.route_mode(d, L, block_rows=block) \
-        if (d > 0 and L > 0 and n > 0) else None
-    if mode is None:
-        return row_select_lanes_xla(binned, idx)
-    return row_select_lanes_pallas(binned, idx,
-                                   interpret=mode == "interpret",
-                                   block=block)
+    """Lane-batched routing as the tree grower calls it (``models/trees.py``
+    ``_row_select_l``): :func:`row_select_lanes_xla`, counted as one
+    ``route:xla`` selection a traced call site."""
+    _dispatch.count_selection("route", ROUTE_KERNEL)
+    return row_select_lanes_xla(binned, idx)
