@@ -152,6 +152,8 @@ def _bucketize():
 
 TPU_KERNELS = {
     "split_scan@gbt": lambda: _split(chip_smoke.FOLDS, 4),
+    # the deepest level of a depth-6 boosted tree (the grid cell, PR 34)
+    "split_scan@gbt-depth6": lambda: _split(chip_smoke.FOLDS, 32),
     "split_scan@rf": lambda: _split(chip_smoke.FOLDS * 50, 32),
     "row_select_lanes@gbt": lambda: _route(chip_smoke.FOLDS),
     "row_select_lanes@refit": lambda: _route(1),

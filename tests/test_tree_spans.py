@@ -73,10 +73,16 @@ def test_a_boosted_fit_records_its_bin_span_and_launch_counts(small_chunks):
     launches = _launches(profile)
     assert set(launches) == {CV, REFIT}
     want = {"rounds": ROUNDS, "levels": DEPTH, "hist_kernel": "xla",
-            "route_kernel": "xla", "binoh_bytes": rows * 33 * D}
+            "route_kernel": "xla", "binoh_bytes": rows * 33 * D,
+            "binoh_walks": ROUNDS * DEPTH}
     assert rows % 128 == 0 and rows >= N
-    assert launches[CV] == {"label": CV, "lanes": 3, **want}
-    assert launches[REFIT] == {"label": REFIT, "lanes": 1, **want}
+    # M of the deepest fresh level: lanes x 1 left child x (grad, hess)
+    assert launches[CV] == {"label": CV, "lanes": 3, **want,
+                            "hist_rows_deepest": 6,
+                            "grid_point": 0, "grid_points": 1}
+    # the refit is no grid point: the selector's ``best`` names its point
+    assert launches[REFIT] == {"label": REFIT, "lanes": 1, **want,
+                               "hist_rows_deepest": 2}
     # the one-hot both read is built once a fit, by a program of its own
     built = [s.counts["label"] for s in profile.spans
              if s.path == "host.launch"
@@ -124,7 +130,8 @@ def test_the_counts_name_the_pallas_kernel_where_it_is_admitted():
         counts = est._launch_counts(codes, 3, 1)
     assert counts == {"lanes": 3, "rounds": 2, "levels": 2,
                       "hist_kernel": "xla", "route_kernel": "xla",
-                      "binoh_bytes": 8192 * 33 * 4}
+                      "binoh_bytes": 8192 * 33 * 4,
+                      "binoh_walks": 4, "hist_rows_deepest": 6}
 
 
 def test_the_shared_one_hot_changes_no_bit_and_is_built_once_a_sweep(

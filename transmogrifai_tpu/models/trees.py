@@ -118,6 +118,12 @@ def _hist_admit(L: int, nn: int, K: int, B: int, d: int, elem_bytes: int,
         elem_bytes=elem_bytes, counted=counted)
 
 
+def _deepest_fresh_nodes(max_depth: int) -> int:
+    """Nodes whose histograms the deepest level of a tree builds afresh:
+    its 2^(max_depth-2) left children (their siblings come by subtraction)."""
+    return max(1, 2 ** max(max_depth - 2, 0))
+
+
 def _deep_hist_mode(L: int, K: int, max_depth: int, n_bins: int, d: int):
     """What a trace's ``_hist_admit`` will answer at the DEEPEST
     fresh-histogram level of a boosted tree (the largest per-level working
@@ -126,8 +132,7 @@ def _deep_hist_mode(L: int, K: int, max_depth: int, n_bins: int, d: int):
     chunk, so the premade operand is moot only where it is admitted THERE:
     if VMEM admission routes the deep levels back to the XLA scan, the
     operand must exist or those levels lose the measured mat-binoh win."""
-    nn_deep = max(1, 2 ** max(max_depth - 2, 0))
-    return _hist_admit(L, nn_deep, K, n_bins + 1, d,
+    return _hist_admit(L, _deepest_fresh_nodes(max_depth), K, n_bins + 1, d,
                        jnp.dtype(_hist_dtype()).itemsize, _HIST_CHUNK,
                        counted=False)
 
@@ -1186,14 +1191,15 @@ class _TreeEstimatorBase(PredictionEstimatorBase):
         # what the grid points share on the device (boosting's bin one-hot)
         # is built once: in the selector's fit table, or one of this sweep's
         with ensure_fit_placements():
-            for grid in grids:
+            for at, grid in enumerate(grids):
                 est = self.copy().set_params(**grid)
                 # a grid point that changes the binning resolution needs its
                 # own codes
                 b = binned if int(est.n_bins) == int(self.n_bins) else \
                     _shared_binned(x32, xd, int(est.n_bins))[0]
                 pending.append(est._sweep_folds(b, x, y_p, tw, vw, metric_fn,
-                                                weights01=int01))
+                                                weights01=int01,
+                                                grid_at=(at, len(grids))))
         return pending
 
     def _reshard_fold_weights(self, tw, vw):
@@ -1201,7 +1207,9 @@ class _TreeEstimatorBase(PredictionEstimatorBase):
         return tw, vw
 
     def _sweep_folds(self, binned, x, y, train_w, val_w, metric_fn,
-                     weights01=False):
+                     weights01=False, grid_at=(0, 1)):
+        """One grid point's program, dispatched; ``grid_at`` says which of
+        how many points of the sweep it is."""
         raise NotImplementedError
 
 
@@ -1252,17 +1260,24 @@ class _GBTBase(_TreeEstimatorBase):
         jointly, rounds, levels a tree, the bytes of the int8 bin one-hot it
         reads at every level (0 where there is none: a small block, one
         over the cap, or the Pallas kernel admitted), what builds the
-        deepest level's histogram, and what routes the rows."""
+        deepest level's histogram, what routes the rows, the walks of the
+        bin operand it makes (one a level of every round, whether the
+        one-hot is resident or rebuilt) and the rows M of the histogram GEMM
+        at the deepest fresh level (lanes x 2^(depth-2) left children x
+        gradient and hessian of each class): what a grid of points costs
+        is the sum of these over its launches."""
         n, d = (int(v) for v in binned.shape)
-        kmode = _deep_hist_mode(lanes, num_class, int(self.max_depth),
-                                int(self.n_bins), d)
+        rounds, depth = int(self.num_rounds), int(self.max_depth)
+        kmode = _deep_hist_mode(lanes, num_class, depth, int(self.n_bins), d)
         mat = _GBT_MAT_BINOH and kmode is None
         return dict(
-            lanes=lanes, rounds=int(self.num_rounds),
-            levels=int(self.max_depth),
+            lanes=lanes, rounds=rounds, levels=depth,
             binoh_bytes=_binoh_bytes(n, d, int(self.n_bins)) if mat else 0,
             hist_kernel=_khist.hist_level_pallas.__name__ if kmode else "xla",
-            route_kernel=_krout.ROUTE_KERNEL)
+            route_kernel=_krout.ROUTE_KERNEL,
+            binoh_walks=rounds * depth,
+            hist_rows_deepest=lanes * _deepest_fresh_nodes(depth)
+            * 2 * num_class)
 
     def _shared_bin_onehot(self, binned, counts: Dict[str, Any]
                            ) -> Dict[str, Any]:
@@ -1317,13 +1332,15 @@ class _GBTBase(_TreeEstimatorBase):
                 place_spec(vw, (MODEL_AXIS, DATA_AXIS)))
 
     def _sweep_folds(self, binned, x, y, train_w, val_w, metric_fn,
-                     weights01=False):
+                     weights01=False, grid_at=(0, 1)):
         from ..parallel.mesh import DATA_AXIS, place_cached
         from ..perf.programs import run_cached
 
         objective, num_class, _ = self._resolved(y, np.ones_like(y))
         yd = place_cached(np.asarray(y, np.float32), (DATA_AXIS,))
-        counts = self._launch_counts(binned, int(train_w.shape[0]), num_class)
+        counts = dict(
+            self._launch_counts(binned, int(train_w.shape[0]), num_class),
+            grid_point=grid_at[0], grid_points=grid_at[1])
         return run_cached(
             _gbt_cv_program,
             binned, yd, train_w, val_w, jax.random.PRNGKey(int(self.seed)),
@@ -1459,7 +1476,7 @@ class _ForestBase(_TreeEstimatorBase):
         return trees, edges
 
     def _sweep_folds(self, binned, x, y, train_w, val_w, metric_fn,
-                     weights01=False):
+                     weights01=False, grid_at=(0, 1)):
         from ..parallel.mesh import DATA_AXIS, MODEL_AXIS, place_cached
         from .base import place_spec
 
