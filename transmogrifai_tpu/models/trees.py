@@ -25,7 +25,8 @@ TPU-first design (not a port of either C++ codebase):
   live activation stays a few MB per CV vmap lane at any row count, with
   sibling subtraction (right child = parent - left) and a totals-only deepest
   level cutting ~4x of the work.  Row routing and per-node table lookups are
-  fused compare-multiply-reduces, never TPU gathers.  When rows are sharded
+  fused compare-multiply-reduces, never TPU gathers; a level's look-up reads
+  the 2^l nodes that level can reach, not the heap.  When rows are sharded
   over the ``data`` mesh axis the histogram contraction IS the Rabit
   allreduce, inserted by XLA as a psum.
 - Split gain is the XGBoost second-order formula with L2 ``reg_lambda``, L1 ``alpha``
@@ -345,19 +346,77 @@ _row_select = _krout.row_select_xla
 
 
 def _node_lookup(tbl: jnp.ndarray, node: jnp.ndarray) -> jnp.ndarray:
-    """tbl[node] for a small per-tree node table, as a fused compare-reduce.
+    """Leaf values tbl[..., node, :] as a fused compare-reduce over the WHOLE
+    heap: tbl (..., m, K), node (..., n) -> (..., n, K).
 
-    Same rationale as _row_select: a (n,) gather from a (m,) / (m, K) table
-    per vmap lane serializes on TPU; the compare against iota fuses into a
-    VPU streaming reduce (n * m * K multiply-adds, m <= 2^(depth+1)-1).
+    Same rationale as _row_select: a (n,) gather from a small table per lane
+    serializes on TPU; the compare against iota fuses into a VPU streaming
+    reduce.  A row's final node may sit at any level, so this one read —
+    once a tree, after the walk — compares with all m = 2^(depth+1)-1 nodes
+    (n * m * K multiply-adds).  The walk itself never does: see
+    ``_level_lookup``.
     """
-    m = tbl.shape[0]
-    oh = node[:, None] == jnp.arange(m, dtype=node.dtype)[None, :]   # (n, m)
-    if tbl.ndim == 1:
-        if tbl.dtype == jnp.bool_:
-            return (oh & tbl[None, :]).any(axis=1)
-        return jnp.where(oh, tbl[None, :], 0).sum(axis=1)
-    return (oh[:, :, None] * tbl[None, :, :]).sum(axis=1)            # (n, K)
+    m = tbl.shape[-2]
+    oh = node[..., None] == jnp.arange(m, dtype=node.dtype)          # (.., n, m)
+    return (oh[..., None] * tbl[..., None, :, :]).sum(axis=-2)       # (.., n, K)
+
+
+def _lookup_nodes(max_depth: int) -> int:
+    """Node-table entries one row is compared with while one tree grows (or
+    is walked) and its leaf value is read: one ``_level_lookup`` over the
+    2^l nodes of each level l < max_depth, then the whole heap once for the
+    value: 63 + 127 = 190 at depth 6, 7 + 15 = 22 at depth 3."""
+    return (2 ** max_depth - 1) + (2 ** (max_depth + 1) - 1)
+
+
+def _level_lookup(feat, thr_bin, miss_left, is_leaf, local, d: int,
+                  n_bins: int):
+    """(feat, thr_bin, miss_left, is_leaf) of each row's node, read in the
+    slice of the heap its LEVEL can reach: the four tables (..., nodes) hold
+    the level's 2^l nodes, ``local`` (..., n) is the row's node id less the
+    level's first.
+
+    The tables are packed into ONE int32 word a node (``feat`` in the low
+    bits, ``thr_bin`` above it, then the two flags; widths from ``d`` and
+    ``n_bins`` at trace time: feat < d, thr_bin <= n_bins), so a level costs
+    one compare-select-reduce over 2^l entries — a masked sum of one int32
+    entry is exact — not four over the heap's 2^(depth+1)-1.  A row stuck at
+    a leaf of an earlier level has ``local < 0``, matches nothing and reads
+    zeros, which the caller discards (it stays where it is).  Level 0 has
+    one node and every row is at it: a broadcast, which also lets XLA's row
+    select read one column a lane (0.87 ms for 2.34 at 3 x 2^20 rows)."""
+    fbits, tbits = (d - 1).bit_length(), int(n_bins).bit_length()
+    flags = fbits + tbits
+    assert flags + 2 <= 31, (
+        f"a node word of 31 bits cannot hold d={d} features and "
+        f"n_bins={n_bins}: {fbits} + {tbits} + 2 bits")
+    words = (feat.astype(jnp.int32)
+             | (thr_bin.astype(jnp.int32) << fbits)
+             | (miss_left.astype(jnp.int32) << flags)
+             | (is_leaf.astype(jnp.int32) << (flags + 1)))
+    nn = words.shape[-1]
+    if nn == 1:
+        w = jnp.broadcast_to(words, local.shape)
+    else:
+        oh = local[..., None] == jnp.arange(nn, dtype=local.dtype)
+        w = jnp.where(oh, words[..., None, :], 0).sum(axis=-1)
+    return (w & ((1 << fbits) - 1), (w >> fbits) & ((1 << tbits) - 1),
+            ((w >> flags) & 1).astype(bool),
+            ((w >> (flags + 1)) & 1).astype(bool))
+
+
+def _route_level(binned, row_select, feat, thr_bin, miss_left, is_leaf,
+                 node, first: int, n_bins: int):
+    """One level of a walk over the level's tables (..., 2^l): rows at its
+    split nodes move to a child, rows at a leaf — of this level or of an
+    earlier one — stay."""
+    local = node - first
+    nf, thr, go_miss, leaf_here = _level_lookup(
+        feat, thr_bin, miss_left, is_leaf, local, binned.shape[-1], n_bins)
+    nb = row_select(binned, nf)
+    go_left = jnp.where(nb == n_bins, go_miss, nb <= thr)
+    child = jnp.where(go_left, 2 * node + 1, 2 * node + 2)
+    return jnp.where((local < 0) | leaf_here, node, child)
 
 
 #: binned[i, idx[l, i]] per lane — the grower's routing pass, through the one
@@ -365,19 +424,6 @@ def _node_lookup(tbl: jnp.ndarray, node: jnp.ndarray) -> jnp.ndarray:
 #: dispatch mode (PR 33: the Pallas kernel cost a boosted fit 22.3 of its
 #: 26.3 s), counted as ``route:xla``.
 _row_select_l = _krout.row_select_lanes
-
-
-def _node_lookup_l(tbl: jnp.ndarray, node: jnp.ndarray) -> jnp.ndarray:
-    """tbl[l, node[l, i]] per lane — lane-batched ``_node_lookup``.
-
-    tbl: (L, m) or (L, m, K); node: (L, n)."""
-    m = tbl.shape[1]
-    oh = node[:, :, None] == jnp.arange(m, dtype=node.dtype)[None, None, :]
-    if tbl.ndim == 2:
-        if tbl.dtype == jnp.bool_:
-            return (oh & tbl[:, None, :]).any(axis=-1)
-        return jnp.where(oh, tbl[:, None, :], 0).sum(axis=-1)
-    return (oh[..., None] * tbl[:, None, :, :]).sum(axis=2)        # (L, n, K)
 
 
 def _leaf_value(G, H, reg_lambda, alpha, eta, max_delta_step):
@@ -613,9 +659,12 @@ def _grow_trees(binned: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
         # nodes with no positive gain (or no rows) become leaves now
         leaf_now = (best_gain <= 0.0) | (H.mean(-1) <= 0.0)
         sl = slice(first, first + n_nodes)
-        feat = feat.at[:, sl].set(jnp.where(leaf_now, 0, bf))
-        thr_bin = thr_bin.at[:, sl].set(jnp.where(leaf_now, n_bins, bb))
-        miss_left = miss_left.at[:, sl].set(jnp.where(leaf_now, False, bml))
+        lvl_feat = jnp.where(leaf_now, 0, bf)                # (L, nodes) each
+        lvl_thr = jnp.where(leaf_now, n_bins, bb)
+        lvl_miss = jnp.where(leaf_now, False, bml)
+        feat = feat.at[:, sl].set(lvl_feat)
+        thr_bin = thr_bin.at[:, sl].set(lvl_thr)
+        miss_left = miss_left.at[:, sl].set(lvl_miss)
         is_leaf = is_leaf.at[:, sl].set(leaf_now)
         value = value.at[:, sl].set(node_val)
 
@@ -654,15 +703,11 @@ def _grow_trees(binned: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
             value = value.at[:, csl].set(child_vals)
             is_leaf = is_leaf.at[:, csl].set(True)
 
-        # route rows: rows at leaf nodes stay put
+        # route rows over the level's own tables: a row is at one of its
+        # 2^depth nodes or stuck at an earlier leaf
         with jax.named_scope("tree_route"):
-            nf = _node_lookup_l(feat, node)
-            nb = _row_select_l(binned, nf)
-            go_left = jnp.where(nb == n_bins,
-                                _node_lookup_l(miss_left, node),
-                                nb <= _node_lookup_l(thr_bin, node))
-            child = jnp.where(go_left, 2 * node + 1, 2 * node + 2)
-            node = jnp.where(_node_lookup_l(is_leaf, node), node, child)
+            node = _route_level(binned, _row_select_l, lvl_feat, lvl_thr,
+                                lvl_miss, leaf_now, node, first, n_bins)
 
     return Tree(feat, thr_bin, miss_left, is_leaf, value), node[:, :n_orig]
 
@@ -682,19 +727,16 @@ def _grow_tree(binned: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
 
 def _predict_tree(tree: Tree, binned: jnp.ndarray, max_depth: int, n_bins: int
                   ) -> jnp.ndarray:
-    """Leaf value vector per row (n, K): fixed-depth traversal (vectorized gathers)."""
-    n = binned.shape[0]
-    node = jnp.zeros(n, dtype=jnp.int32)
-
-    def step(_, node):
-        nf = _node_lookup(tree.feat, node)
-        nb = _row_select(binned, nf)
-        go_left = jnp.where(nb == n_bins, _node_lookup(tree.miss_left, node),
-                            nb <= _node_lookup(tree.thr_bin, node))
-        child = jnp.where(go_left, 2 * node + 1, 2 * node + 2)
-        return jnp.where(_node_lookup(tree.is_leaf, node), node, child)
-
-    node = jax.lax.fori_loop(0, max_depth, step, node)
+    """Leaf value vector per row (n, K): the grower's level walk over static
+    slices of the heap (level l reads its 2^l nodes), then the value of the
+    row's final node from the whole heap."""
+    node = jnp.zeros(binned.shape[0], dtype=jnp.int32)
+    for level in range(max_depth):
+        first = 2 ** level - 1
+        sl = slice(first, first + 2 ** level)
+        node = _route_level(binned, _row_select, tree.feat[sl],
+                            tree.thr_bin[sl], tree.miss_left[sl],
+                            tree.is_leaf[sl], node, first, n_bins)
     return _node_lookup(tree.value, node)
 
 
@@ -789,7 +831,7 @@ def _fit_gbt_lanes(binned, y, w_lanes, key, n_rounds: int, max_depth: int,
                                  colsample_bylevel, bin_oh_c=bin_oh_c)
         # the grower already routed every row to its leaf — no re-traversal
         with jax.named_scope("boost_margin"):
-            new_margin = margin + _node_lookup_l(tree.value, node)
+            new_margin = margin + _node_lookup(tree.value, node)
         return new_margin, tree
 
     margin0 = jnp.broadcast_to(base_score.astype(jnp.float32)[:, None, :],
@@ -950,7 +992,7 @@ def _forest_cv_program(binned, y, y_cols, train_w, val_w, feat_masks, boot_w,
         int_exact=int_exact)
     # in-sample votes read each lane's final row->leaf assignment from
     # the grower — no re-traversal of the whole forest
-    vals = _node_lookup_l(trees.value, nodes)            # (k*T, n, K)
+    vals = _node_lookup(trees.value, nodes)              # (k*T, n, K)
     mean = vals.reshape(k, n_trees, n, K).sum(axis=1) / n_trees
     if classification:
         if K == 1:
@@ -1262,10 +1304,12 @@ class _GBTBase(_TreeEstimatorBase):
         over the cap, or the Pallas kernel admitted), what builds the
         deepest level's histogram, what routes the rows, the walks of the
         bin operand it makes (one a level of every round, whether the
-        one-hot is resident or rebuilt) and the rows M of the histogram GEMM
+        one-hot is resident or rebuilt), the rows M of the histogram GEMM
         at the deepest fresh level (lanes x 2^(depth-2) left children x
-        gradient and hessian of each class): what a grid of points costs
-        is the sum of these over its launches."""
+        gradient and hessian of each class) and the node-table entries one
+        row is compared with in one round of one lane (``_lookup_nodes``):
+        what a grid of points costs is the sum of these over its
+        launches."""
         n, d = (int(v) for v in binned.shape)
         rounds, depth = int(self.num_rounds), int(self.max_depth)
         kmode = _deep_hist_mode(lanes, num_class, depth, int(self.n_bins), d)
@@ -1277,7 +1321,8 @@ class _GBTBase(_TreeEstimatorBase):
             route_kernel=_krout.ROUTE_KERNEL,
             binoh_walks=rounds * depth,
             hist_rows_deepest=lanes * _deepest_fresh_nodes(depth)
-            * 2 * num_class)
+            * 2 * num_class,
+            lookup_nodes=_lookup_nodes(depth))
 
     def _shared_bin_onehot(self, binned, counts: Dict[str, Any]
                            ) -> Dict[str, Any]:
