@@ -10,6 +10,12 @@ explainability — executing on row-sharded device arrays under ``jit`` over a
 
 __version__ = "0.1.0"
 
+import time as _time
+
+#: the clock before the first and after the last import below:
+#: ``perf.timers.package_import_seconds()`` is their difference
+_IMPORT_START = _time.perf_counter()
+
 from .types import *  # noqa: F401,F403 — feature type hierarchy
 from .features.feature import Feature, FeatureHistory
 from .features.builder import FeatureBuilder
@@ -43,6 +49,8 @@ from .ops import ner as _ner  # noqa: F401 — registers NameEntityRecognizer
 from .ops import collections_lift as _lift  # noqa: F401 — registers map/list plumbing
 from .models import combiner as _combiner  # noqa: F401 — registers SelectedModelCombiner
 from . import dsl  # noqa: F401 — attaches the rich-feature DSL methods
+
+_IMPORT_END = _time.perf_counter()
 
 __all__ = [
     "Feature", "FeatureHistory", "FeatureBuilder", "Column", "Dataset",

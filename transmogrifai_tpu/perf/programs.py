@@ -40,7 +40,7 @@ from typing import Any, Dict, Optional, Tuple
 import numpy as np
 
 from ..obs import flight as obs_flight
-from .timers import activity, measure_compiles, phase
+from .timers import activity, compile_phase, measure_compiles
 
 log = logging.getLogger(__name__)
 
@@ -246,7 +246,7 @@ def run_cached(fn, *args, kwargs: Optional[Dict[str, Any]] = None,
             if compiled is None:
                 t0 = time.perf_counter()
                 try:
-                    with phase(f"compile.{stats.label}"), \
+                    with compile_phase(stats.label), \
                             obs_flight.compile_context(
                                 f"perf.run_cached:{stats.label}",
                                 fingerprint=fp), \

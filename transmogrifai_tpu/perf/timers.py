@@ -20,7 +20,12 @@ Compile probe: ``jax.monitoring`` emits an event per compile request
 hit/miss.  A module-level listener accumulates them — a request the
 persistent cache answered counts as a hit, not a compile; ``measure_compiles``
 yields a live delta object, which is how tests assert "the second fit of the
-default sweep performs 0 new XLA compilations".
+default sweep performs 0 new XLA compilations".  The same listener makes
+what it hears spans of the running fit: a duration event arrives at the END
+of the interval it timed, so it records the finished span ``[now - secs,
+now)`` as ``host.trace``, ``host.lower``, ``host.cache_load`` or
+``host.backend_compile``, labelled with the program that was being launched
+(``PhaseRecorder.compile_table``: which step recompiled, and what it cost).
 """
 
 from __future__ import annotations
@@ -30,7 +35,7 @@ import contextvars
 import threading
 import time
 from collections import deque
-from dataclasses import dataclass, field
+from dataclasses import asdict, dataclass, fields, replace
 from typing import Any, Dict, List, Optional
 
 from jax.profiler import TraceAnnotation
@@ -49,7 +54,8 @@ class Span:
     An activity's ``path`` is the flat ``host.<name>``; its ``parent`` is the
     dotted phase path that was open when it ran (relative to the recorder,
     like ``path``) and ``counts`` what its site counted (``nbytes``,
-    ``hit``, ``label``).  Both stay empty on a phase."""
+    ``hit``, ``label``; on the compile probe's spans ``label``, ``fun`` and
+    a load's ``retrieval_s``).  Both stay empty on a phase."""
 
     name: str
     path: str
@@ -57,6 +63,15 @@ class Span:
     seconds: float
     parent: str = ""
     counts: Optional[Dict[str, Any]] = None
+
+
+#: the compile probe's span paths -> their columns in ``compile_table``
+_COMPILE_KEYS = {
+    "host.trace": ("trace_s", "traces"),
+    "host.lower": ("lower_s", "lowers"),
+    "host.cache_load": ("cache_load_s", "cache_loads"),
+    "host.backend_compile": ("backend_compile_s", "backend_compiles"),
+}
 
 
 class PhaseRecorder:
@@ -98,6 +113,38 @@ class PhaseRecorder:
         children, so summing the subtree would double-count."""
         return sum(s.seconds for s in self.spans if s.path == path)
 
+    def compile_table(self) -> Dict[str, Dict[str, Any]]:
+        """{label: {"trace_s", "lower_s", "cache_load_s",
+        "backend_compile_s", the count of each as "traces", "lowers",
+        "cache_loads", "backend_compiles", and "retrieval_s"}}: the compile
+        probe's spans by the program that was being launched — which step
+        recompiled, and what it cost.  Seconds are self time (an inner jit's
+        trace inside an outer's, a trace inside a lowering, count once), but
+        for ``retrieval_s``: the part of ``cache_load_s`` in which jax read,
+        deserialised and loaded the executables, the rest being the cache
+        keys it computed from the modules.  Labels come in the order their
+        first span started."""
+        retro = sorted((s for s in self.spans if s.path in _COMPILE_KEYS),
+                       key=lambda s: (s.start, -s.seconds))
+        own = [s.seconds for s in retro]
+        open_: List[tuple] = []             # (index, end), innermost last
+        for i, s in enumerate(retro):
+            while open_ and s.start >= open_[-1][1]:
+                open_.pop()
+            if open_:
+                own[open_[-1][0]] -= min(s.seconds, open_[-1][1] - s.start)
+            open_.append((i, s.start + s.seconds))
+        out: Dict[str, Dict[str, Any]] = {}
+        for s, secs in zip(retro, own):
+            secs_key, count_key = _COMPILE_KEYS[s.path]
+            row = out.setdefault(s.counts["label"], {
+                **{k: v for pair in _COMPILE_KEYS.values()
+                   for k, v in zip(pair, (0.0, 0))}, "retrieval_s": 0.0})
+            row[secs_key] += secs
+            row[count_key] += 1
+            row["retrieval_s"] += s.counts.get("retrieval_s", 0.0)
+        return out
+
 
 #: stack of active recorders (outermost first) — spans land in ALL of them
 _RECORDERS: "contextvars.ContextVar[tuple]" = contextvars.ContextVar(
@@ -105,6 +152,11 @@ _RECORDERS: "contextvars.ContextVar[tuple]" = contextvars.ContextVar(
 #: current nesting path of open phases
 _PHASE_STACK: "contextvars.ContextVar[tuple]" = contextvars.ContextVar(
     "transmogrifai_tpu_perf_phase_stack", default=())
+#: ``label`` of the open activity that carries one (a ``host.launch``) or of
+#: the open ``compile_phase``: what the compile probe's spans inside it are
+#: put down to
+_OPEN_LABEL: "contextvars.ContextVar[Optional[str]]" = contextvars.ContextVar(
+    "transmogrifai_tpu_perf_open_label", default=None)
 
 
 def current_recorder() -> Optional[PhaseRecorder]:
@@ -145,7 +197,7 @@ class _Phase:
     phase path as its parent."""
 
     __slots__ = ("name", "counts", "recorders", "tracer", "token", "parts",
-                 "t0", "annotation")
+                 "t0", "annotation", "label_token")
 
     def __init__(self, name: str, counts: Optional[Dict[str, Any]] = None):
         self.name = name
@@ -174,6 +226,9 @@ class _Phase:
             self.token = _FLAT
             self.annotation = TraceAnnotation("host." + self.name,
                                               span="activity")
+            label = self.counts.get("label")
+            self.label_token = (_OPEN_LABEL.set(label) if label is not None
+                                else None)
         self.annotation.__enter__()
         self.t0 = time.perf_counter()
         return self
@@ -200,15 +255,24 @@ class _Phase:
                 self.tracer.add_complete(".".join(self.parts), "train",
                                          self.t0, dt, {})
             return
-        path = "host." + self.name
-        for rec in self.recorders:
-            rec.add(Span(name=self.name, path=path, start=self.t0, seconds=dt,
-                         parent=".".join(self.parts[rec._base:]),
-                         counts=self.counts))
-        if self.tracer is not None:
-            self.tracer.add_complete(
-                path, "train", self.t0, dt,
-                {"parent": ".".join(self.parts), **self.counts})
+        if self.label_token is not None:
+            _OPEN_LABEL.reset(self.label_token)
+        _emit_activity(self.recorders, self.tracer, self.parts, self.name,
+                       self.t0, dt, self.counts)
+
+
+def _emit_activity(recorders: tuple, tracer, parts: tuple, name: str,
+                   start: float, seconds: float, counts: Dict[str, Any]
+                   ) -> None:
+    """One finished ``host.<name>`` span into the recorders and the tracer;
+    ``parts`` is the phase stack that was open."""
+    path = "host." + name
+    for rec in recorders:
+        rec.add(Span(name=name, path=path, start=start, seconds=seconds,
+                     parent=".".join(parts[rec._base:]), counts=counts))
+    if tracer is not None:
+        tracer.add_complete(path, "train", start, seconds,
+                            {"parent": ".".join(parts), **counts})
 
 
 def phase(name: str) -> _Phase:
@@ -222,14 +286,29 @@ def phase(name: str) -> _Phase:
     return _Phase(name)
 
 
+@contextlib.contextmanager
+def compile_phase(label: str):
+    """``phase("compile.<label>")`` around one program's lowering and
+    compilation (``run_cached``); the compile probe's spans inside it are
+    put down to ``label``, as inside a ``host.launch`` that carries one."""
+    token = _OPEN_LABEL.set(label)
+    try:
+        with phase("compile." + label):
+            yield
+    finally:
+        _OPEN_LABEL.reset(token)
+
+
 def activity(name: str, **counts) -> _Phase:
     """Time what the host is doing, across phases: same class, sinks and
     early-out as :func:`phase`, but the span's path is the flat
     ``host.<name>`` whatever the phase stack is, and it carries ``parent``
     (the open phase path) and ``counts``.  Flat because padding, stamping,
     placing, launching and waiting recur under ``cv.dispatch``, ``refit`` and
-    ``train_eval`` alike and are summed by activity.  Activities are leaves:
-    nothing nests under one."""
+    ``train_eval`` alike and are summed by activity.  Activities are leaves
+    but for the compile probe's spans, which lie inside the ``host.launch``
+    that traced, lowered, loaded or compiled: its self time is the dispatch
+    alone."""
     return _Phase(name, counts)
 
 
@@ -259,95 +338,113 @@ def recent_fit_profiles() -> List[PhaseRecorder]:
 
 @dataclass
 class CompileStats:
-    """Cumulative XLA compilation counters (process-wide since import)."""
+    """Cumulative XLA compilation counters (process-wide since import).
+
+    Seconds are sums of the events' own lengths: ``trace_seconds`` counts an
+    inner jit's trace again inside its outer's (a recorder's
+    ``compile_table`` has the self times)."""
 
     backend_compiles: int = 0
-    compile_seconds: float = 0.0
-    trace_seconds: float = 0.0          # jaxpr trace + MLIR lowering
+    compile_seconds: float = 0.0        # real compilations only
+    trace_seconds: float = 0.0          # jaxpr trace
+    lower_seconds: float = 0.0          # jaxpr -> MLIR module
+    cache_load_seconds: float = 0.0     # requests the persistent cache answered
     persistent_cache_hits: int = 0
     persistent_cache_misses: int = 0
-    events: Dict[str, int] = field(default_factory=dict)
 
     def snapshot(self) -> "CompileStats":
-        return CompileStats(
-            backend_compiles=self.backend_compiles,
-            compile_seconds=self.compile_seconds,
-            trace_seconds=self.trace_seconds,
-            persistent_cache_hits=self.persistent_cache_hits,
-            persistent_cache_misses=self.persistent_cache_misses,
-            events=dict(self.events),
-        )
+        return replace(self)
 
     def minus(self, other: "CompileStats") -> "CompileStats":
-        return CompileStats(
-            backend_compiles=self.backend_compiles - other.backend_compiles,
-            compile_seconds=self.compile_seconds - other.compile_seconds,
-            trace_seconds=self.trace_seconds - other.trace_seconds,
-            persistent_cache_hits=(self.persistent_cache_hits
-                                   - other.persistent_cache_hits),
-            persistent_cache_misses=(self.persistent_cache_misses
-                                     - other.persistent_cache_misses),
-            events={k: v - other.events.get(k, 0)
-                    for k, v in self.events.items()
-                    if v - other.events.get(k, 0)},
-        )
+        return CompileStats(**{f.name: getattr(self, f.name)
+                               - getattr(other, f.name) for f in fields(self)})
 
     def to_dict(self) -> Dict[str, Any]:
-        return {
-            "backend_compiles": self.backend_compiles,
-            "compile_seconds": round(self.compile_seconds, 3),
-            "trace_seconds": round(self.trace_seconds, 3),
-            "persistent_cache_hits": self.persistent_cache_hits,
-            "persistent_cache_misses": self.persistent_cache_misses,
-        }
+        return {k: round(v, 3) if isinstance(v, float) else v
+                for k, v in asdict(self).items()}
 
 
 _GLOBAL = CompileStats()
 _LOCK = threading.Lock()
 _REGISTERED = False
 
-#: monitoring event names (jax >= 0.4.x); counts land in ``events`` verbatim
+#: monitoring event names (jax >= 0.4.x)
 _EV_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
 _EV_TRACE = "/jax/core/compile/jaxpr_trace_duration"
 _EV_LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 _EV_CACHE_HIT = "/jax/compilation_cache/cache_hits"
 _EV_CACHE_MISS = "/jax/compilation_cache/cache_misses"
+_EV_CACHE_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
 
-
-#: per-thread "the compile request in flight was a persistent-cache hit"
+#: per-thread, from a persistent-cache hit to the duration event that closes
+#: its request: ``cache_hit`` and ``retrieval_s``, the seconds jax took to
+#: read, deserialise and load the executable
 _TL = threading.local()
 
 
+def _probe_span(name: str, secs: float, event: Dict[str, Any],
+                **counts) -> None:
+    """The interval a duration event closed, as a finished activity
+    ``[now - secs, now)`` of the running fit.  Its ``label`` is that of the
+    ``host.launch`` (a direct jit call) or ``compile_phase``
+    (``run_cached``) open on this thread, else ``"unlabelled"``; ``fun`` is
+    jax's own name for what it traced, lowered or compiled.  Never a
+    ``TraceAnnotation``: the profiler cannot be handed a span after the
+    fact, and a capture holds jax's own compile events on this thread's
+    line."""
+    recorders = _RECORDERS.get()
+    tracer = obs_trace.active_tracer()
+    if not recorders and tracer is None:
+        return
+    end = time.perf_counter()
+    _emit_activity(recorders, tracer, _PHASE_STACK.get(), name, end - secs,
+                   secs, {**counts, "label": _OPEN_LABEL.get() or "unlabelled",
+                          "fun": event.get("fun_name")})
+
+
 def _on_event(name: str, **kw) -> None:
-    with _LOCK:
-        _GLOBAL.events[name] = _GLOBAL.events.get(name, 0) + 1
-        if name == _EV_CACHE_HIT:
+    if name == _EV_CACHE_HIT:
+        with _LOCK:
             _GLOBAL.persistent_cache_hits += 1
-            _TL.cache_hit = True
-        elif name == _EV_CACHE_MISS:
+        _TL.cache_hit = True
+    elif name == _EV_CACHE_MISS:
+        with _LOCK:
             _GLOBAL.persistent_cache_misses += 1
 
 
 def _on_duration(name: str, secs: float, **kw) -> None:
-    with _LOCK:
-        _GLOBAL.events[name] = _GLOBAL.events.get(name, 0) + 1
-        if name == _EV_BACKEND_COMPILE:
-            # jax 0.9 times ``compile_or_get_cached`` as a whole, so this
-            # event also closes a request the persistent cache answered (its
-            # hit event lands first, on the same thread).  A load is not a
-            # compile: only real compilations count here.
-            if getattr(_TL, "cache_hit", False):
-                _TL.cache_hit = False
-            else:
+    if name == _EV_BACKEND_COMPILE:
+        # jax 0.9 times ``compile_or_get_cached`` as a whole, so this event
+        # also closes a request the persistent cache answered (its hit and
+        # retrieval events land first, on the same thread).  A load is not a
+        # compile: only real compilations count as one.
+        hit = vars(_TL)
+        if hit.pop("cache_hit", False):
+            with _LOCK:
+                _GLOBAL.cache_load_seconds += secs
+            _probe_span("cache_load", secs, kw,
+                        retrieval_s=hit.pop("retrieval_s", 0.0))
+        else:
+            with _LOCK:
                 _GLOBAL.backend_compiles += 1
                 _GLOBAL.compile_seconds += secs
-        elif name in (_EV_TRACE, _EV_LOWER):
+            _probe_span("backend_compile", secs, kw)
+    elif name == _EV_TRACE:
+        with _LOCK:
             _GLOBAL.trace_seconds += secs
+        _probe_span("trace", secs, kw)
+    elif name == _EV_LOWER:
+        with _LOCK:
+            _GLOBAL.lower_seconds += secs
+        _probe_span("lower", secs, kw)
+    elif name == _EV_CACHE_RETRIEVAL:
+        _TL.retrieval_s = secs
 
 
 def _ensure_registered() -> None:
     """Register the jax.monitoring listeners once.  Listeners are global and
-    live for the process; they cost a dict update per compile event."""
+    live for the process; with no recorder and no tracer active an event
+    costs a counter update."""
     global _REGISTERED
     if _REGISTERED:
         return
@@ -409,3 +506,12 @@ def measure_compiles():
     """Yield a delta object tracking XLA compilations inside (and after) the
     block: ``with measure_compiles() as c: fit(); assert c.backend_compiles == 0``."""
     yield _CompileDelta(compile_snapshot())
+
+
+def package_import_seconds() -> float:
+    """Seconds ``import transmogrifai_tpu`` took in this process, first
+    import to last (the package notes the clock at both ends); jax's own
+    import is inside only where nothing imported jax before."""
+    import transmogrifai_tpu as package
+
+    return package._IMPORT_END - package._IMPORT_START
