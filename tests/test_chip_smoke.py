@@ -174,24 +174,32 @@ def test_kernel_cross_lowers_for_tpu(name):
 
 
 # ---------------------------------------------------------------------------
-# The grower's routing entry is NOT among them (PR 33): with compiled Pallas
+# The walk's routing entry is NOT among them (PR 33): with compiled Pallas
 # forced it lowers for TPU to plain XLA at the boosted cell's shapes (2^20 x
 # 128 codes; the sweep's three lanes, the refit's one) and at the 128 lanes
-# the kernel used to be admitted for, and counts itself as ``route:xla``.
+# the kernel used to be admitted for, and counts itself as ``route:xla`` —
+# at a level that gathers its columns (32 nodes: a ``dot_general``, PR 37)
+# and at one that compares with all of them (128 nodes) alike.
 # ---------------------------------------------------------------------------
 
 
+@pytest.mark.parametrize("nodes", [32, 128])
 @pytest.mark.parametrize("lanes", [chip_smoke.FOLDS, 1, 128])
-def test_grower_routing_lowers_to_plain_xla_for_tpu(lanes):
-    from transmogrifai_tpu.perf.kernels import dispatch as KD
-    from transmogrifai_tpu.perf.kernels.routing import row_select_lanes
+def test_grower_routing_lowers_to_plain_xla_for_tpu(lanes, nodes):
+    from functools import partial
 
-    specs = (S((2 ** 20, D), I32), S((lanes, 2 ** 20), I32))
+    from transmogrifai_tpu.perf.kernels import dispatch as KD
+    from transmogrifai_tpu.perf.kernels.routing import level_select_lanes
+
+    rows = S((lanes, 2 ** 20), I32)
+    specs = (S((2 ** 20, D), I32), S((lanes, nodes), I32), rows, rows)
     before = KD.kernel_selections()
     with KD.force_kernel_mode("pallas"):
-        text = jax.jit(row_select_lanes).trace(*specs).lower(  # opcheck: allow(TM303) test
+        text = jax.jit(partial(level_select_lanes, n_bins=32, chunk=2048)  # opcheck: allow(TM303) test
+                       ).trace(*specs).lower(
             lowering_platforms=("tpu",)).as_text()
     assert "tpu_custom_call" not in text
+    assert ("dot_general" in text) == (nodes < D)
     moved = {k: v - before.get(k, 0) for k, v in KD.kernel_selections().items()
              if k.startswith("route:") and v != before.get(k, 0)}
     assert moved == {"route:xla": 1}

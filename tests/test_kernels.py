@@ -464,17 +464,33 @@ class TestKernelIrFamilies:
 # ---------------------------------------------------------------------------
 
 class TestRoutingParity:
-    """The fused routing kernel (perf/kernels/routing.py, ISSUE 15
-    satellite): the sweep fold-take ``_row_select`` compare-reduce must be
-    BITWISE identical across the XLA reference, the interpret-mode kernel,
-    and the dispatcher — routing decides which child every row takes, so a
-    single off-by-one moves rows between leaves."""
+    """The row select of the tree walk (perf/kernels/routing.py, ISSUE 15
+    satellite; the level's-columns form since PR 37) must be BITWISE
+    identical across the XLA compare-reduce, the level's-columns matmul, the
+    interpret-mode kernel, and the walk's entry — routing decides which
+    child every row takes, so a single off-by-one moves rows between
+    leaves."""
 
     def _fixture(self, seed=0, n=700, d=9, L=4, n_bins=8):
         rng = np.random.default_rng(seed)
         binned = rng.integers(0, n_bins + 1, (n, d)).astype(np.int32)
         idx = rng.integers(0, d, (L, n)).astype(np.int32)
         return binned, idx
+
+    def _level(self, seed=0, n=700, d=9, L=4, nn=4, n_bins=8):
+        """One level of a walk: the level's split columns ``feat`` (L, nn),
+        each row's node in the level ``local`` (a fifth of the rows at none:
+        -1 down to -nn), the row's own column ``idx`` and its code."""
+        rng = np.random.default_rng(seed)
+        binned = rng.integers(0, n_bins + 1, (n, d)).astype(np.int32)
+        feat = rng.integers(0, d, (L, nn)).astype(np.int32)
+        local = rng.integers(0, nn, (L, n)).astype(np.int32)
+        local = np.where(rng.random((L, n)) < 0.2,
+                         -1 - rng.integers(0, nn, (L, n)), local
+                         ).astype(np.int32)
+        idx = np.take_along_axis(feat, np.clip(local, 0, nn - 1), axis=1)
+        truth = binned[np.arange(n)[None, :], idx]
+        return binned, feat, local, idx, truth
 
     def test_interpret_kernel_bitwise_vs_xla_and_ground_truth(self):
         from transmogrifai_tpu.perf.kernels import routing as KR
@@ -501,26 +517,98 @@ class TestRoutingParity:
             np.testing.assert_array_equal(ker, ref)
 
     def test_dispatcher_honors_mode_and_trees_alias(self):
-        """The grower's entry is the XLA form in EVERY mode (PR 33), and
-        says so: one ``route:xla`` a call, never a kernel selection."""
+        """The walk's entry is XLA's own code in EVERY mode (PR 33), in
+        either of its forms (a level of 4 nodes over 9 columns takes the
+        level's columns, one of 16 nodes the compare-reduce), and says so:
+        one ``route:xla`` a call, never a kernel selection."""
         from transmogrifai_tpu.perf.kernels import routing as KR
 
-        binned, idx = self._fixture(seed=3)
+        for nn in (4, 16):
+            binned, feat, local, idx, truth = self._level(seed=3, nn=nn)
+            live = local >= 0
+            for mode in ("xla", "interpret", "pallas"):
+                before = KD.kernel_selections()
+                with KD.force_kernel_mode(mode):
+                    out = np.asarray(KR.level_select_lanes(
+                        jnp.asarray(binned), jnp.asarray(feat),
+                        jnp.asarray(local), jnp.asarray(idx), 8, 2048))
+                np.testing.assert_array_equal(out[live], truth[live],
+                                              err_msg=mode)
+                moved = {k: v - before.get(k, 0)
+                         for k, v in KD.kernel_selections().items()
+                         if k.startswith("route:") and v != before.get(k, 0)}
+                assert moved == {"route:xla": 1}, (mode, moved)
+        # the walk step of grower and predictor goes through the ONE entry
+        binned, feat, local, idx, _ = self._level(seed=4, nn=4)
+        before = KD.kernel_selections().get("route:xla", 0)
+        flag = jnp.zeros(feat.shape, bool)
+        T._route_level(jnp.asarray(binned), jnp.asarray(feat),
+                       jnp.asarray(feat), flag, flag,
+                       jnp.asarray(local + 3), 3, 8)
+        assert KD.kernel_selections()["route:xla"] == before + 1
+
+    @pytest.mark.parametrize("n,d,L,nn,n_bins,whole_max", [
+        (700, 9, 4, 1, 8, None), (700, 9, 4, 2, 8, None),
+        (700, 9, 4, 8, 8, None), (257, 128, 1, 32, 32, None),
+        (513, 300, 3, 64, 255, None), (100, 12, 7, 4, 300, None),
+        # C would pass the cap: rows in chunks of 128, 700 padded to 768
+        (700, 9, 4, 8, 8, 0), (768, 128, 3, 32, 32, 0),
+        (130, 16, 150, 8, 32, 0)])
+    def test_level_columns_select_bitwise_vs_compare_reduce(
+            self, n, d, L, nn, n_bins, whole_max, monkeypatch):
+        """The level's columns gathered by a one-hot matmul, the row's own
+        picked among them: equal to ``binned[i, feat[l, local[l, i]]]`` and
+        to the compare-reduce on every row at a node of the level, 0 on the
+        rows at none; whole and in row chunks alike."""
+        from transmogrifai_tpu.perf.kernels import routing as KR
+
+        if whole_max is not None:
+            monkeypatch.setattr(KR, "_COLUMNS_WHOLE_MAX_BYTES", whole_max)
+        binned, feat, local, idx, truth = self._level(
+            seed=n + nn, n=n, d=d, L=L, nn=nn, n_bins=n_bins)
+        live = local >= 0
+        got = np.asarray(KR.level_columns_select_xla(
+            jnp.asarray(binned), jnp.asarray(feat), jnp.asarray(local),
+            n_bins, 128))
         ref = np.asarray(KR.row_select_lanes_xla(jnp.asarray(binned),
                                                  jnp.asarray(idx)))
-        for mode in ("xla", "interpret", "pallas"):
-            before = KD.kernel_selections()
-            with KD.force_kernel_mode(mode):
-                out = np.asarray(KR.row_select_lanes(jnp.asarray(binned),
-                                                     jnp.asarray(idx)))
-            np.testing.assert_array_equal(out, ref, err_msg=mode)
-            moved = {k: v - before.get(k, 0)
-                     for k, v in KD.kernel_selections().items()
-                     if k.startswith("route:") and v != before.get(k, 0)}
-            assert moved == {"route:xla": 1}, (mode, moved)
-        # trees' sweep fold-take path routes through the ONE entry
-        assert T._row_select_l is KR.row_select_lanes
-        assert T._row_select is KR.row_select_xla
+        assert got.shape == (L, n) and got.dtype == np.int32
+        np.testing.assert_array_equal(got[live], truth[live])
+        np.testing.assert_array_equal(got[live], ref[live])
+        assert not got[~live].any()
+
+    @pytest.mark.parametrize("backend,n_bins,want", [
+        ("cpu", 32, "float32"), ("cpu", 300, "float32"),
+        ("tpu", 32, "bfloat16"), ("tpu", 255, "bfloat16"),
+        ("tpu", 256, "bfloat16"), ("tpu", 257, "float32"),
+        ("tpu", 300, "float32"), ("tpu", 2 ** 24, "float32"),
+        ("tpu", 2 ** 24 + 1, None), ("cpu", 2 ** 24 + 1, None)])
+    def test_select_dtype_holds_every_code_exactly(self, backend, n_bins,
+                                                   want, monkeypatch):
+        """The matmul's operand dtype follows from ``n_bins`` at trace time:
+        the narrowest the backend multiplies that holds every code."""
+        from transmogrifai_tpu.perf.kernels import routing as KR
+
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+        if want is None:
+            with pytest.raises(AssertionError, match="exactly"):
+                KR.select_dtype(n_bins)
+            return
+        dt = KR.select_dtype(n_bins)
+        assert jnp.dtype(dt).name == want
+        # the largest code, and its neighbour, come back whole
+        top = np.array([n_bins, n_bins - 1], np.int32)
+        np.testing.assert_array_equal(
+            np.asarray(jnp.asarray(top).astype(dt).astype(jnp.int32)), top)
+
+    @pytest.mark.parametrize("depth,d,want", [
+        (3, 128, 7), (6, 128, 63), (8, 128, 127 + 128), (1, 128, 1),
+        (6, 16, 15 + 2 * 16), (3, 300, 7), (12, 128, 127 + 5 * 128)])
+    def test_select_cols_counts_the_form_each_level_takes(self, depth, d,
+                                                         want):
+        from transmogrifai_tpu.perf.kernels import routing as KR
+
+        assert KR.select_cols(depth, d) == want
 
     def test_growth_bitwise_across_routing_modes(self):
         """End-to-end: tree growth (whose per-level routing is the kernel's
@@ -554,12 +642,13 @@ class TestRoutingParity:
         # a lane/feature product far past any VMEM budget must fall back
         assert route_mode(4096, 4096) is None
         monkeypatch.setattr(KR, "row_select_lanes_pallas", None)  # unreached
-        for d, L in ((8, 2), (40, 130)):
-            binned, idx = self._fixture(seed=d, n=64, d=d, L=L)
-            np.testing.assert_array_equal(
-                np.asarray(KR.row_select_lanes(jnp.asarray(binned),
-                                               jnp.asarray(idx))),
-                np.stack([binned[np.arange(64), idx[l]] for l in range(L)]))
+        for d, L, nn in ((8, 2, 4), (8, 2, 8), (40, 130, 16), (40, 130, 64)):
+            binned, feat, local, idx, truth = self._level(
+                seed=d, n=64, d=d, L=L, nn=nn)
+            out = np.asarray(KR.level_select_lanes(
+                jnp.asarray(binned), jnp.asarray(feat), jnp.asarray(local),
+                jnp.asarray(idx), 8, 2048))
+            np.testing.assert_array_equal(out[local >= 0], truth[local >= 0])
 
 
 @pytest.mark.slow

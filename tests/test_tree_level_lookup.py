@@ -103,9 +103,9 @@ def test_level_walk_equals_the_whole_heap_walk_bitwise(case, monkeypatch):
         np.testing.assert_array_equal(node[lane], want)
         assert np.asarray(one.is_leaf)[want].all()
         stopped_early += int((want < m // 2).sum())
-        got = jax.jit(partial(T._predict_tree, max_depth=depth,
-                              n_bins=n_bins))(
-            T.Tree(*(jnp.asarray(a) for a in one)), jnp.asarray(codes))
+        got = T._predict_trees_sum(
+            T.Tree(*(jnp.asarray(a)[None] for a in one)), jnp.asarray(codes),
+            depth, n_bins)                  # a stack of one tree
         np.testing.assert_array_equal(np.asarray(got),
                                       np.asarray(one.value)[want])
     if gamma or mcw > 1.0:
@@ -122,8 +122,8 @@ def test_level_walk_equals_the_whole_heap_walk_bitwise(case, monkeypatch):
 
 
 def test_the_stacked_predictor_sums_the_level_walks():
-    """``_predict_trees_sum`` (trees as vmap lanes) over a boosted ensemble
-    equals the sum of whole-heap walks, tree by tree."""
+    """``_predict_trees_sum`` (trees as the lanes of one walk) over a boosted
+    ensemble equals the sum of whole-heap walks, tree by tree."""
     n, d, n_bins, depth = 500, 10, 32, 4
     codes, _, _ = _problem(5, n, d, n_bins, 1, 0.0)
     rng = np.random.default_rng(6)

@@ -77,7 +77,10 @@ def test_a_boosted_fit_records_its_bin_span_and_launch_counts(small_chunks):
             "binoh_walks": ROUNDS * DEPTH,
             # levels of 1 and 2 nodes, then the heap of 7 once for the value
             # (the whole-heap walk before PR 35: 4 x 2 + 1 look-ups of 7)
-            "lookup_nodes": (1 + 2) + 7}
+            "lookup_nodes": (1 + 2) + 7,
+            # the row's code is picked among the level's 1 and 2 split
+            # columns (the compare-reduce before PR 37: 2 levels x D)
+            "select_cols": 1 + 2}
     assert rows % 128 == 0 and rows >= N
     # M of the deepest fresh level: lanes x 1 left child x (grad, hess)
     assert launches[CV] == {"label": CV, "lanes": 3, **want,
@@ -135,7 +138,7 @@ def test_the_counts_name_the_pallas_kernel_where_it_is_admitted():
                       "hist_kernel": "xla", "route_kernel": "xla",
                       "binoh_bytes": 8192 * 33 * 4,
                       "binoh_walks": 4, "hist_rows_deepest": 6,
-                      "lookup_nodes": 10}
+                      "lookup_nodes": 10, "select_cols": 1 + 2}
 
 
 def test_the_shared_one_hot_changes_no_bit_and_is_built_once_a_sweep(

@@ -336,22 +336,13 @@ class Tree(NamedTuple):
 _soft_threshold = _ksplit.soft_threshold
 
 
-#: binned[i, idx[i]] as a fused compare-multiply-reduce, not a gather: TPU
-#: lowers a per-row dynamic-minor gather (take_along_axis on the (n, d) code
-#: matrix) to an extremely slow serialized access pattern — it was the
-#: dominant cost of tree growth/prediction.  ONE definition now lives in
-#: perf/kernels/routing.py (shared by the XLA path, the Pallas routing
-#: kernel, and the parity tests).
-_row_select = _krout.row_select_xla
-
-
 def _node_lookup(tbl: jnp.ndarray, node: jnp.ndarray) -> jnp.ndarray:
     """Leaf values tbl[..., node, :] as a fused compare-reduce over the WHOLE
     heap: tbl (..., m, K), node (..., n) -> (..., n, K).
 
-    Same rationale as _row_select: a (n,) gather from a small table per lane
-    serializes on TPU; the compare against iota fuses into a VPU streaming
-    reduce.  A row's final node may sit at any level, so this one read —
+    Same rationale as the walk's row select (perf/kernels/routing.py): a
+    (n,) gather from a small table per lane serializes on TPU; the compare
+    against iota fuses into a VPU streaming reduce.  A row's final node may sit at any level, so this one read —
     once a tree, after the walk — compares with all m = 2^(depth+1)-1 nodes
     (n * m * K multiply-adds).  The walk itself never does: see
     ``_level_lookup``.
@@ -383,8 +374,7 @@ def _level_lookup(feat, thr_bin, miss_left, is_leaf, local, d: int,
     entry is exact — not four over the heap's 2^(depth+1)-1.  A row stuck at
     a leaf of an earlier level has ``local < 0``, matches nothing and reads
     zeros, which the caller discards (it stays where it is).  Level 0 has
-    one node and every row is at it: a broadcast, which also lets XLA's row
-    select read one column a lane (0.87 ms for 2.34 at 3 x 2^20 rows)."""
+    one node and every row is at it: a broadcast."""
     fbits, tbits = (d - 1).bit_length(), int(n_bins).bit_length()
     flags = fbits + tbits
     assert flags + 2 <= 31, (
@@ -405,25 +395,28 @@ def _level_lookup(feat, thr_bin, miss_left, is_leaf, local, d: int,
             ((w >> (flags + 1)) & 1).astype(bool))
 
 
-def _route_level(binned, row_select, feat, thr_bin, miss_left, is_leaf,
-                 node, first: int, n_bins: int):
-    """One level of a walk over the level's tables (..., 2^l): rows at its
-    split nodes move to a child, rows at a leaf — of this level or of an
-    earlier one — stay."""
+def _route_level(binned, feat, thr_bin, miss_left, is_leaf, node, first: int,
+                 n_bins: int):
+    """One level of a walk of L lanes over the level's tables (L, 2^l), node
+    (L, n): rows at its split nodes move to a child, rows at a leaf — of
+    this level or of an earlier one — stay.
+
+    The row's bin code at its node's split column comes through the one
+    entry of perf/kernels/routing.py (``level_select_lanes``, counted as
+    ``route:xla``), which picks its form from the shapes: while the level
+    has fewer nodes than the table has columns, the level's 2^l columns are
+    gathered on the MXU and the row's own picked among them; from there on
+    the row's column index is compared with all d columns.  Both are exact,
+    so ``node`` is the same to the last bit; a row with ``local < 0`` reads
+    a code that the last line discards."""
     local = node - first
     nf, thr, go_miss, leaf_here = _level_lookup(
         feat, thr_bin, miss_left, is_leaf, local, binned.shape[-1], n_bins)
-    nb = row_select(binned, nf)
+    nb = _krout.level_select_lanes(binned, feat, local, nf, n_bins,
+                                   _HIST_CHUNK)
     go_left = jnp.where(nb == n_bins, go_miss, nb <= thr)
     child = jnp.where(go_left, 2 * node + 1, 2 * node + 2)
     return jnp.where((local < 0) | leaf_here, node, child)
-
-
-#: binned[i, idx[l, i]] per lane — the grower's routing pass, through the one
-#: entry of perf/kernels/routing.py: the shared XLA compare-reduce in every
-#: dispatch mode (PR 33: the Pallas kernel cost a boosted fit 22.3 of its
-#: 26.3 s), counted as ``route:xla``.
-_row_select_l = _krout.row_select_lanes
 
 
 def _leaf_value(G, H, reg_lambda, alpha, eta, max_delta_step):
@@ -706,8 +699,8 @@ def _grow_trees(binned: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
         # route rows over the level's own tables: a row is at one of its
         # 2^depth nodes or stuck at an earlier leaf
         with jax.named_scope("tree_route"):
-            node = _route_level(binned, _row_select_l, lvl_feat, lvl_thr,
-                                lvl_miss, leaf_now, node, first, n_bins)
+            node = _route_level(binned, lvl_feat, lvl_thr, lvl_miss,
+                                leaf_now, node, first, n_bins)
 
     return Tree(feat, thr_bin, miss_left, is_leaf, value), node[:, :n_orig]
 
@@ -723,21 +716,6 @@ def _grow_tree(binned: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
                              min_child_weight, eta, max_delta_step,
                              colsample_bylevel)
     return Tree(*(a[0] for a in tree)), node[0]
-
-
-def _predict_tree(tree: Tree, binned: jnp.ndarray, max_depth: int, n_bins: int
-                  ) -> jnp.ndarray:
-    """Leaf value vector per row (n, K): the grower's level walk over static
-    slices of the heap (level l reads its 2^l nodes), then the value of the
-    row's final node from the whole heap."""
-    node = jnp.zeros(binned.shape[0], dtype=jnp.int32)
-    for level in range(max_depth):
-        first = 2 ** level - 1
-        sl = slice(first, first + 2 ** level)
-        node = _route_level(binned, _row_select, tree.feat[sl],
-                            tree.thr_bin[sl], tree.miss_left[sl],
-                            tree.is_leaf[sl], node, first, n_bins)
-    return _node_lookup(tree.value, node)
 
 
 # ---------------------------------------------------------------------------
@@ -907,9 +885,19 @@ def _fit_forest(binned, y_cols, w, max_depth, n_bins,
 
 @partial(jax.jit, static_argnames=("max_depth", "n_bins"))
 def _predict_trees_sum(trees: Tree, binned, max_depth, n_bins):
-    """(n, K) sum of leaf value vectors over a stacked batch of trees."""
-    vals = jax.vmap(lambda t: _predict_tree(t, binned, max_depth, n_bins))(trees)
-    return vals.sum(axis=0)
+    """(n, K) sum of leaf value vectors over T stacked trees: the grower's
+    level walk with the trees as its lanes, over static slices of the heap
+    (level l reads its 2^l nodes; the row select sees how many trees there
+    are, and chunks the rows where 50 trees x 32 nodes x n would not fit),
+    then the value of each row's final node from the whole heap."""
+    node = jnp.zeros((trees.feat.shape[0], binned.shape[0]), dtype=jnp.int32)
+    for level in range(max_depth):
+        first = 2 ** level - 1
+        sl = slice(first, first + 2 ** level)
+        node = _route_level(binned, trees.feat[:, sl], trees.thr_bin[:, sl],
+                            trees.miss_left[:, sl], trees.is_leaf[:, sl],
+                            node, first, n_bins)
+    return _node_lookup(trees.value, node).sum(axis=0)
 
 
 # ---------------------------------------------------------------------------
@@ -1306,10 +1294,12 @@ class _GBTBase(_TreeEstimatorBase):
         bin operand it makes (one a level of every round, whether the
         one-hot is resident or rebuilt), the rows M of the histogram GEMM
         at the deepest fresh level (lanes x 2^(depth-2) left children x
-        gradient and hessian of each class) and the node-table entries one
-        row is compared with in one round of one lane (``_lookup_nodes``):
-        what a grid of points costs is the sum of these over its
-        launches."""
+        gradient and hessian of each class), the node-table entries one
+        row is compared with in one round of one lane (``_lookup_nodes``)
+        and the columns its code is compared with while that tree is routed
+        (``routing.select_cols``: 2^l at a level that gathers its columns
+        on the MXU, d at one that compares with all of them): what a grid
+        of points costs is the sum of these over its launches."""
         n, d = (int(v) for v in binned.shape)
         rounds, depth = int(self.num_rounds), int(self.max_depth)
         kmode = _deep_hist_mode(lanes, num_class, depth, int(self.n_bins), d)
@@ -1322,7 +1312,8 @@ class _GBTBase(_TreeEstimatorBase):
             binoh_walks=rounds * depth,
             hist_rows_deepest=lanes * _deepest_fresh_nodes(depth)
             * 2 * num_class,
-            lookup_nodes=_lookup_nodes(depth))
+            lookup_nodes=_lookup_nodes(depth),
+            select_cols=_krout.select_cols(depth, d))
 
     def _shared_bin_onehot(self, binned, counts: Dict[str, Any]
                            ) -> Dict[str, Any]:

@@ -25,7 +25,8 @@ Modules:
 - :mod:`.encode` — fused serving-prefix encode kernels: level-code one-hot
   (``ops/onehot.py``) and right-inclusive bucketize one-hot
   (``ops/bucketizers.py``).
-- :mod:`.routing` — fused row-routing compare-reduce (``_row_select``).
+- :mod:`.routing` — the tree walk's row select: the level's columns
+  gathered on the MXU, the compare-reduce, and the routing kernel.
 
 Tuning: every kernel resolves its schedule parameters as explicit arg >
 env knob > the persistent autotuner's verified winner for the shape class

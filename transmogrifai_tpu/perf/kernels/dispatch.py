@@ -44,13 +44,15 @@ formulation — with the decision itself made observable and cacheable:
   ``split_scan``        selected (all tree levels at d=128, 32 bins).
                         Repaired in PR 21: the (8, 128) block rule and the
                         missing ``cumsum`` lowering refused the original.
-  ``row_select_lanes``  never (PR 33): the grower's entry runs the XLA
-                        compare-reduce in every mode.  The kernel pads the
+  ``row_select_lanes``  never (PR 33): the walk's entry
+                        (``level_select_lanes``) runs XLA's own code in
+                        every mode.  The kernel pads the
                         lane axis to 128, so it took 74.5 ms a call at
                         2^20 x 128 codes for three lanes and for one (22.3
-                        of a boosted fit's 26.3 s) where the XLA form
-                        takes 2.3 and 0.9 ms; ``route_mode`` only answers
-                        callers that name the kernel themselves.
+                        of a boosted fit's 26.3 s) where the XLA
+                        compare-reduce takes 2.3 and 0.9 ms; ``route_mode``
+                        only answers callers that name the kernel
+                        themselves.
   ``onehot_codes``,     selected at every serving width.
   ``bucketize_right``
   ``hist_level``        selected only where the working set fits; at
@@ -331,8 +333,8 @@ def split_mode(block_bytes: int) -> Optional[str]:
 
 def route_mode(d: int, lanes: int, block_rows: int = 256) -> Optional[str]:
     """Admission of the routing kernel (perf/kernels/routing.py) for a
-    caller that names it: the grower's entry ``row_select_lanes`` no longer
-    asks (PR 33: it runs the XLA form in every mode).
+    caller that names it: the walk's entry ``level_select_lanes`` does not
+    ask (PR 33: it runs XLA's own code in every mode).
     Its rank-3 (block, d, lanes) compare-reduce puts the lane axis on the
     128 vector lanes, so VMEM goes by lane TILES, not lanes.  The scratch
     model is fitted to what the v5e compiler reported (libtpu 0.0.34, d=128:
