@@ -1,0 +1,8 @@
+"""Device seconds per fit of the linear family's sweep modules where the
+boosted family's sweep shares the fit, from the trace."""
+
+from ..layerlib import family_device_seconds
+
+
+def read(ctx):
+    return family_device_seconds(ctx, ["lr"])

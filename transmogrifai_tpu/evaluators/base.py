@@ -102,7 +102,8 @@ class BinaryClassificationEvaluator(Evaluator):
 
         with activity("launch", label="BinaryClassificationEvaluator/summary"):
             vals = M.binary_summary(score_dev, pred_dev, y_dev, w_dev)
-        with activity("device_wait"):
+        with activity("device_wait",
+                      label="BinaryClassificationEvaluator/summary"):
             vals = np.asarray(vals)
         return dict(zip(("auROC", "auPR", "precision", "recall", "f1", "error",
                          "tp", "fp", "tn", "fn"), (float(v) for v in vals)))

@@ -8,6 +8,7 @@ a param pytree; predict is a jitted batched function.  Estimators that implement
 
 from __future__ import annotations
 
+import contextvars
 import functools
 from functools import partial
 from typing import Any, Dict, List, Optional
@@ -136,6 +137,22 @@ def place_grid(arr):
     return place_spec(arr, (MODEL_AXIS,) + (None,) * (arr.ndim - 1))
 
 
+#: whose sweep ``gather_scores`` is about to wait for: the estimator's class
+#: name, set by the estimator's own gather round the call (the function keeps
+#: its one argument: callers and tests wrap it)
+_GATHER_FAMILY: "contextvars.ContextVar[Optional[str]]" = \
+    contextvars.ContextVar("transmogrifai_tpu_gather_family", default=None)
+
+
+def _gather_for(family: str, pending) -> np.ndarray:
+    """``gather_scores(pending)`` with its wait labelled ``<family>/cv_gather``."""
+    token = _GATHER_FAMILY.set(family)
+    try:
+        return gather_scores(pending)
+    finally:
+        _GATHER_FAMILY.reset(token)
+
+
 def gather_scores(pending) -> np.ndarray:
     """Host-fetch a pending sweep result: a (g, k) device array or a list of
     per-grid (k,) device arrays (one async fetch either way).
@@ -151,7 +168,9 @@ def gather_scores(pending) -> np.ndarray:
     fault_point("device_sync",
                 programs=len(pending)
                 if isinstance(pending, (list, tuple)) else 1)
-    with activity("device_wait"):
+    family = _GATHER_FAMILY.get()
+    labelled = {"label": f"{family}/cv_gather"} if family else {}
+    with activity("device_wait", **labelled):
         if isinstance(pending, (list, tuple)):
             return np.stack(jax.device_get(list(pending)))
         return np.asarray(jax.device_get(pending))
@@ -437,7 +456,7 @@ class PredictionEstimatorBase(Estimator):
         else python loops (generic estimators)."""
         pending = self._cv_sweep_device(x, y, train_w, val_w, grids, metric_fn)
         if pending is not None:
-            return gather_scores(pending)
+            return _gather_for(type(self).__name__, pending)
         return self._cv_sweep_generic(
             x, y, *host_fold_weights(train_w, val_w, len(y)), grids, metric_fn)
 
@@ -463,7 +482,7 @@ class PredictionEstimatorBase(Estimator):
             return lambda: scores
         pending = self._cv_sweep_device(x, y, train_w, val_w, grids, metric_fn)
         if pending is not None:
-            return lambda: gather_scores(pending)
+            return lambda: _gather_for(type(self).__name__, pending)
         scores = self._cv_sweep_generic(
             x, y, *host_fold_weights(train_w, val_w, len(y)), grids, metric_fn)
         return lambda: scores

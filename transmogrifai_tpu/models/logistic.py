@@ -308,7 +308,7 @@ class LogisticRegression(PredictionEstimatorBase):
                     jnp.float32(self._effective_reg()), self.max_iter,
                     has_intercept=bool(self.fit_intercept),
                 )
-        with activity("device_wait"):
+        with activity("device_wait", label="LogisticRegression/refit"):
             beta, mean, std = (np.asarray(a) for a in (beta, mean_d, std_d))
         coef, intercept = self._finalize_beta(beta, mean, std)
         return LogisticRegressionModel(coef=coef, intercept=intercept)

@@ -210,7 +210,7 @@ class LinearSVC(PredictionEstimatorBase):
                 xs, y_pm, wd,
                 jnp.float32(self.reg_param), int(self.max_iter),
                 has_intercept=bool(self.fit_intercept))
-        with activity("device_wait"):
+        with activity("device_wait", label="LinearSVC/refit"):
             beta, mean, std = (np.asarray(a) for a in (beta, mean_d, std_d))
         if self.fit_intercept:
             coef_s, b0 = beta[:-1], beta[-1]

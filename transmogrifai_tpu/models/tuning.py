@@ -495,7 +495,20 @@ class CrossValidator:
                     metric_name=self.evaluator.default_metric,
                     metric_values=[float(v) for v in scores[gi]],
                 ))
-        best = self._best_index(evaluations)
+        # the choice across every family's grid points: who won, among how
+        # many, and by how much over the best point of any OTHER family
+        with activity("choose", families=len(dispatched),
+                      candidates=len(evaluations),
+                      fold_models=len(evaluations) * self.num_folds) as chose:
+            best = self._best_index(evaluations)
+            winner = evaluations[best]
+            others = [ev.mean_metric for ev in evaluations
+                      if ev.model_uid != winner.model_uid
+                      and np.isfinite(ev.mean_metric)]
+            chose.note(winner=winner.model_name)
+            if others:
+                best_of = max if self.evaluator.larger_is_better else min
+                chose.note(margin=float(winner.mean_metric - best_of(others)))
         # the fold weights and the pending sweeps die with this frame, while
         # the device waits for the refit; where a family made the host form
         # that is 4-10 ms of unmapping at 4M rows that no python call shows
