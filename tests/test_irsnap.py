@@ -165,6 +165,23 @@ class TestDiffer:
         codes = [d.code for d in diff_snapshots(old, new)]
         assert "TM704" in codes
 
+    def test_tm704_reads_no_dtype_from_a_collectives_device_table(self):
+        """A program that gains its first all-reduce (PR 39: the IRLS sweep's
+        per-chip region) gains a ``replica_groups`` table typed
+        ``tensor<..xi64>``: an attribute, not a value, so no TM704."""
+        old = _snap_of(lambda x: x * 2.0, _spec(16))
+        reduce = ('    %9 = "stablehlo.all_reduce"(%0) <{replica_groups = '
+                  'dense<[[0, 2], [1, 3]]> : tensor<2x2xi64>, '
+                  'use_global_device_ids}> : (tensor<16xf32>) -> '
+                  'tensor<16xf32>\n')
+        lines = old.text.splitlines(keepends=True)
+        at = next(i for i, ln in enumerate(lines) if "stablehlo.multiply" in ln
+                  or "stablehlo.mul" in ln)
+        new = IRSnapshot.from_text(old.key,
+                                   "".join(lines[:at] + [reduce] + lines[at:]))
+        assert "i64" not in new.dtype_counts
+        assert "TM704" not in [d.code for d in diff_snapshots(old, new)]
+
 
 class TestTm705Regression:
     """The GSPMD sharded-sort-dim miscompile class: the detector must fire

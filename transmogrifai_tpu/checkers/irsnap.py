@@ -192,9 +192,15 @@ def _op_histogram(text: str) -> Dict[str, int]:
     return counts
 
 
+#: a collective's device-id table (``replica_groups = dense<...> :
+#: tensor<2x4xi64>``): an attribute, not the element type of any value
+_DEVICE_TABLE_RE = re.compile(
+    r"(?:replica_groups|source_target_pairs) = dense<[^>]*> : tensor<[^>]*>")
+
+
 def _dtype_histogram(text: str) -> Dict[str, int]:
     counts: Dict[str, int] = {}
-    for m in _TENSOR_DTYPE_RE.finditer(text):
+    for m in _TENSOR_DTYPE_RE.finditer(_DEVICE_TABLE_RE.sub("", text)):
         dt = m.group(1)
         counts[dt] = counts.get(dt, 0) + 1
     return counts
@@ -828,10 +834,11 @@ def _sweep_entries() -> List[CorpusEntry]:
         return _jax.jit(_mesh_variant, static_argnames=static_argnames)
 
     def irls_meshed():
-        """The dp x mp SHARDED IRLS sweep (ISSUE 15): rows constrained to the
-        data axis, the beta batch to the model axis — the corpus pins the
-        sharded lowering (constraint inventory included) across jax bumps,
-        and the TM705 scan proves the sharded-sort hazard stays absent."""
+        """The dp x mp SHARDED IRLS sweep: since PR 39 one shard_map region,
+        a chip's rows in, the row sums psum'd over data, the grid dealt over
+        the model axis — the corpus pins the sharded lowering across jax
+        bumps, and the TM705 scan proves the sharded-sort hazard stays
+        absent."""
         from ..models.logistic import _irls_sweep
         from ..parallel.mesh import make_mesh, use_mesh
 

@@ -37,7 +37,7 @@ def _ridge_core(x: jnp.ndarray, y: jnp.ndarray, w: jnp.ndarray, reg: jnp.ndarray
 
 @partial(jax.jit, static_argnames=("has_intercept",))
 def _ridge_sweep(x, y, train_w, regs, has_intercept: bool = True):
-    """dp x mp sharding annotations as in logistic._irls_sweep: rows pin to
+    """dp x mp sharding annotations as in logistic._fista_sweep: rows pin to
     the data axis (the normal-equation psums carry only (d, d) blocks), the
     beta batch's grid axis to the model axis; identity off-mesh."""
     from ..parallel.mesh import constrain_fold_rows, constrain_grid, \

@@ -51,9 +51,10 @@ def _mxu_dtype():
     return jnp.bfloat16 if jax.default_backend() == "tpu" else jnp.float32
 
 
-@partial(jax.jit, static_argnames=("max_iter", "has_intercept"))
+@partial(jax.jit, static_argnames=("max_iter", "has_intercept", "row_sum"))
 def _irls_core(x: jnp.ndarray, y: jnp.ndarray, w: jnp.ndarray, reg: jnp.ndarray,
-               max_iter: int, has_intercept: bool = True) -> jnp.ndarray:
+               max_iter: int, has_intercept: bool = True,
+               row_sum=None) -> jnp.ndarray:
     """Weighted L2-regularized IRLS on pre-standardized features.
 
     x: (n, d[+1]) — trailing ones column when ``has_intercept``; returns beta.
@@ -69,9 +70,16 @@ def _irls_core(x: jnp.ndarray, y: jnp.ndarray, w: jnp.ndarray, reg: jnp.ndarray,
 
         H = [[Xᵀ S X,  Xᵀ s],
              [sᵀ X,    Σ s ]] / sw + diag(reg·mask)
+
+    ``row_sum`` completes each sum over the rows (``w.sum()``, the
+    gradient's ``Xᵀ r``, the bordered Hessian) before it is used: the
+    identity when ``x`` holds every row, the all-reduce over the mesh's data
+    axis inside :func:`_irls_sweep`'s per-chip region, where it holds a
+    chip's share of them (:func:`_data_psum`).
     """
+    row_sum = row_sum or (lambda a: a)
     n, d1 = x.shape
-    sw = jnp.maximum(w.sum(), 1e-12)
+    sw = jnp.maximum(row_sum(w.sum()), 1e-12)
     reg_mask = jnp.ones(d1)
     if has_intercept:
         reg_mask = reg_mask.at[-1].set(0.0)  # don't regularize intercept
@@ -81,7 +89,7 @@ def _irls_core(x: jnp.ndarray, y: jnp.ndarray, w: jnp.ndarray, reg: jnp.ndarray,
     def step(_, beta):
         z = x @ beta
         p = jax.nn.sigmoid(z)
-        g = x.T @ (w * (p - y)) / sw + reg * reg_mask * beta
+        g = row_sum(x.T @ (w * (p - y))) / sw + reg * reg_mask * beta
         # stable names in the ops' metadata, for per-kernel time from a trace
         with jax.named_scope("irls_hessian"):
             s = jnp.maximum(w * p * (1.0 - p), 1e-10)
@@ -98,7 +106,7 @@ def _irls_core(x: jnp.ndarray, y: jnp.ndarray, w: jnp.ndarray, reg: jnp.ndarray,
                 ], axis=0)
             else:
                 h = hxx
-            h = h / sw + jnp.diag(reg * reg_mask + 1e-8)
+            h = row_sum(h) / sw + jnp.diag(reg * reg_mask + 1e-8)
         with jax.named_scope("irls_solve"):
             return beta - jnp.linalg.solve(h, g)
 
@@ -152,30 +160,89 @@ def _fista_elastic(x, y, w, l1, l2, max_iter, has_intercept: bool = True):
     return b
 
 
+def _data_psum(a):
+    """A row sum over the mesh's data axis: each chip holds its rows' part."""
+    from ..parallel.mesh import DATA_AXIS
+
+    return jax.lax.psum(a, DATA_AXIS)
+
+
+def _irls_lanes(x, y, train_w, regs, max_iter, has_intercept, row_sum=None):
+    """The IRLS fit vmapped over fold weights (k, n) and the reg grid (g,)."""
+    fit_fold = jax.vmap(
+        lambda w, reg: _irls_core(x, y, w, reg, max_iter,
+                                  has_intercept=has_intercept,
+                                  row_sum=row_sum),
+        in_axes=(0, None))
+    fit_grid = jax.vmap(lambda reg: fit_fold(train_w, reg), in_axes=0)
+    return fit_grid(regs)
+
+
+def _irls_region(mesh, x, y, train_w, regs, max_iter, has_intercept):
+    """The sweep under ``mesh`` as ONE ``shard_map`` region: each chip runs
+    the one-chip IRLS step on its own row shard, and only the step's row sums
+    cross the chips (:func:`_data_psum`: (d+1) and (d+1)² floats a lane an
+    iteration, a fold's ``w.sum()`` once).  Every chip then solves the small
+    system itself.  The grid points are dealt over the model axis, padded to
+    a multiple of it with copies of the last; the betas leave the region
+    replicated over the data axis, on the grid's placement of the
+    unpartitioned form (``constrain_grid``).  Why a region: GSPMD's form of
+    the same program laid the float32 feature block out row-major and ran
+    the Hessian and its border at about a quarter of one chip's speed
+    (PERF.md, PR 39)."""
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+
+    from ..parallel.mesh import DATA_AXIS, MODEL_AXIS, constrain_grid
+
+    model = MODEL_AXIS if MODEL_AXIS in mesh.axis_names else None
+    g = regs.shape[0]
+    dealt = jnp.pad(regs, (0, (-g) % (mesh.shape[model] if model else 1)),
+                    mode="edge")
+    betas = shard_map(
+        partial(_irls_lanes, max_iter=max_iter, has_intercept=has_intercept,
+                row_sum=_data_psum),
+        mesh=mesh,
+        in_specs=(P(DATA_AXIS), P(DATA_AXIS), P(None, DATA_AXIS), P(model)),
+        # the loop's zero start is the same on every chip, what it carries
+        # after a step varies with the model slice's grid points
+        out_specs=P(model), check_vma=False)(x, y, train_w, dealt)
+    return constrain_grid(betas[:g])
+
+
+def irls_allreduce_bytes(k: int, g: int, d1: int, max_iter: int) -> int:
+    """Bytes :func:`_irls_region` all-reduces in a sweep of ``g`` grid points
+    over ``k`` folds under the ambient mesh, from shapes: each lane (padded
+    grid point x fold) its gradient's d+1 and Hessian's (d+1)² floats an
+    iteration, and each model slice its k fold weight sums once.  0 off the
+    mesh."""
+    from ..parallel.mesh import MODEL_AXIS, current_mesh
+
+    mesh = current_mesh()
+    if mesh is None:
+        return 0
+    slices = mesh.shape[MODEL_AXIS] if MODEL_AXIS in mesh.axis_names else 1
+    g += (-g) % slices
+    return (max_iter * g * k * (d1 * d1 + d1) + slices * k) * 4
+
+
 @partial(jax.jit, static_argnames=("max_iter", "has_intercept"))
 def _irls_sweep(x, y, train_w, regs, max_iter, has_intercept: bool = True):
     """vmap the IRLS fit over fold weights (k, n) and reg grid (g,) -> betas (g, k, d+1).
 
-    dp x mp sharding rides ambient ``with_sharding_constraint`` annotations
-    (parallel/mesh.py:constrain_* — identity off-mesh, so the single-host
-    program is byte-identical to the pre-annotation form): row operands pin
-    to the data axis so XLA keeps the IRLS row math shard-local (the psums
-    carry only the (d, d) Hessian/gradient statistics), and the (g, k, d+1)
-    beta batch pins its grid axis to the model axis.  The executable cache
-    keys on the ambient mesh token, so traces under different meshes/process
-    topologies never alias.
+    Under an ambient mesh (read at trace time; the executable cache keys on
+    the mesh token, and operands placed over a mesh key jax's trace apart)
+    the fit runs as :func:`_irls_region`: the one-chip step on each chip's
+    rows, its row sums all-reduced.  Off the mesh the program is the one-chip
+    sweep, unchanged.
     """
-    from ..parallel.mesh import constrain_fold_rows, constrain_grid, \
-        constrain_rows
+    from ..parallel.mesh import current_mesh
 
-    x, y, train_w = constrain_rows(x), constrain_rows(y), \
-        constrain_fold_rows(train_w)
-    fit_fold = jax.vmap(
-        lambda w, reg: _irls_core(x, y, w, reg, max_iter,
-                                  has_intercept=has_intercept),
-        in_axes=(0, None))
-    fit_grid = jax.vmap(lambda reg: fit_fold(train_w, reg), in_axes=0)
-    return constrain_grid(fit_grid(regs))
+    mesh = current_mesh()
+    if mesh is not None:
+        return _irls_region(mesh, x, y, train_w, regs, max_iter,
+                            has_intercept)
+    return _irls_lanes(x, y, train_w, regs, max_iter, has_intercept)
 
 
 @partial(jax.jit, static_argnames=("max_iter", "has_intercept"))
@@ -184,7 +251,11 @@ def _fista_sweep(x, y, train_w, l1s, l2s, max_iter, has_intercept: bool = True):
     (l1, l2) grid (g,) -> betas (g, k, d+1).  Grid points with l1 > 0 are ranked
     under the same composite objective the final fit solves (ADVICE r1: the
     smooth approximation could re-order near-tied grids that vary elastic_net).
-    Sharding annotations as in :func:`_irls_sweep` (identity off-mesh)."""
+
+    dp x mp sharding rides ambient ``with_sharding_constraint`` annotations
+    (parallel/mesh.py:constrain_* — identity off-mesh): row operands pin to
+    the data axis so XLA keeps the row math shard-local, and the (g, k, d+1)
+    beta batch pins its grid axis to the model axis."""
     from ..parallel.mesh import constrain_fold_rows, constrain_grid, \
         constrain_rows
 
@@ -361,7 +432,9 @@ class LogisticRegression(PredictionEstimatorBase):
                 _irls_sweep, xd, yd, train_w, regs,
                 statics=dict(max_iter=int(self.max_iter),
                              has_intercept=has_icpt),
-                label="LogisticRegression/irls_sweep")))
+                label="LogisticRegression/irls_sweep",
+                counts=dict(irls_allreduce_bytes=irls_allreduce_bytes(
+                    k, len(l2_idx), d1, int(self.max_iter))))))
         if en_idx:
             l1s = place_grid(np.asarray([l1l2[i][0] for i in en_idx],
                                         dtype=np.float32))
