@@ -32,10 +32,7 @@ the best of any other family, in the program's last fit and in the reference.
 
 ``BENCHMARK.json`` runs it as the cell ``lr_gbt_sweep_1m``
 (``binsel_lr_gbt_d128`` x ``postprep_1m``, one chip): python3 chipbench/run.py
---workload lr_gbt_sweep_1m --seed <n> --seconds 10 --trace <0|1>.  Six
-per-layer metrics of the cell are built and staged, not yet declared
-(``chipbench/staged_families_metrics.json``, which says why); ``python3
-chipbench/run_staged.py`` with the same arguments reads them too.
+--workload lr_gbt_sweep_1m --seed <n> --seconds 10 --trace <0|1>.
 """
 
 from __future__ import annotations

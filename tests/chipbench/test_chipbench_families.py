@@ -5,28 +5,34 @@ references — every fold-model under its family's limit, the choice among all
 six points, the linear winner's refit; the float8 control failing the linear
 family's own limit; a table of this test's making on which the trees win, so
 that the entry's replay branch runs; the work model against the two accepted
-ones; the six staged readers on hand-made contexts.  CPU only: nothing here is
-a time, a rate or a device number."""
+ones; the cell's six per-layer metrics as declared, and their readers on
+hand-made contexts.  CPU only: nothing here is a time, a rate or a device
+number."""
 
 import importlib
-import json
-import os
 from types import SimpleNamespace
 
 import numpy as np
 import pytest
 
 from chipbench import run as harness
-from chipbench import run_staged, traffic
+from chipbench import traffic
 
 CELL, CONFIG = "lr_gbt_sweep_1m", "binsel_lr_gbt_d128"
 ROWS = 4096
 SEED = 2**31 + 38
 READERS = ["mix_lr_device_s", "mix_gbt_device_s", "mix_roofline",
            "families_queued_s", "release_s", "placement_misses"]
-STAGED = os.path.join(harness.ROOT, "chipbench",
-                      "staged_families_metrics.json")
-BENCH = run_staged.staged_benchmark()
+BENCH = harness.load_benchmark()
+#: the six as PR 38 built them and PR 40 declared them: unit, better, source,
+#: layer
+DECLARED = {
+    "mix_lr_device_s": ("s/fit", "lower", "device_trace", "family programs"),
+    "mix_gbt_device_s": ("s/fit", "lower", "device_trace", "family programs"),
+    "mix_roofline": ("%", "higher", "device_trace", "kernels and XLA scans"),
+    "families_queued_s": ("s/fit", "lower", "program_span", "selector"),
+    "release_s": ("s/fit", "lower", "program_span", "selector"),
+    "placement_misses": ("count", "lower", "program_counter", "placement")}
 entry = importlib.import_module("chipbench.entries.selector_fit_families")
 
 
@@ -88,39 +94,23 @@ def test_the_cell_is_declared_and_its_families_are_the_accepted_ones():
     assert traffic.load(cell["traffic"])["rows"] == 2 ** 20
 
 
-def test_the_staged_metrics_keep_the_contract_and_collide_with_nothing():
-    from test_chipbench_contract import NAME, SOURCES, UNIT
-
-    with open(STAGED) as f:
-        staged = json.load(f)
-    assert set(staged) == {"note", "per_layer"}
-    assert [m["name"] for m in staged["per_layer"]] == READERS
-    with open(os.path.join(harness.ROOT, "chipbench",
-                           "staged_cells.json")) as f:
-        older = json.load(f)
-    taken = {m["name"] for m in harness.load_benchmark()["per_layer"]
-             + harness.load_benchmark()["end_to_end"] + older["per_layer"]}
-    layers = {m["layer"] for m in harness.load_benchmark()["per_layer"]}
-    for m in staged["per_layer"]:
-        assert set(m) == {"name", "unit", "better", "source", "layer",
-                          "moves", "workloads"}
-        assert NAME.match(m["name"]) and UNIT.match(m["unit"])
-        assert m["name"] not in taken
-        assert m["source"] in SOURCES and m["better"] in ("lower", "higher")
-        assert m["layer"] in layers
-        assert (m["moves"], m["workloads"]) == ("fold_models_per_s", [CELL])
-        assert callable(importlib.import_module(
-            f"chipbench.per_layer.{m['name']}").read)
-    # what run_staged.py hands the harness: BENCHMARK.json with every staged
-    # entry at the end of its list
-    assert [m["name"] for m in BENCH["per_layer"][-6:]] == READERS
-    assert BENCH["per_layer"][:len(harness.load_benchmark()["per_layer"])] \
-        == harness.load_benchmark()["per_layer"]
+@pytest.mark.parametrize("name", READERS)
+def test_the_six_are_declared_for_the_cell(name):
+    """Pinned by what the entry says, not by where it stands, and read in
+    the cell and in no other accepted one (the contract's guards hold the
+    characters, the reader's file and unique names)."""
+    (m,) = [m for m in BENCH["per_layer"] if m["name"] == name]
+    assert set(m) == {"name", "unit", "better", "source", "layer", "moves",
+                      "workloads"}
+    assert (m["unit"], m["better"], m["source"], m["layer"]) == DECLARED[name]
+    assert m["moves"] == "fold_models_per_s" and CELL in m["workloads"]
+    assert not {"lr_sweep_4m", "svc_sweep_4m", "lr_sweep_mesh4_16m",
+                "gbt_sweep_1m", "xgb_grid_1m"} & set(m["workloads"])
 
 
 @pytest.fixture(scope="module")
 def tiny_run(tmp_path_factory):
-    """One traced run of the cell at 4,096 rows with the staged metrics."""
+    """One traced run of the cell at 4,096 rows."""
     from test_chipbench_run import _tiny
 
     mp = pytest.MonkeyPatch()
@@ -178,7 +168,7 @@ def test_two_families_through_the_entry_against_the_references(tiny_run):
     assert margin["compared"] == pytest.approx(margin["reference"], abs=2e-3)
 
 
-def test_the_traced_line_holds_the_staged_metrics_a_cpu_can_read(tiny_run):
+def test_the_traced_line_holds_the_six_a_cpu_can_read(tiny_run):
     metrics = tiny_run["metrics"]
     declared = {m["name"] for m in harness.cell_metrics(
         BENCH, "per_layer", CELL)}

@@ -36,6 +36,12 @@ def test_the_cell_is_declared_with_its_five_metrics():
     own = [m["name"] for m in bench["per_layer"]
            if m.get("workloads") == ["gbt_sweep_1m"]]
     assert own == READERS
+    # beside them the six of set-up, listed for every cell
+    from test_chipbench_setup_spans import METRICS as SETUP
+
+    listed = {m["name"] for m in bench["per_layer"]
+              if "gbt_sweep_1m" in m.get("workloads", [])}
+    assert listed == set(READERS) | set(SETUP)
     cfg = _config()
     assert [f["key"] for f in cfg["families"]] == ["gbt"]
     # selector_fit's names (the guards hold the control to its compare too)

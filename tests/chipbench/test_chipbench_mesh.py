@@ -45,9 +45,12 @@ def test_the_cell_is_the_one_four_chip_cell_and_its_files_resolve():
     for name in READERS:
         m = next(m for m in BENCH["per_layer"] if m["name"] == name)
         assert m["workloads"] == [CELL] and m["moves"] == "fold_models_per_s"
-    # every metric without a list is read in this cell as it is
+    # every metric without a list is read in this cell as it is, and the
+    # six of set-up since PR 40 listed it
+    from test_chipbench_setup_spans import METRICS as SETUP
+
     assert {m["name"] for m in harness.cell_metrics(BENCH, "per_layer", CELL)
-            } == set(READERS) | {
+            } == set(READERS) | set(SETUP) | {
         "cv_dispatch_s", "tail_s", "fit_mfu", "window_compiles",
         "setup_cache_loads", "device_idle_share", "peak_hbm_gb"}
 

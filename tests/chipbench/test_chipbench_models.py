@@ -7,6 +7,7 @@ import numpy as np
 import pytest
 
 from chipbench import layerlib, run as harness, traffic
+from chipbench.generators import postprep_table
 from chipbench.reference import common
 
 PARAMS = {**traffic.load("postprep_4m"), "rows": 2048}
@@ -35,9 +36,9 @@ def test_table_has_the_post_transmogrify_shape():
 def test_table_does_not_depend_on_the_number_of_threads(monkeypatch):
     """Each block of rows has its own random stream; the imputed mean is
     taken over all blocks."""
-    monkeypatch.setattr(traffic, "BLOCK_ROWS", 500)     # 5 blocks, one short
+    monkeypatch.setattr(postprep_table, "BLOCK_ROWS", 500)  # 5 blocks, 1 short
     many = traffic.generate(PARAMS, 2**31 + 3)
-    monkeypatch.setattr(traffic, "THREADS", 1)
+    monkeypatch.setattr(postprep_table, "THREADS", 1)
     one = traffic.generate(PARAMS, 2**31 + 3)
     assert np.array_equal(many.x, one.x) and np.array_equal(many.y, one.y)
     nulls = many.x[:, 1] == 1.0

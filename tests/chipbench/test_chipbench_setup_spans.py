@@ -1,11 +1,9 @@
 """The six readers of set-up from inside the program (``chipbench/setuplib.py``
 and ``chipbench/per_layer/setup_*.py``): on hand-made profiles of the
 program's own classes, on a program without the import mark, on a ring that
-has lost the warm-up fit, and on a tiny CPU run of every cell with the six
-lists widened in memory.  CPU only: nothing here is a time or a device
-number."""
+has lost the warm-up fit, and on a tiny CPU run of every cell.  CPU only:
+nothing here is a time or a device number."""
 
-import copy
 import importlib
 import json
 import os
@@ -21,7 +19,9 @@ from transmogrifai_tpu.perf.timers import PhaseRecorder, Span
 REPO = harness.ROOT
 METRICS = ["setup_import_s", "setup_fit_s", "setup_lower_s",
            "setup_cache_load_s", "setup_compile_s", "setup_placement_s"]
-CELLS = ["lr_sweep_4m", "svc_sweep_4m", "gbt_sweep_1m"]
+#: the cells the six are declared for (PR 40); a later cell appends its name
+CELLS = ["lr_sweep_4m", "svc_sweep_4m", "lr_sweep_mesh4_16m", "gbt_sweep_1m",
+         "xgb_grid_1m", "lr_gbt_sweep_1m"]
 with open(os.path.join(REPO, "BENCHMARK.json")) as f:
     BENCH = json.load(f)
 
@@ -208,27 +208,16 @@ def test_a_span_the_probe_heard_of_late_counts_once(ring):
 
 @pytest.mark.parametrize("name", METRICS)
 def test_the_declared_entries(name):
+    """Pinned by what the entry says, not by where it stands: a later PR
+    appends its metrics after these and its cell to their ``workloads``."""
     (m,) = [m for m in BENCH["per_layer"] if m["name"] == name]
-    assert m["workloads"] == CELLS
-    assert (m["unit"], m["better"], m["source"], m["moves"]) == (
-        "s", "lower", "program_span", "setup_s")
-    assert BENCH["per_layer"].index(m) >= len(BENCH["per_layer"]) - 6
-
-
-@pytest.fixture(scope="module")
-def widened():
-    """BENCHMARK.json with the six lists widened to every cell, in memory:
-    how the two cells whose metric sets the benchmark's tests pin are read."""
-    bench = copy.deepcopy(BENCH)
-    for m in bench["per_layer"]:
-        if m["name"] in METRICS:
-            m["workloads"] = [w["name"] for w in bench["workloads"]]
-    return bench
+    assert set(CELLS) <= set(m["workloads"])
+    assert (m["name"], m["unit"], m["better"], m["source"], m["moves"]) == (
+        name, "s", "lower", "program_span", "setup_s")
 
 
 @pytest.mark.parametrize("cell", [w["name"] for w in BENCH["workloads"]])
-def test_a_tiny_run_of_every_cell_reads_the_six(cell, widened, monkeypatch,
-                                                tmp_path):
+def test_a_tiny_run_of_every_cell_reads_the_six(cell, monkeypatch, tmp_path):
     from test_chipbench_run import _tiny
 
     monkeypatch.setenv("TMOG_PALLAS", "interpret")
@@ -236,7 +225,7 @@ def test_a_tiny_run_of_every_cell_reads_the_six(cell, widened, monkeypatch,
     monkeypatch.setattr(harness, "TRACE_DIR", str(tmp_path / "trace"))
     result = harness.run(cell, 2**31 + 36, 0.3, True, require_tpu=False,
                          overrides=_tiny(cell), free_device=False,
-                         bench=widened)
+                         bench=BENCH)
     assert result["correct"] is True, result["compared"]
     got = {name: result["metrics"][name]["value"] for name in METRICS}
     assert all(v >= 0.0 for v in got.values()), got
