@@ -301,11 +301,14 @@ class TestCounts:
         fitted = sel.fit(ds)
         moved = self._moved(before, M.placement_stats())["fit"]
         n_padded = M.padded_row_count(n)
-        # LR: tw, vw.  SVC: y, tw, vw.  RF: tw, vw.  Both evaluators: y.
+        # The validator places y and base_w beside the fold ids, so LR's y
+        # and the fold blocks' base_w pass through.  LR: y, tw, vw.  SVC: y,
+        # tw, vw.  RF: tw, vw.  The blocks: base_w.  Both evaluators: y.
         # A linear winner's refit: y, w (the forest's refit places its own).
         linear = fitted.summary.best_model_name != "RandomForestClassifier"
         rf = "rf" in families
-        assert moved["passed_through"] == 2 + 3 + 2 * rf + 2 + 2 * linear
+        assert moved["passed_through"] == \
+            3 + 3 + 2 * rf + 1 + 2 + 2 * linear
         assert moved["derived"] == 4                # tw, vw, ±1, unit weights
         assert moved["bytes_derived"] == (2 * 3 + 2) * 4 * n_padded
         assert moved["bytes_passed"] >= 4 * 3 * 4 * n_padded

@@ -296,13 +296,13 @@ def place_fit_arrays(x, y, w):
     cache (a refit after CV hits the block the sweep already transferred),
     labels/weights zero-padded to match — through ``place_fit_rows``, so
     inside a selector fit the handles the sweep placed come back as they are."""
-    from ..parallel.mesh import place_fit_rows, place_rows_bucketed_cached
+    from ..parallel.mesh import place_fit_vector, place_rows_bucketed_cached
 
     x32 = np.asarray(x, np.float32)
     xd, _ = place_rows_bucketed_cached(x32)
     n_padded = int(xd.shape[0])
-    return (xd, place_fit_rows(y, n_padded, np.float32),
-            place_fit_rows(w, n_padded, np.float32))
+    return (xd, place_fit_vector(y, n_padded),
+            place_fit_vector(w, n_padded))
 
 
 @partial(jax.jit, static_argnames=("has_intercept", "standardize"))
@@ -409,11 +409,12 @@ class LogisticRegression(PredictionEstimatorBase):
         # via sweep_placements); standardization runs on device.
         from .base import sweep_placements
 
+        from ..parallel.mesh import fit_vector
         from ..perf.timers import activity
 
         x32 = np.asarray(x, np.float32)
         xd_raw, (yd,), train_w, val_w, n0 = sweep_placements(
-            x32, [np.asarray(y)], train_w, val_w)
+            x32, [fit_vector(y)], train_w, val_w)
         with activity("launch", label="LogisticRegression/prepare"):
             xd = _device_prepare(xd_raw, jnp.int32(n0),
                                  has_intercept=bool(self.fit_intercept),

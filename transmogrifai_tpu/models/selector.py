@@ -216,7 +216,7 @@ class ModelSelector(PredictionEstimatorBase):
         def evaluate(ev, w: Optional[np.ndarray]) -> Dict[str, float]:
             if payload is not None and hasattr(ev, "evaluate_device") \
                     and getattr(ev, "num_thresholds", 0) == 0:
-                from ..parallel.mesh import place_fit_rows
+                from ..parallel.mesh import place_fit_vector
                 from .base import unit_weights
 
                 # labels/weights over the PAYLOAD's row count (bucket+mesh
@@ -229,10 +229,10 @@ class ModelSelector(PredictionEstimatorBase):
                 if id(w) not in _w_dev:
                     _w_dev[id(w)] = unit_weights(len(y), n_padded) \
                         if w is None else \
-                        place_fit_rows(w, n_padded, np.float32)
+                        place_fit_vector(w, n_padded)
                 return ev.evaluate_device(
                     payload[0], payload[1],
-                    place_fit_rows(y, n_padded), _w_dev[id(w)])
+                    place_fit_vector(y, n_padded), _w_dev[id(w)])
             return ev.evaluate_arrays(y.astype(np.float64), pred_col(), w=w)
 
         train_eval: Dict[str, float] = {}

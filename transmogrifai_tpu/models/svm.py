@@ -238,12 +238,12 @@ class LinearSVC(PredictionEstimatorBase):
         regs = place_grid(np.asarray(
             [float(g.get("reg_param", self.reg_param)) for g in grids],
             dtype=np.float32))
-        from ..parallel.mesh import DATA_AXIS
+        from ..parallel.mesh import DATA_AXIS, fit_vector
         from ..perf.programs import run_cached
 
         x32 = np.asarray(x, np.float32)
         xd, (yd,), tw, vw, n0 = sweep_placements(
-            x32, [np.asarray(y, np.float32)], train_w, val_w)
+            x32, [fit_vector(y)], train_w, val_w)
         # the ±1 targets are a function of the placed labels: made there, not
         # as a second host vector to pad, hash and look up
         ypmd = derive_on_device(_sign_targets, yd, jnp.int32(n0),

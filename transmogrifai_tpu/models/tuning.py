@@ -13,6 +13,7 @@ pool, OpCrossValidation.scala:114-134).
 from __future__ import annotations
 
 import contextvars
+import time
 from dataclasses import dataclass, field
 from functools import partial
 from typing import Any, Dict, List, Optional, Sequence, Tuple
@@ -262,13 +263,13 @@ class FoldWeights:
             return self.host()
         if self._device is None:
             from ..parallel.mesh import (
-                DATA_AXIS, padded_row_count, place_fit_rows)
+                DATA_AXIS, padded_row_count, place_fit_rows, place_fit_vector)
             from .base import derive_on_device
 
             n_padded = padded_row_count(len(self.fold_id))
             self._device = derive_on_device(
                 _fold_weight_blocks, place_fit_rows(self.fold_id, n_padded),
-                place_fit_rows(self.base_w, n_padded),
+                place_fit_vector(self.base_w, n_padded),
                 axes=(None, DATA_AXIS),
                 statics=dict(num_folds=self.num_folds),
                 label="CrossValidator/fold_weights")
@@ -312,19 +313,27 @@ class CrossValidator:
 
     def fold_ids(self, y: np.ndarray) -> np.ndarray:
         """(n,) fold each row is validated in: the assignment, as small
-        integers (4 MB at 4M rows where the weights it implies are 96 MB)."""
+        integers (4 MB at 4M rows where the weights it implies are 96 MB).
+        Unstratified it is ``default_rng(seed).permutation(n) % k``."""
         n = len(y)
         rng = np.random.default_rng(self.seed)
+        dtype = np.int8 if self.num_folds <= 127 else np.int32
         if self.stratify:
             fold_id = np.empty(n, dtype=np.int64)
             for lbl in np.unique(y):
                 idx = np.flatnonzero(y == lbl)
                 idx = rng.permutation(idx)
                 fold_id[idx] = np.arange(len(idx)) % self.num_folds
-        else:
-            fold_id = rng.permutation(n)
-            fold_id %= self.num_folds   # in place: no second (n,) int64
-        return fold_id.astype(np.int8 if self.num_folds <= 127 else np.int32)
+            return fold_id.astype(dtype)
+        # the shuffle draws the same swaps for n rows whatever their dtype,
+        # so shuffling ``arange(n) % k`` is ``permutation(n) % k`` to the
+        # bit, with no (n,) int64 made, divided and cast.  ``np.tile``, not
+        # ``np.resize``: that joins n/k tiny copies holding the GIL (about a
+        # second at 2^24 rows) and stalls the placements made beside the ids
+        k = self.num_folds
+        fold_id = np.tile(np.arange(k, dtype=dtype), -(-n // k))[:n]
+        rng.shuffle(fold_id)
+        return fold_id
 
     def fold_weights(self, y: np.ndarray, base_w: np.ndarray
                      ) -> Tuple[np.ndarray, np.ndarray]:
@@ -348,13 +357,57 @@ class CrossValidator:
         # and built on the host only for those that do not.  A validator with
         # a ``fold_weights`` of its own has made host blocks, and they are
         # what every family gets.
-        with activity("fold_weights"):
-            if type(self).fold_weights is _STOCK_FOLD_WEIGHTS:
-                folds = FoldWeights(self.fold_ids(y), base_w, self.num_folds)
-            else:
+        if type(self).fold_weights is _STOCK_FOLD_WEIGHTS:
+            from ..parallel.mesh import fit_vector
+
+            base_w = fit_vector(base_w)     # what the folds keep and place
+            folds = FoldWeights(self._fold_ids_beside_placements(
+                models, y, base_w), base_w, self.num_folds)
+        else:
+            with activity("fold_weights"):
                 folds = FoldWeights.of_host(*self.fold_weights(y, base_w))
         with folds:     # what ``folds_of`` answers with while families dispatch
             return self._validate_folds(models, x, y, folds)
+
+    def _fold_ids_beside_placements(self, models, y: np.ndarray,
+                                    base_w: np.ndarray) -> np.ndarray:
+        """``fold_ids(y)``, made on a worker thread while this one places the
+        fit's labels and base weights as every reader asks for them
+        (``place_fit_vector``, in the fit's table, so their requests pass by
+        identity): where a fit's table is open and some family takes device
+        folds.  The ids and the hashes of the placements release the GIL, so
+        the one hides behind the other.  ``host.fold_weights`` is this
+        thread's wait for the ids; it notes how long they took (``made_s``)
+        and how much of that the placements covered (``hidden_s``).  The
+        worker records no span: span readers take a fit's spans as one
+        thread's."""
+        from concurrent.futures import ThreadPoolExecutor
+
+        from ..parallel.mesh import (
+            fit_table_open, fit_vector, padded_row_count, place_fit_vector)
+        from ..perf.timers import activity
+
+        def timed():
+            t0 = time.perf_counter()
+            return self.fold_ids(y), t0, time.perf_counter()
+
+        with ThreadPoolExecutor(1, thread_name_prefix="fold_ids") as worker:
+            making = worker.submit(timed)
+            p0 = p1 = time.perf_counter()
+            if fit_table_open() and any(est.takes_device_folds()
+                                        for est, _ in models):
+                n_padded = padded_row_count(len(y))
+                for v in (y, base_w):
+                    # where a cast would make a new object, no later request
+                    # could find this one
+                    if fit_vector(v) is v:
+                        place_fit_vector(v, n_padded)
+                p1 = time.perf_counter()
+            with activity("fold_weights") as span:
+                fold_id, t0, t1 = making.result()
+                span.note(made_s=t1 - t0,
+                          hidden_s=max(0.0, min(t1, p1) - max(t0, p0)))
+        return fold_id
 
     def _validate_folds(self, models, x, y, folds: "FoldWeights"
                         ) -> ValidationResult:

@@ -625,6 +625,12 @@ class fit_placements:
         _FIT_PLACED.reset(self._token)
 
 
+def fit_table_open() -> bool:
+    """Whether a fit's table is open: what ``place_fit_rows`` places now is
+    what the fit's later requests for the same object get."""
+    return _FIT_PLACED.get() is not None
+
+
 @contextlib.contextmanager
 def ensure_fit_placements():
     """The open fit's table, or one for the length of the block where none
@@ -692,6 +698,23 @@ def place_fit_rows(arr, n_padded: int, dtype=None):
     if table is not None:
         table[key] = (arr, placed)
     return placed
+
+
+def fit_vector(v) -> np.ndarray:
+    """The host object a fit places for its ``(n,)`` labels or row weights:
+    ``v`` in float32, ``v`` itself where it is float32 already.  Every
+    request for a fit's labels and base weights — the validator's beside the
+    fold ids, the families' sweeps and refits, the evaluators — is made in
+    this form (``place_fit_vector``), so that inside ``fit_placements()``
+    the first places it and the rest pass by identity."""
+    return np.asarray(v, np.float32)
+
+
+def place_fit_vector(v, n_padded: int):
+    """``place_fit_rows`` of ``fit_vector(v)`` (a placed array passes as it
+    is): a fit's labels or row weights as every reader of the fit asks for
+    them."""
+    return place_fit_rows(v, n_padded, np.float32)
 
 
 def place_rows_bucketed_cached(arr: np.ndarray,
