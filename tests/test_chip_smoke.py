@@ -150,6 +150,18 @@ def _bucketize():
             (S((N,), F32), S((6,), F32)))
 
 
+def _hinge():
+    from transmogrifai_tpu.perf.kernels import descent
+    from transmogrifai_tpu.perf.kernels.dispatch import descent_tile
+
+    def step(x, y, w, beta):
+        block = descent.hinge_block(x, y, w, descent_tile(D))
+        return descent.hinge_grad_pallas(block, beta, True)
+
+    return step, (S((N, D + 1), F32), S((N,), F32), S((N,), F32),
+                  S((D + 1,), F32))
+
+
 TPU_KERNELS = {
     "split_scan@gbt": lambda: _split(chip_smoke.FOLDS, 4),
     # the deepest level of a depth-6 boosted tree (the grid cell, PR 34)
@@ -161,6 +173,8 @@ TPU_KERNELS = {
     "hist_level@stream-int8": lambda: _hist("stream", True),
     "hist_level@resident-bf16": lambda: _hist("resident", False),
     "onehot_codes": _onehot,
+    # the SVC refit's squared-hinge step, the ones column last
+    "hinge_grad@refit": _hinge,
     "bucketize_right_encode": _bucketize,
 }
 

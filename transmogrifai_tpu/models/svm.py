@@ -4,8 +4,9 @@ Reference capability: core/.../classification/OpLinearSVC.scala (wrapping Spark
 LinearSVC: hinge loss via OWLQN, L2 reg, no probability output).
 
 TPU-first: squared hinge is smooth, so a fixed-iteration Nesterov descent under
-``lax.fori_loop`` compiles to one XLA program; the gradient is a single matvec pair.
-Like Spark's LinearSVC the model emits rawPrediction only (no probabilities) — the
+``lax.fori_loop`` compiles to one XLA program; the gradient is a single matvec pair,
+and in the refit on a TPU one pass of the descent kernel (perf/kernels/descent.py)
+that reads the block once a step.  Like Spark's LinearSVC the model emits rawPrediction only (no probabilities) — the
 binary evaluator ranks by the margin.
 """
 
@@ -51,17 +52,31 @@ def _momentum_descent(loss_grad, curvature, d1: int, reg, max_iter: int,
 
 
 def _svc_body(x: jnp.ndarray, y_pm: jnp.ndarray, w: jnp.ndarray, reg: jnp.ndarray,
-              max_iter: int, has_intercept: bool = True) -> jnp.ndarray:
+              max_iter: int, has_intercept: bool = True,
+              descent: str = "xla") -> jnp.ndarray:
     """Squared-hinge descent; y in {-1, +1}.  With ``has_intercept`` the
     trailing ones column is exempt from L2 (it IS the intercept); without it
-    every column is a real feature and all are regularized."""
+    every column is a real feature and all are regularized.
+
+    ``descent`` is ``dispatch.descent_mode``'s answer: off ``xla`` the
+    gradient is the one-read kernel (perf/kernels/descent.py) over the
+    block's transpose, else XLA's margin and gradient products."""
+    from ..perf.kernels import descent as _kdesc
+    from ..perf.kernels.dispatch import descent_tile
+
     sw = jnp.maximum(w.sum(), 1e-12)
 
-    def hinge_grad(beta):
-        z = x @ beta
-        margin = 1.0 - y_pm * z
-        active = jnp.maximum(margin, 0.0)
-        return x.T @ (w * (-2.0 * y_pm * active)) / sw
+    if descent != "xla":
+        block = _kdesc.hinge_block(
+            x, y_pm, w, descent_tile(x.shape[1] - has_intercept))
+
+        def hinge_grad(beta):
+            return _kdesc.hinge_grad_pallas(
+                block, beta, has_intercept,
+                interpret=descent == "interpret") / sw
+    else:
+        def hinge_grad(beta):
+            return _kdesc.hinge_grad_xla(x, y_pm, w, beta) / sw
 
     # Lipschitz bound for the step size: squared hinge curvature <= 2 ||x||^2
     return _momentum_descent(
@@ -69,8 +84,8 @@ def _svc_body(x: jnp.ndarray, y_pm: jnp.ndarray, w: jnp.ndarray, reg: jnp.ndarra
         x.shape[1], reg, max_iter, has_intercept, x.dtype)
 
 
-_svc_core = partial(jax.jit,
-                    static_argnames=("max_iter", "has_intercept"))(_svc_body)
+_svc_core = partial(jax.jit, static_argnames=(
+    "max_iter", "has_intercept", "descent"))(_svc_body)
 
 
 @jax.jit
@@ -197,6 +212,7 @@ class LinearSVC(PredictionEstimatorBase):
     sweepable_params = ("reg_param",)
 
     def _fit_arrays(self, x, y, w):
+        from ..perf.kernels import dispatch as _kdispatch
         from ..perf.timers import activity
 
         xd, yd, wd = place_fit_arrays(x, y, w)
@@ -205,11 +221,14 @@ class LinearSVC(PredictionEstimatorBase):
                 xd, wd, has_intercept=bool(self.fit_intercept),
                 standardize=bool(self.standardize))
             y_pm = jnp.where(yd > 0.5, 1.0, -1.0).astype(jnp.float32)
-        with activity("launch", label="LinearSVC/svc_refit"):
+        descent = _kdispatch.descent_mode(
+            int(xs.shape[1]) - bool(self.fit_intercept))
+        with activity("launch", label="LinearSVC/svc_refit",
+                      descent_kernel=descent):
             beta = _svc_core(
                 xs, y_pm, wd,
                 jnp.float32(self.reg_param), int(self.max_iter),
-                has_intercept=bool(self.fit_intercept))
+                has_intercept=bool(self.fit_intercept), descent=descent)
         with activity("device_wait", label="LinearSVC/refit"):
             beta, mean, std = (np.asarray(a) for a in (beta, mean_d, std_d))
         if self.fit_intercept:
@@ -233,6 +252,11 @@ class LinearSVC(PredictionEstimatorBase):
         if (not self.standardize
                 or any(set(g) - {"reg_param"} for g in grids)):
             return None
+        from ..perf.kernels.dispatch import prefetch_pallas
+
+        # the winner's refit traces the descent kernel: its import runs
+        # beside the placements below
+        prefetch_pallas()
         from .base import derive_on_device, place_grid, sweep_placements
 
         regs = place_grid(np.asarray(

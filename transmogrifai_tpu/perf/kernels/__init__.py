@@ -27,6 +27,9 @@ Modules:
   (``ops/bucketizers.py``).
 - :mod:`.routing` — the tree walk's row select: the level's columns
   gathered on the MXU, the compare-reduce, and the routing kernel.
+- :mod:`.descent` — one step of the linear SVC's squared-hinge descent
+  (margins, residuals and gradient) in one read of the block, and the
+  XLA formulation it replaces.
 
 Tuning: every kernel resolves its schedule parameters as explicit arg >
 env knob > the persistent autotuner's verified winner for the shape class

@@ -1,4 +1,5 @@
-"""Kernel dispatch layer: who runs a tree/encode hot loop, and how we know.
+"""Kernel dispatch layer: who runs a tree, encode or descent hot loop, and how
+we know.
 
 Reference role (SURVEY §2.9): the reference dispatches its tree hot loops to
 XGBoost's native C++ kernels through the JNI when the library is present and
@@ -27,10 +28,10 @@ formulation — with the decision itself made observable and cacheable:
   the same fallback discipline the fused transform planner established
   (``TMOG_FUSED_TRANSFORM``, PR 4).
 - VMEM admission guards (``hist_mode``/``split_mode``/``route_mode``/
-  ``encode_mode``): compiled Pallas keeps its accumulator and operands
-  resident in VMEM, so a shape whose working set exceeds the budget
-  (``TMOG_PALLAS_VMEM_BUDGET``, default 10 MiB of the compiler's 16 MiB
-  scoped limit — see ``_DEFAULT_VMEM_BUDGET``) takes the XLA path by this
+  ``encode_mode``/``descent_mode``): compiled Pallas keeps its accumulator
+  and operands resident in VMEM, so a shape whose working set exceeds the
+  budget (``TMOG_PALLAS_VMEM_BUDGET``, default 10 MiB of the compiler's 16
+  MiB scoped limit — see ``_DEFAULT_VMEM_BUDGET``) takes the XLA path by this
   module's DECISION, before the compiler is asked.  Nothing here catches a
   compiler error and reroutes: a kernel that is selected and then refused
   fails the program.  Interpret mode has no such limit.
@@ -61,6 +62,12 @@ formulation — with the decision itself made observable and cacheable:
                         headline shapes run the XLA scan.  Repaired in
                         PR 21 for the int8 path (no int8 multiply or
                         sublane broadcast on the v5e vector unit).
+  ``hinge_grad_pallas`` selected where its tile admits (d=128: 8192-row
+                        tiles).  The SVC refit's step over a 2^22 x 129
+                        float32 block: 2.84 ms, one read at 768 GB/s,
+                        where XLA's margin and gradient fusions take 6.13
+                        ms together (``descent_mode``; under a
+                        multi-device mesh ``xla`` in every mode).
   ====================  =================================================
 
   Under a multi-device ambient mesh (``parallel.mesh.use_mesh``) ``auto``
@@ -80,6 +87,7 @@ from __future__ import annotations
 
 import logging
 import os
+import sys
 import threading
 from contextlib import contextmanager
 from typing import Any, Dict, Optional, Tuple
@@ -354,6 +362,78 @@ def encode_mode(width: int, block_rows: int = 1024) -> Optional[str]:
     if width <= 0:
         return None
     return _admit("encode", 2 * block_rows * (width + 2) * 4)
+
+
+#: the descent kernel's largest tile, rows (64 sublane rows of 128 lanes)
+_DESCENT_TILE_MAX = 8192
+
+
+def _descent_working_set(d: int, tile_rows: int) -> int:
+    """VMEM of one descent grid step: the (d + 1, tile) block (sublanes
+    padded to 8) and the labels' and weights' tiles, double-buffered, and
+    the (d, 128) coefficients and (d + 1, 128) accumulators, double-buffered
+    too."""
+    rows = -(-(d + 1) // 8) * 8
+    return 2 * (rows + 2) * tile_rows * 4 + 2 * (2 * rows) * 128 * 4
+
+
+def descent_tile(d: int) -> int:
+    """Rows of the descent kernel's tile at ``d`` feature columns: the
+    largest of 1024, 2048, ... 8192 whose working set fits the VMEM budget
+    (1024 where none does: ``descent_mode`` then declines the kernel)."""
+    rows = 1024
+    while (rows < _DESCENT_TILE_MAX and _descent_working_set(d, 2 * rows)
+           <= vmem_budget()):
+        rows *= 2
+    return rows
+
+
+def descent_mode(d: int) -> str:
+    """Dispatch decision for the squared-hinge step kernel
+    (perf/kernels/descent.py) at ``d`` feature columns: ``pallas``,
+    ``interpret`` or ``xla``.  Unlike the tree kernels it answers ``xla``
+    under a multi-device ambient mesh in every mode: the refit's block is
+    sharded by rows there and the kernel is not wrapped in ``shard_map``."""
+    from ...parallel.mesh import current_mesh
+
+    mesh = current_mesh()
+    if mesh is not None and mesh.size > 1:
+        count_selection("descent", "xla")
+        return "xla"
+    return _admit("descent",
+                  _descent_working_set(d, descent_tile(d))) or "xla"
+
+
+#: set once a process has started importing Pallas (:func:`prefetch_pallas`)
+_PALLAS_PREFETCHED = False
+_PREFETCH_LOCK = threading.Lock()
+
+
+def prefetch_pallas() -> None:
+    """Import jax's Pallas on a daemon thread, once a process, where a
+    kernel may be traced (the mode is not ``xla``).  The import takes 1.3 s
+    and more (jax 0.9 brings its GPU interpreter with it); a fit that knows
+    a kernel is coming starts it before work that releases the GIL, such as
+    the table's stamp and transfer, so the kernel's first trace does not
+    wait for it.  Where the import is still running, that trace waits for
+    it on the module's import lock."""
+    global _PALLAS_PREFETCHED
+    if "jax.experimental.pallas.tpu" in sys.modules \
+            or kernel_mode() == "xla":
+        return
+    with _PREFETCH_LOCK:
+        if _PALLAS_PREFETCHED:
+            return
+        _PALLAS_PREFETCHED = True
+    threading.Thread(target=_import_pallas, name="transmogrifai-pallas",
+                     daemon=True).start()
+
+
+def _import_pallas() -> None:
+    try:
+        from jax.experimental.pallas import tpu  # noqa: F401
+    except Exception:  # pragma: no cover — the kernel's own import raises
+        log.debug("Pallas prefetch failed", exc_info=True)
 
 
 def kernel_provenance() -> Dict[str, Any]:
