@@ -407,37 +407,84 @@ def phase_serve(model, df, prediction, scored_pred):
 
 def phase_kernels(mode: str, rf_depth: int) -> Dict[str, str]:
     """Compile each Pallas kernel ``auto`` can select at the shapes this
-    run's train produced, and the routing kernel the grower no longer
-    reaches (PR 33), and compare it with its XLA twin (the autotuner's
-    kernel + reference pairs).  Bitwise on the integer fixtures."""
-    from transmogrifai_tpu.perf import autotune
+    run's train produced and compare it with its XLA twin, bitwise on
+    seeded integer fixtures."""
+    import jax
+    import jax.numpy as jnp
+
+    from transmogrifai_tpu.perf.kernels import encode as KE
+    from transmogrifai_tpu.perf.kernels import histogram as KH
+    from transmogrifai_tpu.perf.kernels import splitscan as KS
+
+    interpret = mode != "pallas"
+    rng = np.random.default_rng(17)
+    B = BINS + 1
+
+    def split(lanes: int, nodes: int):
+        # a histogram the grower could have built: EVERY feature's bins sum
+        # to the node totals (independent draws per feature leave negative
+        # right-child hessians, where the gain formula's eps guard — and
+        # with it the comparison — degenerates to 1/0 vs 1/1e-12)
+        cells = (lanes, nodes, 1, WIDTH)
+
+        def spread(totals):
+            return rng.multinomial(
+                np.broadcast_to(totals[..., None], cells), np.full(B, 1 / B))
+
+        pos, neg = (rng.integers(0, 120, (lanes, nodes, 1)) for _ in range(2))
+        tot_h = rng.integers(B, 8 * B, (lanes, nodes, 1))
+        hists = [jnp.asarray(h.astype(np.float32))
+                 for h in (spread(pos) - spread(neg), spread(tot_h),
+                           pos - neg, tot_h)]
+        mask = jnp.ones((lanes, WIDTH), jnp.float32)
+        params = [jnp.float32(v) for v in (1.0, 0.5, 0.1, 1.0)]
+        kernel = jax.jit(lambda *a: KS.split_scan_pallas(
+            *a[:5], BINS, *a[5:], interpret=interpret))
+        return (kernel(*hists, mask, *params),
+                KS.split_scan_xla(*hists, mask, BINS, *params))
+
+    def hist(rows: int, lanes: int, nodes: int):
+        local = jnp.asarray(
+            rng.integers(-1, nodes, (lanes, rows)).astype(np.int32))
+        ghT = jnp.asarray(
+            rng.integers(-3, 4, (lanes, 2, rows)).astype(np.int8))
+        binned = jnp.asarray(
+            rng.integers(0, B, (rows, WIDTH)).astype(np.int32))
+        # the VMEM admission guard keeps the default 2048-row chunk on the
+        # XLA scan at this width; a 512-row chunk is what it admits
+        kernel = jax.jit(lambda *a: KH.hist_level_pallas(
+            *a, nodes, BINS, int_exact=True, interpret=interpret, chunk=512))
+        return (kernel(local, ghT, binned),
+                KH.hist_level_xla(local, ghT, binned, nodes, BINS,
+                                  int_exact=True))
+
+    def encode(rows: int, width: int):
+        codes = jnp.asarray(
+            rng.integers(-1, width + 1, rows).astype(np.int32))
+        kernel = jax.jit(lambda c: KE.onehot_codes(
+            c, width, interpret=interpret))
+        return kernel(codes), jax.nn.one_hot(codes, width, dtype=jnp.float32)
 
     # lanes only lengthen a kernel's grid, so the RF-shaped split block (one
     # lane x 2^(depth-1) nodes per step; binary RF has one class channel)
     # runs at 8 lanes instead of FOLDS x trees; rows likewise
-    shapes = [
-        ("split", {"lanes": FOLDS, "nodes": 4, "classes": 1,
-                   "features": WIDTH, "bins": BINS}),
-        ("split", {"lanes": 8, "nodes": 2 ** (rf_depth - 1), "classes": 1,
-                   "features": WIDTH, "bins": BINS}),
-        ("route", {"rows": 8192, "features": WIDTH, "lanes": FOLDS}),
-        ("route", {"rows": 8192, "features": WIDTH, "lanes": 1}),
-        ("encode", {"rows": 8192, "width": PICKLISTS[0] + 2}),  # +OTHER+null
-        # hist: the VMEM admission guard keeps the default 2048-row chunk on
-        # the XLA scan at this width; a 512-row chunk is what it admits
-        ("hist", {"rows": 8192, "features": WIDTH, "bins": BINS,
-                  "lanes": FOLDS, "nodes": 2, "classes": 1}),
-    ]
+    pairs = {
+        f"split_scan@lanes{FOLDS}-nodes4": lambda: split(FOLDS, 4),
+        f"split_scan@lanes8-nodes{2 ** (rf_depth - 1)}":
+            lambda: split(8, 2 ** (rf_depth - 1)),
+        # +OTHER+null
+        f"onehot_codes@width{PICKLISTS[0] + 2}":
+            lambda: encode(8192, PICKLISTS[0] + 2),
+        "hist_level@chunk512": lambda: hist(8192, FOLDS, 2),
+    }
     verdicts = {}
-    for family, dims in shapes:
-        cls = autotune.shape_class(family, mode, **dims)
-        params = dict(autotune.family_defaults(family, cls))
-        if family == "hist":
-            params["chunk"] = 512
-        make, reference = autotune._family_bench(family, dims, mode)
-        ok = autotune._verify(make(params)(), reference(), family)
-        check(ok, f"kernel {cls} {params} differs from its XLA twin")
-        verdicts[cls] = "matches"
+    for name, run in pairs.items():
+        got, want = run()
+        same = all(np.array_equal(np.asarray(a), np.asarray(b))
+                   for a, b in zip(jax.tree.leaves(got),
+                                   jax.tree.leaves(want)))
+        check(same, f"kernel {name} ({mode}) differs from its XLA twin")
+        verdicts[name] = "matches"
     log(f"kernels vs XLA twins ({mode}): {len(verdicts)} shapes match")
     return verdicts
 
@@ -537,7 +584,7 @@ def run(rows: int = ROWS, rf_trees: int = 50, gbt_rounds: int = 50,
             check(selected.get(f"{kernel}:{mode}", 0) > 0,
                   f"{kernel} kernel was never selected as {mode}: {selected}")
         # ... but for routing, which the grower runs as the XLA compare-reduce
-        # in every mode (phase_kernels compared the kernel it names itself)
+        # in every mode
         check(selected.get("route:xla", 0) > 0,
               f"the grower's routing was never counted as xla: {selected}")
     snap = compile_snapshot()

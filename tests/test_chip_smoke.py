@@ -39,7 +39,7 @@ class TestSmokeBody:
             assert picked.get(f"{kernel}:interpret", 0) > 0, picked
         assert picked.get("route:xla", 0) > 0, picked
         assert picked.get("route:interpret", 0) == 0, picked
-        assert len(out["kernels"]) == 6
+        assert len(out["kernels"]) == 4
         assert set(out["kernels"].values()) == {"matches"}
         # the verdict the driver parses: these keys and no others
         verdict = json.loads(chip_smoke.verdict_line(out))
@@ -120,19 +120,13 @@ def _split(lanes, nodes):
         (hist, hist, tot, tot, S((lanes, D), F32)))
 
 
-def _route(lanes):
-    from transmogrifai_tpu.perf.kernels.routing import row_select_lanes_pallas
-
-    return row_select_lanes_pallas, (S((N, D), I32), S((lanes, N), I32))
-
-
-def _hist(variant, int_exact):
+def _hist(int_exact):
     from transmogrifai_tpu.perf.kernels.histogram import hist_level_pallas
 
     gh = S((3, 2, 8192), jnp.int8 if int_exact else F32)
     return (lambda lo, g, b: hist_level_pallas(
         lo, g, b, 2, BINS, int_exact=int_exact, mxu_dtype=jnp.bfloat16,
-        chunk=512, variant=variant),
+        chunk=512),
         (S((3, 8192), I32), gh, S((8192, D), I32)))
 
 
@@ -167,11 +161,8 @@ TPU_KERNELS = {
     # the deepest level of a depth-6 boosted tree (the grid cell, PR 34)
     "split_scan@gbt-depth6": lambda: _split(chip_smoke.FOLDS, 32),
     "split_scan@rf": lambda: _split(chip_smoke.FOLDS * 50, 32),
-    "row_select_lanes@gbt": lambda: _route(chip_smoke.FOLDS),
-    "row_select_lanes@refit": lambda: _route(1),
-    "hist_level@stream-bf16": lambda: _hist("stream", False),
-    "hist_level@stream-int8": lambda: _hist("stream", True),
-    "hist_level@resident-bf16": lambda: _hist("resident", False),
+    "hist_level@stream-bf16": lambda: _hist(False),
+    "hist_level@stream-int8": lambda: _hist(True),
     "onehot_codes": _onehot,
     # the SVC refit's squared-hinge step, the ones column last
     "hinge_grad@refit": _hinge,

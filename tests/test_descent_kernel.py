@@ -126,7 +126,7 @@ class TestDescentMode:
         # no tile of a 4096-column block fits the budget: XLA serves it
         assert KD.descent_tile(4096) == 1024
         assert KD.descent_mode(4096) == "xla"
-        monkeypatch.setenv("TMOG_PALLAS_VMEM_BUDGET", str(4 << 20))
+        monkeypatch.setattr(KD, "_VMEM_BUDGET", 4 << 20)
         assert KD.descent_tile(D) == 2048
         assert KD.descent_mode(D) == "pallas"
 

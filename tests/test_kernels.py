@@ -137,33 +137,177 @@ class TestDispatch:
         assert fps["xla"] != fps["interpret"]
 
     def test_provenance_reports_bound_knobs(self, monkeypatch):
-        # provenance reports the values BOUND into models/trees.py — the
-        # ones traced programs actually used, incl. test monkeypatches
+        # provenance reports the values BOUND into models/trees.py and
+        # dispatch — the ones traced programs actually used, incl. test
+        # monkeypatches
         monkeypatch.setattr(T, "_HIST_CHUNK", 512)
+        monkeypatch.setattr(KD, "_VMEM_BUDGET", 2097152)
         prov = KD.kernel_provenance()
         assert prov["hist_chunk"] == 512
-        assert prov["hist_unroll"] == T._HIST_UNROLL
+        assert prov["pallas_vmem_budget"] == 2097152
         assert prov["kernel_mode"] in ("xla", "pallas", "interpret")
-        # the one env-knob helper: parses, clamps, and survives junk
-        monkeypatch.setenv("TMOG_HIST_CHUNK", "512")
-        assert KD.tuning_int("TMOG_HIST_CHUNK", 2048) == 512
-        monkeypatch.setenv("TMOG_HIST_CHUNK", "junk")
-        assert KD.tuning_int("TMOG_HIST_CHUNK", 2048) == 2048
+        assert set(prov) == {"kernel_mode", "tmog_pallas", "hist_chunk",
+                             "pallas_vmem_budget", "serve_donation",
+                             "selected"}
 
     def test_cache_token_carries_vmem_budget_in_pallas_mode(self, monkeypatch):
         # the budget decides which call sites trace the kernel vs the XLA
         # fallback, so two budgets must be two program families
         with KD.force_kernel_mode("pallas"):
             t1 = KD.cache_token()
-            monkeypatch.setenv("TMOG_PALLAS_VMEM_BUDGET", "2097152")
+            monkeypatch.setattr(KD, "_VMEM_BUDGET", 2097152)
             t2 = KD.cache_token()
         assert t1 != t2
         with KD.force_kernel_mode("xla"):
-            monkeypatch.setenv("TMOG_PALLAS_VMEM_BUDGET", "4194304")
+            monkeypatch.setattr(KD, "_VMEM_BUDGET", 4194304)
             t3 = KD.cache_token()
-            monkeypatch.delenv("TMOG_PALLAS_VMEM_BUDGET")
+            monkeypatch.undo()
             # budget is irrelevant off the compiled path: token stable
             assert KD.cache_token() == t3
+
+    @pytest.mark.parametrize("mode,donate,token", [
+        ("xla", False, "kernels:xla"),
+        ("interpret", False, "kernels:interpret"),
+        ("pallas", False, "kernels:pallas:vmem=10485760"),
+        ("xla", True, "kernels:xla:serve-donate"),
+        ("interpret", True, "kernels:interpret:serve-donate"),
+        ("pallas", True, "kernels:pallas:vmem=10485760:serve-donate")])
+    def test_cache_token_literal_strings(self, mode, donate, token):
+        """The token every program key, plan fingerprint and deploy
+        manifest carries: the kernel mode (with the VMEM budget where
+        compiled Pallas runs) and the serve-donation choice, nothing else."""
+        with KD.force_kernel_mode(mode), KD.force_serve_donation(donate):
+            assert KD.cache_token() == token
+
+
+# ---------------------------------------------------------------------------
+# one schedule per kernel: a module constant, chosen by shape alone
+# ---------------------------------------------------------------------------
+
+_S = jax.ShapeDtypeStruct
+
+
+def _schedules():
+    """What the kernels resolve, as jaxpr text (a Pallas call's jaxpr
+    carries its grid and block shapes; the XLA scan's its chunk and unroll),
+    with every schedule argument left to the kernel; the cache token in
+    every mode; and the VMEM budget with what it decides."""
+    from functools import partial
+
+    from transmogrifai_tpu.perf.kernels import routing as KR
+
+    i32, f32 = jnp.int32, jnp.float32
+    hist = (_S((2, 4097), i32), _S((2, 2, 4097), jnp.int8),
+            _S((4097, 16), i32))
+    split = ([_S((3, 8, 1, 16, 9), f32)] * 2 + [_S((3, 8, 1), f32)] * 2
+             + [_S((3, 16), f32)] + [_S((), f32)] * 4)
+    level = (_S((4097, 16), i32), _S((3, 4), i32), _S((3, 4097), i32),
+             _S((3, 4097), i32))
+    programs = {
+        "hist_pallas": (partial(KH.hist_level_pallas, nn=8, n_bins=8,
+                                int_exact=True, interpret=True), hist),
+        "hist_xla": (partial(KH.hist_level_xla, nn=8, n_bins=8,
+                             int_exact=True), hist),
+        "split_pallas": (lambda *a: KS.split_scan_pallas(
+            *a[:5], 8, *a[5:], interpret=True), split),
+        "onehot": (lambda c: KE.onehot_codes(c, 16, interpret=True),
+                   (_S((4097,), i32),)),
+        "bucketize": (lambda x, e: KE.bucketize_right_encode(
+            x, e, True, True, interpret=True),
+            (_S((4097,), f32), _S((6,), f32))),
+        "route": (partial(KR.level_select_lanes, n_bins=8, chunk=2048),
+                  level),
+    }
+    out = {name: str(jax.make_jaxpr(fn)(*specs))
+           for name, (fn, specs) in programs.items()}
+    for mode in ("xla", "interpret", "pallas"):
+        with KD.force_kernel_mode(mode):
+            out[f"token:{mode}"] = KD.cache_token()
+    out["vmem"] = (KD.vmem_budget(), KD.descent_tile(128),
+                   KD.kernel_provenance()["hist_chunk"])
+    return out
+
+
+@pytest.fixture(scope="module")
+def default_schedules():
+    return _schedules()
+
+
+class TestOneSchedule:
+    @pytest.mark.parametrize("name,value", [
+        ("TMOG_AUTOTUNE", "1"), ("TMOG_AUTOTUNE_DIR", None),
+        ("TMOG_HIST_CHUNK", "256"), ("TMOG_HIST_UNROLL", "4"),
+        ("TMOG_ENCODE_BLOCK", "256"), ("TMOG_ROUTE_BLOCK", "128"),
+        ("TMOG_SPLIT_LANE_BLOCK", "2"),
+        ("TMOG_PALLAS_VMEM_BUDGET", "2097152")])
+    def test_removed_variable_moves_neither_token_nor_schedule(
+            self, name, value, default_schedules, monkeypatch, tmp_path):
+        """None of these variables is read: setting one changes neither
+        ``cache_token()`` nor any schedule a kernel resolves."""
+        monkeypatch.setenv(name, value or str(tmp_path))
+        assert _schedules() == default_schedules
+
+
+# ---------------------------------------------------------------------------
+# each kernel against its XLA twin, in interpret mode, at the default
+# schedule: 16 features, 8 bins, 8 nodes, one class
+# ---------------------------------------------------------------------------
+
+class TestKernelTwinParity:
+    @pytest.mark.parametrize("int_exact", [True, False],
+                             ids=["int8", "float"])
+    @pytest.mark.parametrize("rows", [4096, 4097])
+    def test_hist_kernel_matches_twin(self, rows, int_exact):
+        local, ghT, binned, nn, n_bins = _hist_fixture(
+            seed=rows, L=2, n=rows, d=16, nn=8)
+        if not int_exact:
+            ghT = np.random.default_rng(rows).normal(
+                size=ghT.shape).astype(np.float32)
+        args = (jnp.asarray(local), jnp.asarray(ghT), jnp.asarray(binned),
+                nn, n_bins)
+        hp = np.asarray(KH.hist_level_pallas(*args, int_exact=int_exact,
+                                             interpret=True))
+        hx = np.asarray(KH.hist_level_xla(*args, int_exact=int_exact))
+        np.testing.assert_array_equal(hp, hx)
+        if int_exact:
+            np.testing.assert_array_equal(
+                hp, _np_exact_hist(local, ghT, binned, nn, n_bins))
+
+    @pytest.mark.parametrize("lanes", [1, 3, 4])
+    def test_split_kernel_matches_twin(self, lanes):
+        """On histograms the grower could have built: every feature's bins
+        sum to the node totals."""
+        rng = np.random.default_rng(lanes)
+        nn, d, n_bins = 8, 16, 8
+        B = n_bins + 1
+        cells = (lanes, nn, 1, d)
+
+        def spread(totals):
+            return rng.multinomial(
+                np.broadcast_to(totals[..., None], cells), np.full(B, 1 / B))
+
+        pos, neg = (rng.integers(0, 120, (lanes, nn, 1)) for _ in range(2))
+        tot_h = rng.integers(B, 8 * B, (lanes, nn, 1))
+        hists = [jnp.asarray(h.astype(np.float32))
+                 for h in (spread(pos) - spread(neg), spread(tot_h),
+                           pos - neg, tot_h)]
+        mask = jnp.ones((lanes, d), jnp.float32)
+        params = [jnp.float32(v) for v in (1.0, 0.5, 0.1, 1.0)]
+        got = KS.split_scan_pallas(*hists, mask, n_bins, *params,
+                                   interpret=True)
+        want = KS.split_scan_xla(*hists, mask, n_bins, *params)
+        for g, w in zip(got, want):
+            assert g.shape == (lanes, nn)
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+    @pytest.mark.parametrize("rows", [1, 1025, 4097])
+    def test_encode_kernel_matches_twin(self, rows):
+        width = 16
+        codes = jnp.asarray(np.random.default_rng(rows).integers(
+            -1, width + 1, rows).astype(np.int32))
+        got = np.asarray(KE.onehot_codes(codes, width, interpret=True))
+        np.testing.assert_array_equal(
+            got, np.asarray(jax.nn.one_hot(codes, width, dtype=jnp.float32)))
 
 
 # ---------------------------------------------------------------------------
@@ -466,10 +610,9 @@ class TestKernelIrFamilies:
 class TestRoutingParity:
     """The row select of the tree walk (perf/kernels/routing.py, ISSUE 15
     satellite; the level's-columns form since PR 37) must be BITWISE
-    identical across the XLA compare-reduce, the level's-columns matmul, the
-    interpret-mode kernel, and the walk's entry — routing decides which
-    child every row takes, so a single off-by-one moves rows between
-    leaves."""
+    identical across the XLA compare-reduce, the level's-columns matmul and
+    the walk's entry, in every mode — routing decides which child every row
+    takes, so a single off-by-one moves rows between leaves."""
 
     def _fixture(self, seed=0, n=700, d=9, L=4, n_bins=8):
         rng = np.random.default_rng(seed)
@@ -492,7 +635,20 @@ class TestRoutingParity:
         truth = binned[np.arange(n)[None, :], idx]
         return binned, feat, local, idx, truth
 
+    def _walk_entry(self, binned, feat, local, idx):
+        """``level_select_lanes`` in interpret mode, on the rows at a node
+        of the level (the others' value is the caller's to discard)."""
+        from transmogrifai_tpu.perf.kernels import routing as KR
+
+        with KD.force_kernel_mode("interpret"):
+            out = np.asarray(KR.level_select_lanes(
+                jnp.asarray(binned), jnp.asarray(feat), jnp.asarray(local),
+                jnp.asarray(idx), 8, 2048))
+        return out[local >= 0]
+
     def test_interpret_kernel_bitwise_vs_xla_and_ground_truth(self):
+        """The compare-reduce and the walk's entry, in the mode that once
+        emulated a routing kernel, against ground truth."""
         from transmogrifai_tpu.perf.kernels import routing as KR
 
         binned, idx = self._fixture()
@@ -500,21 +656,28 @@ class TestRoutingParity:
                           for l in range(idx.shape[0])])
         ref = np.asarray(KR.row_select_lanes_xla(jnp.asarray(binned),
                                                  jnp.asarray(idx)))
-        ker = np.asarray(KR.row_select_lanes_pallas(
-            jnp.asarray(binned), jnp.asarray(idx), interpret=True))
         np.testing.assert_array_equal(ref, truth)
-        np.testing.assert_array_equal(ker, truth)
+        for nn in (4, 16):     # the level's columns, then the compare-reduce
+            binned, feat, local, idx, truth = self._level(seed=nn, nn=nn)
+            np.testing.assert_array_equal(
+                self._walk_entry(binned, feat, local, idx),
+                truth[local >= 0])
 
     def test_unaligned_rows_and_single_lane(self):
         from transmogrifai_tpu.perf.kernels import routing as KR
 
         for n, d, L in ((257, 3, 1), (100, 12, 7), (513, 5, 2)):
             binned, idx = self._fixture(seed=n, n=n, d=d, L=L)
+            truth = np.stack([binned[np.arange(n), idx[l]]
+                              for l in range(L)])
             ref = np.asarray(KR.row_select_lanes_xla(jnp.asarray(binned),
                                                      jnp.asarray(idx)))
-            ker = np.asarray(KR.row_select_lanes_pallas(
-                jnp.asarray(binned), jnp.asarray(idx), interpret=True))
-            np.testing.assert_array_equal(ker, ref)
+            np.testing.assert_array_equal(ref, truth)
+            binned, feat, local, idx, truth = self._level(
+                seed=n, n=n, d=d, L=L, nn=4)
+            np.testing.assert_array_equal(
+                self._walk_entry(binned, feat, local, idx),
+                truth[local >= 0])
 
     def test_dispatcher_honors_mode_and_trees_alias(self):
         """The walk's entry is XLA's own code in EVERY mode (PR 33), in
@@ -611,9 +774,9 @@ class TestRoutingParity:
         assert KR.select_cols(depth, d) == want
 
     def test_growth_bitwise_across_routing_modes(self):
-        """End-to-end: tree growth (whose per-level routing is the kernel's
-        call site) must produce identical trees with the routing kernel
-        interpret-emulated vs the XLA path."""
+        """End-to-end: tree growth must produce identical trees in
+        interpret mode and on the XLA path — routing runs the same XLA code
+        in both."""
         binned, grad, hess, masks, n_bins = _growth_fixture()
 
         def grow():
@@ -632,16 +795,11 @@ class TestRoutingParity:
         np.testing.assert_array_equal(np.asarray(nx), np.asarray(ni))
 
     def test_vmem_admission_falls_back(self, monkeypatch):
-        """``route_mode`` still answers a caller that names the kernel; the
-        grower's entry no longer asks it, at a shape it admits or refuses."""
+        """With compiled Pallas forced, the walk's entry runs XLA's code at
+        shapes any VMEM budget would admit or refuse alike."""
         from transmogrifai_tpu.perf.kernels import routing as KR
-        from transmogrifai_tpu.perf.kernels.dispatch import route_mode
 
         monkeypatch.setenv("TMOG_PALLAS", "pallas")
-        assert route_mode(8, 2) == "pallas"
-        # a lane/feature product far past any VMEM budget must fall back
-        assert route_mode(4096, 4096) is None
-        monkeypatch.setattr(KR, "row_select_lanes_pallas", None)  # unreached
         for d, L, nn in ((8, 2, 4), (8, 2, 8), (40, 130, 16), (40, 130, 64)):
             binned, feat, local, idx, truth = self._level(
                 seed=d, n=64, d=d, L=L, nn=nn)

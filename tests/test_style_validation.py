@@ -158,14 +158,6 @@ class TestStyleValidation:
                         os.path.join("deploy", "bundle.py")):
             assert dep_mod in linted, \
                 f"the deploy module {dep_mod} left the lint gate"
-        for tune_mod in (os.path.join("perf", "autotune.py"),
-                         os.path.join("cli", "tune.py")):
-            # the autotuner (ISSUE 19) owns a module-level memo + per-key
-            # lock table — exactly the shared-mutable-state shape TM306
-            # polices — and its CLI is operator-facing; neither may leave
-            # the gate via a rename/move
-            assert tune_mod in linted, \
-                f"the autotune module {tune_mod} left the lint gate"
         assert not findings, (
             "unallowlisted hazards in serve//perf/ (fix them, or mark "
             "intentional ones inline with '# opcheck: allow(TMxxx) reason'):\n"

@@ -1080,24 +1080,11 @@ def _kernel_entries() -> List[CorpusEntry]:
             "perf.kernels.encode@interpret", fn.lower(*specs),
             content_fingerprint=cache_key_fingerprint(fn, *specs))
 
-    def route_interpret():
-        from ..perf.kernels.routing import row_select_lanes_pallas
-
-        def route_program(binned, idx):
-            return row_select_lanes_pallas(binned, idx, interpret=True)
-
-        fn = jax.jit(route_program)  # opcheck: allow(TM303) lower-only snapshot path, zero backend compiles
-        specs = [_spec(n, d, dtype="int32"), _spec(L, n, dtype="int32")]
-        return snapshot_lowered(
-            "perf.kernels.route@interpret", fn.lower(*specs),
-            content_fingerprint=cache_key_fingerprint(fn, *specs))
-
     return [
         CorpusEntry("perf.kernels.hist@interpret", hist_interpret),
         CorpusEntry("perf.kernels.hist@tpu", hist_tpu),
         CorpusEntry("perf.kernels.split_scan@interpret", split_interpret),
         CorpusEntry("perf.kernels.encode@interpret", encode_interpret),
-        CorpusEntry("perf.kernels.route@interpret", route_interpret),
     ]
 
 

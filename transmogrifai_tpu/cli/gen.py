@@ -281,7 +281,6 @@ def main(argv=None) -> int:
     from .serve import add_serve_parser, run_serve
     from .top import add_top_parser, run_top
     from .train import add_train_parser, run_train
-    from .tune import add_tune_parser, run_tune
 
     ap = argparse.ArgumentParser(prog="transmogrifai_tpu.cli")
     sub = ap.add_subparsers(dest="command", required=True)
@@ -298,7 +297,6 @@ def main(argv=None) -> int:
     add_serve_parser(sub)
     add_top_parser(sub)
     add_train_parser(sub)
-    add_tune_parser(sub)
     ns = ap.parse_args(argv)
     if ns.command == "deploy":
         return run_deploy(ns)
@@ -310,8 +308,6 @@ def main(argv=None) -> int:
         return run_top(ns)
     if ns.command == "train":
         return run_train(ns)
-    if ns.command == "tune":
-        return run_tune(ns)
     out, kind = generate_project(ns.input, ns.response, ns.output,
                                  name=ns.name, id_column=ns.id_column,
                                  schema_path=ns.schema_path)

@@ -71,24 +71,15 @@ from .prediction import PredictionColumn
 DEFAULT_BINS = 32
 
 #: histogram-accumulation row-chunk size (see _grow_tree); module-level so
-#: tests can shrink it to exercise the chunked path on small data, and
-#: env-overridable (``TMOG_HIST_CHUNK``, read through the one tuning-knob
-#: helper ``perf.kernels.dispatch.tuning_int``; ``kernel_provenance()``
-#: reports the bound value and ``chip_smoke.py`` prints it).
+#: tests can shrink it to exercise the chunked path on small data
+#: (``kernel_provenance()`` reports the bound value and ``chip_smoke.py``
+#: prints it).
 #: 2048 measured 3.8x faster than 8192 on v5e at 1M x 128 (64 bins): the
 #: per-step (chunk, B*d) bin one-hot operand is small enough for XLA to keep
 #: the one-hot -> matmul pipeline on-chip instead of spilling through HBM.
 #: Re-measured at the 32-bin default (r4): 2048 and 4096 tie (RF cv 3.4s,
 #: GBT cv 2.4s) while 8192 still regresses GBT 3.4x — 2048 stands.
-_HIST_CHUNK = _kdispatch.tuning_int("TMOG_HIST_CHUNK",
-                                    _kdispatch.HIST_CHUNK_DEFAULT)
-
-#: unroll factor for the histogram chunk scans — r5 tuning knob
-#: (``TMOG_HIST_UNROLL``): the 1M-row growth runs ~500 scan steps per level,
-#: and per-step sequencing overhead is material at 32 bins where each
-#: step's matmul is small
-_HIST_UNROLL = _kdispatch.tuning_int("TMOG_HIST_UNROLL",
-                                     _kdispatch.HIST_UNROLL_DEFAULT)
+_HIST_CHUNK = _kdispatch.HIST_CHUNK_DEFAULT
 
 #: boosting reuses ONE materialized int8 bin one-hot across all rounds and
 #: levels instead of regenerating it per histogram pass — GBT's measured
@@ -102,7 +93,6 @@ _HIST_UNROLL = _kdispatch.tuning_int("TMOG_HIST_UNROLL",
 #: direct caller of ``_fit_gbt`` that hands none in gets the per-pass
 #: rebuild.  Capped so the operand (n_padded * (bins+1) * d int8) never
 #: risks HBM.
-_GBT_MAT_BINOH = True
 _BINOH_MAT_MAX_BYTES = 6_000_000_000
 
 
@@ -536,7 +526,7 @@ def _grow_trees(binned: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
     def _hist_block(local_blk, ghT_blk, binned_blk, nn, premade):
         # local_blk: (L, rows); ghT_blk: (L, 2K, rows); binned_blk is the
         # (rows, d) codes — or, when ``premade``, the already-materialized
-        # (rows, B*d) int8 one-hot slice (boosting reuse, _GBT_MAT_BINOH)
+        # (rows, B*d) int8 one-hot slice (boosting reuse, _bin_onehot)
         rows = binned_blk.shape[0]
         # node one-hot generated DIRECTLY in (L, nn, rows) layout — broadcast
         # compare; no transpose anywhere on the lane-folded lhs
@@ -565,7 +555,7 @@ def _grow_trees(binned: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
         if kmode is not None:
             # fused Pallas build: row chunks stream through VMEM, the
             # (M, B*d) accumulator stays resident across the whole pass —
-            # the premade bin one-hot (_GBT_MAT_BINOH) is unnecessary here,
+            # the premade bin one-hot (_bin_onehot) is unnecessary here,
             # the kernel constructs its one-hots in VMEM per chunk
             hist = _khist.hist_level_pallas(
                 local, ghT, binned, nn, n_bins, int_exact=int_exact,
@@ -581,8 +571,7 @@ def _grow_trees(binned: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
             hist0 = jnp.zeros((L * nn * 2 * K, B * d), acc_t)
             hist, _ = jax.lax.scan(
                 chunk_step, hist0,
-                (local_c, gh_c, bin_oh_c if premade else binned_c),
-                unroll=_HIST_UNROLL)
+                (local_c, gh_c, bin_oh_c if premade else binned_c))
         else:
             hist = _hist_block(local, ghT, binned, nn, False)
         # int_exact: per-(node, feat, bin) partial sums stay far below 2^24,
@@ -1303,10 +1292,9 @@ class _GBTBase(_TreeEstimatorBase):
         n, d = (int(v) for v in binned.shape)
         rounds, depth = int(self.num_rounds), int(self.max_depth)
         kmode = _deep_hist_mode(lanes, num_class, depth, int(self.n_bins), d)
-        mat = _GBT_MAT_BINOH and kmode is None
         return dict(
             lanes=lanes, rounds=rounds, levels=depth,
-            binoh_bytes=_binoh_bytes(n, d, int(self.n_bins)) if mat else 0,
+            binoh_bytes=0 if kmode else _binoh_bytes(n, d, int(self.n_bins)),
             hist_kernel=_khist.hist_level_pallas.__name__ if kmode else "xla",
             route_kernel=_krout.ROUTE_KERNEL,
             binoh_walks=rounds * depth,
@@ -1384,9 +1372,7 @@ class _GBTBase(_TreeEstimatorBase):
                     **self._shared_bin_onehot(binned, counts)},
             statics=dict(objective=objective, num_class=num_class,
                          metric_fn=metric_fn, **self._fit_config()),
-            key_extras=dict(mat_binoh=_GBT_MAT_BINOH,
-                            hist_chunk=_HIST_CHUNK,
-                            hist_unroll=_HIST_UNROLL),
+            key_extras=dict(hist_chunk=_HIST_CHUNK),
             label=f"{type(self).__name__}/cv_program", counts=counts)
 
 
@@ -1545,8 +1531,7 @@ class _ForestBase(_TreeEstimatorBase):
                          # targets: exact int8 when fold weights are 0/1 and
                          # targets are class indicators
                          int_exact=weights01 and self.classification),
-            key_extras=dict(hist_chunk=_HIST_CHUNK,
-                            hist_unroll=_HIST_UNROLL),
+            key_extras=dict(hist_chunk=_HIST_CHUNK),
             label=f"{type(self).__name__}/cv_program")
 
 

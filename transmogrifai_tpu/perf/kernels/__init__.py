@@ -11,9 +11,9 @@ Modules:
 
 - :mod:`.dispatch` — mode resolution (``TMOG_PALLAS``: compiled Pallas on
   TPU, ``pallas.interpret=True`` for CPU/CI parity tests, XLA reference as
-  escape hatch), VMEM admission guards, the cache token that keys every
-  ``run_cached`` executable and plan fingerprint on the kernel choice, and
-  the env-overridable tuning knobs (``TMOG_HIST_CHUNK``, ...).
+  escape hatch), VMEM admission guards, and the cache token that keys
+  every ``run_cached`` executable and plan fingerprint on the kernel
+  choice.
 - :mod:`.histogram` — fused histogram-build kernel: row chunks stream
   through VMEM, per-(node, class, feature, bin) grad/hess histograms
   accumulate in a VMEM-resident accumulator (exact-int8 path included),
@@ -26,16 +26,16 @@ Modules:
   (``ops/onehot.py``) and right-inclusive bucketize one-hot
   (``ops/bucketizers.py``).
 - :mod:`.routing` — the tree walk's row select: the level's columns
-  gathered on the MXU, the compare-reduce, and the routing kernel.
+  gathered on the MXU, and the compare-reduce.  Its Pallas kernel was
+  never selected, and is gone.
 - :mod:`.descent` — one step of the linear SVC's squared-hinge descent
   (margins, residuals and gradient) in one read of the block, and the
   XLA formulation it replaces.
 
-Tuning: every kernel resolves its schedule parameters as explicit arg >
-env knob > the persistent autotuner's verified winner for the shape class
-(:mod:`transmogrifai_tpu.perf.autotune`) > module default.  Adopted winners
-ride ``cache_token()`` so tuned and untuned processes never alias
-executables or deploy artifacts.
+Schedules: a kernel's row chunk or block is a constant of its module,
+chosen by shape alone (the histogram kernel also takes its caller's
+``chunk``).  The Pallas/XLA mode is the only kernel choice a program key
+records; a chip measurement, not a knob, changes a schedule.
 
 Parity discipline (docs/performance.md "Pallas fused tree kernels"):
 interpret-mode kernels are pinned bitwise-equal to the exact-int8 GEMM
@@ -49,5 +49,4 @@ from .dispatch import (  # noqa: F401
     force_kernel_mode,
     kernel_mode,
     kernel_provenance,
-    tuning_int,
 )

@@ -27,14 +27,14 @@ formulation — with the decision itself made observable and cacheable:
   mode can never serve a stale executable compiled for the other mode —
   the same fallback discipline the fused transform planner established
   (``TMOG_FUSED_TRANSFORM``, PR 4).
-- VMEM admission guards (``hist_mode``/``split_mode``/``route_mode``/
-  ``encode_mode``/``descent_mode``): compiled Pallas keeps its accumulator
-  and operands resident in VMEM, so a shape whose working set exceeds the
-  budget (``TMOG_PALLAS_VMEM_BUDGET``, default 10 MiB of the compiler's 16
-  MiB scoped limit — see ``_DEFAULT_VMEM_BUDGET``) takes the XLA path by this
-  module's DECISION, before the compiler is asked.  Nothing here catches a
-  compiler error and reroutes: a kernel that is selected and then refused
-  fails the program.  Interpret mode has no such limit.
+- VMEM admission guards (``hist_mode``/``split_mode``/``encode_mode``/
+  ``descent_mode``): compiled Pallas keeps its accumulator and operands
+  resident in VMEM, so a shape whose working set exceeds the budget
+  (``_VMEM_BUDGET``, 10 MiB of the compiler's 16 MiB scoped limit) takes the
+  XLA path by this module's DECISION, before the compiler is asked.
+  Nothing here catches a compiler error and reroutes: a kernel that is
+  selected and then refused fails the program.  Interpret mode has no such
+  limit.
 - What ``auto`` selects on a TPU, per kernel, as established on a v5e with
   jax 0.9.0 / libtpu 0.0.34 (CHANGES.md PR 21; ``chip_smoke.py`` re-proves
   it):
@@ -45,15 +45,6 @@ formulation — with the decision itself made observable and cacheable:
   ``split_scan``        selected (all tree levels at d=128, 32 bins).
                         Repaired in PR 21: the (8, 128) block rule and the
                         missing ``cumsum`` lowering refused the original.
-  ``row_select_lanes``  never (PR 33): the walk's entry
-                        (``level_select_lanes``) runs XLA's own code in
-                        every mode.  The kernel pads the
-                        lane axis to 128, so it took 74.5 ms a call at
-                        2^20 x 128 codes for three lanes and for one (22.3
-                        of a boosted fit's 26.3 s) where the XLA
-                        compare-reduce takes 2.3 and 0.9 ms; ``route_mode``
-                        only answers callers that name the kernel
-                        themselves.
   ``onehot_codes``,     selected at every serving width.
   ``bucketize_right``
   ``hist_level``        selected only where the working set fits; at
@@ -75,12 +66,15 @@ formulation — with the decision itself made observable and cacheable:
   and the kernels are not wrapped in ``shard_map`` yet, so the sharded
   programs keep the XLA formulations (``chip_smoke.py --mesh 2x2``).
 
+  The tree walk's row select runs XLA's own code in every mode
+  (``routing.level_select_lanes``): its Pallas kernel lost to it on the
+  chip, was never selected, and is gone.
+
   ``kernel_selections()`` counts the decisions this process actually made
   (at trace time), so a run can show which kernels it really used.
-- ``tuning_int()`` is the one helper every env-overridable tuning knob
-  reads through (``TMOG_HIST_CHUNK``, ``TMOG_HIST_UNROLL``, the VMEM
-  budget); ``kernel_provenance()`` reports the live values, so a run
-  (``chip_smoke.py`` prints them) says what tuning it ran under.
+- A kernel's schedule (row chunk, block) is a constant of its module,
+  chosen by shape alone; ``kernel_provenance()`` reports the live values
+  (``chip_smoke.py`` prints them).
 """
 
 from __future__ import annotations
@@ -98,59 +92,27 @@ log = logging.getLogger(__name__)
 #: A scalar rebind (not a mutated container) — single-writer test usage.
 _FORCED: Optional[str] = None
 
-#: the autotuner's cache-token component ("" = untuned/defaults), installed
-#: by perf/autotune.py under its own guard whenever a non-default winner is
-#: adopted.  A scalar rebind read lock-free here — same discipline as
-#: _FORCED; the writer holds autotune._GUARD.
-_TUNING_TOKEN: str = ""
-
 #: test override installed by force_serve_donation(); None = resolve from
 #: env.  Same scalar-rebind discipline as _FORCED.
 _FORCED_DONATION: Optional[bool] = None
 
-#: default VMEM budget for compiled kernels (bytes).  Source: the TPU
+#: VMEM budget for compiled kernels (bytes).  Source: the TPU
 #: compiler's own report for a v5e under libtpu 0.0.34 — "Scoped allocation
 #: with size 25.45M and limit 16.00M exceeded scoped vmem limit" (Mosaic
 #: kernels get a 16 MiB scoped-VMEM stack by default; the chip has more, but
 #: nothing here raises ``vmem_limit_bytes``).  The admission formulas count
 #: blocks and the larger temporaries, not every compiler scratch buffer, so
 #: the budget keeps 6 MiB of that limit in hand.
-_DEFAULT_VMEM_BUDGET = 10 * 1024 * 1024
+_VMEM_BUDGET = 10 * 1024 * 1024
 
 #: (kernel, chosen mode) -> how many dispatch decisions this process made;
 #: decisions happen at trace time, so this counts traces, not executions
 _SELECTIONS: Dict[Tuple[str, str], int] = {}
 _SELECTIONS_LOCK = threading.Lock()
 
-#: the histogram tuning-knob defaults — ONE definition; models/trees.py and
-#: perf/kernels/histogram.py both resolve their knobs against these
+#: the histogram kernel's row chunk — ONE definition; models/trees.py and
+#: perf/kernels/histogram.py both read it
 HIST_CHUNK_DEFAULT = 2048
-HIST_UNROLL_DEFAULT = 1
-
-
-def tuning_int(name: str, default: int, minimum: int = 1) -> int:
-    """THE env-knob reader: ``int(os.environ[name])``, ``default`` when the
-    variable is unset, non-integer, or below ``minimum`` — with a logged
-    warning on the malformed cases so a typo'd ``TMOG_HIST_CHUNK`` degrades
-    a serve boot to the default instead of crashing it or silently running
-    a clamped value nobody asked for.  Every tuning knob
-    (``TMOG_HIST_CHUNK``, ``TMOG_HIST_UNROLL``, ``TMOG_PALLAS_VMEM_BUDGET``)
-    funnels through here so provenance reporting cannot drift from the
-    values actually used."""
-    raw = os.environ.get(name)
-    if raw is None:
-        return int(default)
-    try:
-        value = int(raw)
-    except ValueError:
-        log.warning("%s=%r is not an integer — using default %d",
-                    name, raw, int(default))
-        return int(default)
-    if value < int(minimum):
-        log.warning("%s=%d is below the minimum %d — using default %d",
-                    name, value, int(minimum), int(default))
-        return int(default)
-    return value
 
 
 def _env_mode() -> str:
@@ -252,39 +214,11 @@ def cache_token() -> str:
         else f"kernels:{mode}"
     if serve_donation():
         token += ":serve-donate"
-    tune = _load_tuning_token()
-    if tune:
-        token += f":{tune}"
     return token
 
 
-def _set_tuning_token(token: str) -> None:
-    """Installed by perf/autotune.py (holding its guard) when winners are
-    adopted; "" returns the token to the untuned form so default runs stay
-    byte-identical to pre-autotuner fingerprints."""
-    global _TUNING_TOKEN
-    _TUNING_TOKEN = str(token)
-
-
-def _tuning_token() -> str:
-    return _TUNING_TOKEN
-
-
-def _load_tuning_token() -> str:
-    """The autotuner component for :func:`cache_token`: loading the winner
-    store happens HERE, eagerly at key-computation time, so a program key
-    always reflects every winner its trace could observe — a winner adopted
-    mid-trace can never alias the untuned executable."""
-    try:
-        from .. import autotune as _autotune
-
-        return _autotune.tuning_token()
-    except Exception:  # pragma: no cover — autotune import failure
-        return _TUNING_TOKEN
-
-
 def vmem_budget() -> int:
-    return tuning_int("TMOG_PALLAS_VMEM_BUDGET", _DEFAULT_VMEM_BUDGET)
+    return _VMEM_BUDGET
 
 
 def _admit(kernel: str, working_set_bytes: int, counted: bool = True
@@ -337,22 +271,6 @@ def split_mode(block_bytes: int) -> Optional[str]:
     pipeline double-buffers it, and the bin loop's running sums and
     candidates are a few (classes, nodes, features) slabs on top."""
     return _admit("split", 2 * block_bytes + block_bytes // 2)
-
-
-def route_mode(d: int, lanes: int, block_rows: int = 256) -> Optional[str]:
-    """Admission of the routing kernel (perf/kernels/routing.py) for a
-    caller that names it: the walk's entry ``level_select_lanes`` does not
-    ask (PR 33: it runs XLA's own code in every mode).
-    Its rank-3 (block, d, lanes) compare-reduce puts the lane axis on the
-    128 vector lanes, so VMEM goes by lane TILES, not lanes.  The scratch
-    model is fitted to what the v5e compiler reported (libtpu 0.0.34, d=128:
-    block 512 -> 18.64M at 1 lane tile, 25.48M at 2; block 1024 -> 36.25M
-    and 47.53M): about 192 + 88 * lane_tiles bytes per (row, feature).
-    Undersizing would select a kernel the compiler then refuses."""
-    lane_tiles = -(-lanes // 128)
-    ws = block_rows * d * (192 + 88 * lane_tiles) \
-        + 2 * block_rows * (d + 128 * lane_tiles) * 4   # in/out blocks, x2
-    return _admit("route", ws)
 
 
 def encode_mode(width: int, block_rows: int = 1024) -> Optional[str]:
@@ -437,17 +355,13 @@ def _import_pallas() -> None:
 
 
 def kernel_provenance() -> Dict[str, Any]:
-    """Dispatch + tuning snapshot (``chip_smoke.py`` prints it).
-
-    ``hist_chunk``/``hist_unroll`` report the values BOUND into
-    models/trees.py (import-time env resolution, the values traced programs
-    actually used — incl. test monkeypatches), falling back to a live env
-    read only when the trees module is absent."""
+    """Dispatch snapshot (``chip_smoke.py`` prints it).  ``hist_chunk`` is
+    the value BOUND into models/trees.py (the one traced programs used,
+    test monkeypatches included)."""
     prov = {
         "kernel_mode": kernel_mode(),
         "tmog_pallas": os.environ.get("TMOG_PALLAS", ""),
-        "hist_chunk": tuning_int("TMOG_HIST_CHUNK", HIST_CHUNK_DEFAULT),
-        "hist_unroll": tuning_int("TMOG_HIST_UNROLL", HIST_UNROLL_DEFAULT),
+        "hist_chunk": HIST_CHUNK_DEFAULT,
         "pallas_vmem_budget": vmem_budget(),
         "serve_donation": serve_donation(),
         "selected": kernel_selections(),
@@ -456,14 +370,6 @@ def kernel_provenance() -> Dict[str, Any]:
         from ...models import trees as _trees
 
         prov["hist_chunk"] = int(_trees._HIST_CHUNK)
-        prov["hist_unroll"] = int(_trees._HIST_UNROLL)
     except Exception:  # pragma: no cover — trees not importable
         pass
-    try:
-        from .. import autotune as _autotune
-
-        prov["tuning"] = _autotune.provenance()
-    except Exception:  # pragma: no cover — autotune import failure
-        prov["tuning"] = {"token": _TUNING_TOKEN, "winners": {},
-                          "store": None, "sweeps_this_process": 0}
     return prov

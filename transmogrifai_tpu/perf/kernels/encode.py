@@ -28,41 +28,14 @@ between buckets.
 
 from __future__ import annotations
 
-import os
-from typing import Optional
-
 import jax
 import jax.numpy as jnp
 
-from .dispatch import tuning_int
-
 #: row-block size for the encode grids: wide enough to amortize per-step
 #: overheads, small enough that (block, width) blocks sit comfortably in
-#: VMEM at serving widths.  Env-overridable (``TMOG_ENCODE_BLOCK``) and
-#: autotunable per shape class (perf/autotune.py family ``encode``).
+#: VMEM at serving widths (``dispatch.encode_mode`` admits the same 1024
+#: rows).
 _ENCODE_BLOCK = 1024
-
-
-def _resolve_block(block: Optional[int], n: int, width: int,
-                   interpret: bool) -> int:
-    """Row-block resolution: explicit arg > ``TMOG_ENCODE_BLOCK`` > the
-    autotuner's verified winner for this shape class > module default.
-    Winner reads hit the in-process memo the cache token already loaded —
-    trace-time resolution can never alias executables."""
-    if block is not None:
-        return int(block)
-    if os.environ.get("TMOG_ENCODE_BLOCK") is not None:
-        return tuning_int("TMOG_ENCODE_BLOCK", _ENCODE_BLOCK)
-    try:
-        from .. import autotune as _autotune
-
-        cls = _autotune.shape_class(
-            "encode", "interpret" if interpret else "pallas",
-            rows=n, width=width)
-        return int(_autotune.kernel_param("encode", cls, "block",
-                                          _ENCODE_BLOCK))
-    except Exception:  # pragma: no cover — autotune unavailable
-        return _ENCODE_BLOCK
 
 
 def _pad_block(x2d, block: int, fill):
@@ -74,14 +47,13 @@ def _pad_block(x2d, block: int, fill):
 
 
 def onehot_codes(codes: jnp.ndarray, width: int, *,
-                 interpret: bool = False,
-                 block: Optional[int] = None) -> jnp.ndarray:
+                 interpret: bool = False) -> jnp.ndarray:
     """(n, width) float32 one-hot of int32 codes — ``jax.nn.one_hot``
     semantics (out-of-range rows all-zero), as one fused Pallas pass."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    block = _resolve_block(block, int(codes.shape[0]), width, interpret)
+    block = _ENCODE_BLOCK
     c2d, n = _pad_block(codes.astype(jnp.int32)[:, None], block, -1)
     grid = c2d.shape[0] // block
 
@@ -104,8 +76,7 @@ def onehot_codes(codes: jnp.ndarray, width: int, *,
 
 def bucketize_right_encode(x: jnp.ndarray, splits: jnp.ndarray,
                            track_nulls: bool, track_invalid: bool, *,
-                           interpret: bool = False,
-                           block: Optional[int] = None) -> jnp.ndarray:
+                           interpret: bool = False) -> jnp.ndarray:
     """Fused right-inclusive bucketize one-hot — the device half of
     ``ops.bucketizers.bucketize_right`` in one Pallas pass.
 
@@ -119,7 +90,7 @@ def bucketize_right_encode(x: jnp.ndarray, splits: jnp.ndarray,
     n_splits = int(splits.shape[0])
     n_buckets = n_splits - 1
     width = n_buckets + (1 if track_invalid else 0) + (1 if track_nulls else 0)
-    block = _resolve_block(block, int(x.shape[0]), width, interpret)
+    block = _ENCODE_BLOCK
     # NaN-pad: padded rows read as missing and are sliced off anyway
     x2d, n = _pad_block(x.astype(jnp.float32)[:, None], block, jnp.nan)
     grid = x2d.shape[0] // block

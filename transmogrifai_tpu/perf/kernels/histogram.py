@@ -25,45 +25,18 @@ reference scan.
 
 :func:`hist_level_xla` is the standalone always-available reference — the
 same math as ``models/trees.py``'s in-place chunk scan (without the
-growth-loop-specific operand pre-chunking), used by the parity tests, the
-autotuner's verification and ``chip_smoke.py`` as the comparison baseline.
+growth-loop-specific operand pre-chunking), used by the parity tests and
+``chip_smoke.py`` as the comparison baseline.
 """
 
 from __future__ import annotations
 
-import os
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
-from .dispatch import HIST_CHUNK_DEFAULT, tuning_int
-
-
-def _default_chunk() -> int:
-    """Row-chunk for the kernel grid when the caller passes none — the SAME
-    env knob (and shared default) models/trees.py reads
-    (TMOG_HIST_CHUNK)."""
-    return tuning_int("TMOG_HIST_CHUNK", HIST_CHUNK_DEFAULT)
-
-
-def _tuned(mode: str, n: int, d: int, n_bins: int, L: int, nn: int,
-           two_k: int, name: str, fallback):
-    """The autotuner's winner for one hist parameter at this shape class,
-    else ``fallback``.  Consulted only when the caller pinned NOTHING
-    (explicit args and the env knob both outrank the store — winner params
-    were chosen jointly and must not be mixed with pinned ones); reads the
-    in-process memo the cache token already loaded, so resolution at trace
-    time can never alias executables (perf/autotune.py)."""
-    try:
-        from .. import autotune as _autotune
-
-        cls = _autotune.shape_class(
-            "hist", mode, rows=n, features=d, bins=n_bins, lanes=L,
-            nodes=nn, classes=max(1, two_k // 2))
-        return _autotune.kernel_param("hist", cls, name, fallback)
-    except Exception:  # pragma: no cover — autotune unavailable
-        return fallback
+from .dispatch import HIST_CHUNK_DEFAULT
 
 
 def _pad_rows(local, ghT, binned, chunk: int):
@@ -82,8 +55,7 @@ def hist_level_pallas(local: jnp.ndarray, ghT: jnp.ndarray,
                       binned: jnp.ndarray, nn: int, n_bins: int, *,
                       int_exact: bool = False, mxu_dtype=None,
                       interpret: bool = False,
-                      chunk: Optional[int] = None,
-                      variant: Optional[str] = None) -> jnp.ndarray:
+                      chunk: Optional[int] = None) -> jnp.ndarray:
     """(L*nn*2K, B*d) per-(node, class, feature, bin) histograms, fused.
 
     local: (L, n) int32 per-lane local node index (negative = inactive row —
@@ -97,18 +69,7 @@ def hist_level_pallas(local: jnp.ndarray, ghT: jnp.ndarray,
     output block (pl.when-initialized at step 0).  int8 operands accumulate
     in int32 (exact); float operands go through the MXU in ``mxu_dtype``
     (bf16 on TPU, f32 in CPU parity runs — trees' ``_hist_dtype`` contract)
-    and accumulate in f32.
-
-    ``variant`` selects the kernel schedule (autotune family ``hist``):
-    ``"stream"`` (default) is the chunk grid above — block DMA per step,
-    double-buffered by the Pallas pipeline on TPU; ``"resident"`` holds
-    every operand VMEM-resident for the whole pass and loops the chunks
-    inside ONE kernel invocation (no per-step DMA — wins when the working
-    set fits VMEM outright).  Both share the identical per-chunk math and
-    sequential accumulation order, so the exact-int8 path is bitwise-equal
-    across variants.  When the caller pins neither ``chunk`` nor
-    ``variant``, the persistent autotuner's verified winner for this shape
-    class applies (perf/autotune.py).
+    and accumulate in f32.  ``chunk`` defaults to ``HIST_CHUNK_DEFAULT``.
     """
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -120,23 +81,13 @@ def hist_level_pallas(local: jnp.ndarray, ghT: jnp.ndarray,
     M = L * nn * two_k
     hdt = jnp.int8 if int_exact else jnp.dtype(mxu_dtype or ghT.dtype)
     acc_t = jnp.int32 if int_exact else jnp.float32
-    mode = "interpret" if interpret else "pallas"
-    if chunk is None and variant is None \
-            and os.environ.get("TMOG_HIST_CHUNK") is None:
-        chunk = int(_tuned(mode, n, d, n_bins, L, nn, two_k, "chunk",
-                           HIST_CHUNK_DEFAULT))
-        variant = str(_tuned(mode, n, d, n_bins, L, nn, two_k, "variant",
-                             "stream"))
-    chunk = int(chunk or _default_chunk())
-    variant = variant or "stream"
-    if variant not in ("stream", "resident"):
-        raise ValueError(f"unknown hist kernel variant {variant!r}")
+    chunk = int(chunk or HIST_CHUNK_DEFAULT)
     local, ghT, binned, n_p = _pad_rows(local, ghT, binned, chunk)
     grid = n_p // chunk
 
     def _chunk_update(lb, gh, bb):
-        """The shared per-chunk math: node one-hot x gh contraction against
-        the joint (feature, bin) one-hot — identical across variants."""
+        """The per-chunk math: node one-hot x gh contraction against the
+        joint (feature, bin) one-hot."""
         node_ids = jax.lax.broadcasted_iota(jnp.int32, (1, nn, 1), 1)
         # select in a 32-bit type, then narrow: the v5e vector unit has no
         # int8 multiply or int8 sublane broadcast (Mosaic: "failed to
@@ -155,29 +106,6 @@ def hist_level_pallas(local: jnp.ndarray, ghT: jnp.ndarray,
         return jax.lax.dot_general(
             acc, bin_oh, (((1,), (0,)), ((), ())),
             preferred_element_type=acc_t)
-
-    if variant == "resident":
-        def kernel(local_ref, gh_ref, binned_ref, out_ref):
-            def body(c, acc):
-                sl = pl.dslice(c * chunk, chunk)
-                return acc + _chunk_update(local_ref[:, sl],
-                                           gh_ref[:, :, sl],
-                                           binned_ref[sl, :])
-
-            out_ref[:] = jax.lax.fori_loop(
-                0, grid, body, jnp.zeros((M, B * d), acc_t))
-
-        return pl.pallas_call(
-            kernel,
-            in_specs=[
-                pl.BlockSpec(memory_space=pltpu.VMEM),
-                pl.BlockSpec(memory_space=pltpu.VMEM),
-                pl.BlockSpec(memory_space=pltpu.VMEM),
-            ],
-            out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
-            out_shape=jax.ShapeDtypeStruct((M, B * d), acc_t),
-            interpret=bool(interpret),
-        )(local, ghT, binned)
 
     def kernel(local_ref, gh_ref, binned_ref, out_ref):
         @pl.when(pl.program_id(0) == 0)
@@ -206,11 +134,11 @@ def hist_level_pallas(local: jnp.ndarray, ghT: jnp.ndarray,
 
 def hist_level_xla(local: jnp.ndarray, ghT: jnp.ndarray, binned: jnp.ndarray,
                    nn: int, n_bins: int, *, int_exact: bool = False,
-                   mxu_dtype=None, chunk: Optional[int] = None,
-                   unroll: int = 1) -> jnp.ndarray:
+                   mxu_dtype=None, chunk: Optional[int] = None
+                   ) -> jnp.ndarray:
     """The always-available XLA reference: the one-hot GEMM chunk scan of
     ``models/trees.py`` as a standalone function (same shapes/semantics as
-    :func:`hist_level_pallas`), for parity tests and the autotuner."""
+    :func:`hist_level_pallas`), for parity tests and ``chip_smoke.py``."""
     L, n = local.shape
     two_k = ghT.shape[1]
     d = binned.shape[1]
@@ -218,13 +146,7 @@ def hist_level_xla(local: jnp.ndarray, ghT: jnp.ndarray, binned: jnp.ndarray,
     M = L * nn * two_k
     hdt = jnp.int8 if int_exact else jnp.dtype(mxu_dtype or ghT.dtype)
     acc_t = jnp.int32 if int_exact else jnp.float32
-    if chunk is None and os.environ.get("TMOG_HIST_CHUNK") is None \
-            and os.environ.get("TMOG_HIST_UNROLL") is None:
-        chunk = int(_tuned("xla", n, d, n_bins, L, nn, two_k, "chunk",
-                           HIST_CHUNK_DEFAULT))
-        unroll = int(_tuned("xla", n, d, n_bins, L, nn, two_k, "unroll",
-                            unroll))
-    chunk = int(chunk or _default_chunk())
+    chunk = int(chunk or HIST_CHUNK_DEFAULT)
     local, ghT, binned, n_p = _pad_rows(local, ghT, binned, chunk)
     n_chunks = n_p // chunk
 
@@ -246,6 +168,5 @@ def hist_level_xla(local: jnp.ndarray, ghT: jnp.ndarray, binned: jnp.ndarray,
             preferred_element_type=acc_t), None
 
     hist0 = jnp.zeros((M, B * d), acc_t)
-    hist, _ = jax.lax.scan(chunk_step, hist0, (local_c, gh_c, binned_c),
-                           unroll=unroll)
+    hist, _ = jax.lax.scan(chunk_step, hist0, (local_c, gh_c, binned_c))
     return hist

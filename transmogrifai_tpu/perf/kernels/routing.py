@@ -1,5 +1,5 @@
-"""Row routing of the tree walk: the level's-columns select, the compare-
-reduce it replaced, and the Pallas kernel nobody reaches.
+"""Row routing of the tree walk: the level's-columns select and the
+compare-reduce it replaced.
 
 Reference capability (SURVEY §2.9): XGBoost's row-partition routing — after a
 level's splits are chosen, every row reads the bin code of its node's split
@@ -20,16 +20,12 @@ This module holds the ONE definition of that math:
   trace time alone; :func:`select_cols` counts what it gives a tree;
 - :func:`row_select_lanes_xla` — ``binned[i, idx[l, i]]`` as a one-hot
   compare against a feature iota fused into a streaming multiply-reduce over
-  ALL d columns: the form of the levels with ``nn >= d``, of the parity
-  tests, the autotuner and the corpus;
-- :func:`row_select_lanes_pallas` — the fused kernel: the grid walks row
-  blocks, each step holds one (block, d) code tile, the (block, L) lane
-  indices, and the (block, d, L) one-hot product in VMEM, emitting the
-  routed (block, L) codes in one pass.  The walk never selects it (PR 33:
-  on a v5e it took 74.5 ms a call at 2^20 x 128 codes, the same for three
-  lanes and for one, where the XLA compare-reduce takes 2.3 and 0.9 ms); it
-  stays for callers that name it (parity tests, the autotuner,
-  ``chip_smoke.py``).
+  ALL d columns: the form of the levels with ``nn >= d`` and of the parity
+  tests.
+
+A Pallas routing kernel lost to the compare-reduce on a v5e (74.5 ms a call
+at 2^20 x 128 codes, the same for three lanes and for one, where the
+compare-reduce takes 2.3 and 0.9 ms): it was never selected, and is gone.
 
 Selection parity: every product is an exact 0/code value (codes < 2^24 in
 float32, <= 256 in bfloat16: :func:`select_dtype`) and each sum holds exactly
@@ -40,38 +36,10 @@ tests/test_tree_level_select.py).
 
 from __future__ import annotations
 
-import os
-from typing import Optional
-
 import jax
 import jax.numpy as jnp
 
 from . import dispatch as _dispatch
-
-#: row-block size for the routing grid: the (block, d, L) one-hot product is
-#: the VMEM resident — the admission guard scales against it.
-#: Env-overridable (``TMOG_ROUTE_BLOCK``) and autotunable per shape class
-#: (perf/autotune.py family ``route``).
-_ROUTE_BLOCK = 256
-
-
-def _resolve_block(block: Optional[int], n: int, d: int, L: int,
-                   mode: str) -> int:
-    """Row-block resolution: explicit arg > ``TMOG_ROUTE_BLOCK`` > the
-    autotuner's verified winner for this shape class > module default."""
-    if block is not None:
-        return int(block)
-    if os.environ.get("TMOG_ROUTE_BLOCK") is not None:
-        return _dispatch.tuning_int("TMOG_ROUTE_BLOCK", _ROUTE_BLOCK)
-    try:
-        from .. import autotune as _autotune
-
-        cls = _autotune.shape_class("route", mode, rows=n, features=d,
-                                    lanes=L)
-        return int(_autotune.kernel_param("route", cls, "block",
-                                          _ROUTE_BLOCK))
-    except Exception:  # pragma: no cover — autotune unavailable
-        return _ROUTE_BLOCK
 
 
 def row_select_lanes_xla(binned: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
@@ -85,60 +53,11 @@ def row_select_lanes_xla(binned: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
         .astype(jnp.int32)
 
 
-def row_select_lanes_pallas(binned: jnp.ndarray, idx: jnp.ndarray, *,
-                            interpret: bool = False,
-                            block: Optional[int] = None) -> jnp.ndarray:
-    """Fused per-row-block routing; same contract as
-    :func:`row_select_lanes_xla`.
-
-    The lane axis rides the block's minor dimension (idx enters transposed
-    to (n, L)), so the one-hot product reduces over the feature axis with
-    ``keepdims``-free layouts and each output column is a lane — no
-    relayout between the reduce and the store."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    n, d = binned.shape
-    L = idx.shape[0]
-    block = _resolve_block(block, int(n), int(d), int(L),
-                           "interpret" if interpret else "pallas")
-    pad = (-n) % block
-    if pad:
-        # padded rows select feature 0 of zero-rows and are sliced off
-        binned = jnp.pad(binned, ((0, pad), (0, 0)))
-        idx = jnp.pad(idx, ((0, 0), (0, pad)))
-    n_p = n + pad
-    idx_t = idx.T.astype(jnp.int32)                              # (n_p, L)
-
-    def kernel(b_ref, i_ref, o_ref):
-        codes = b_ref[:].astype(jnp.float32)                     # (block, d)
-        sel = i_ref[:]                                           # (block, L)
-        ids = jax.lax.broadcasted_iota(jnp.int32, (block, d), 1)
-        oh = (ids[:, :, None] == sel[:, None, :]).astype(jnp.float32)
-        o_ref[:] = (codes[:, :, None] * oh).sum(axis=1).astype(jnp.int32)
-
-    out = pl.pallas_call(
-        kernel,
-        grid=(n_p // block,),
-        in_specs=[
-            pl.BlockSpec((block, d), lambda i: (i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((block, L), lambda i: (i, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((block, L), lambda i: (i, 0),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((n_p, L), jnp.int32),
-        interpret=bool(interpret),
-    )(binned.astype(jnp.int32), idx_t)
-    return out[:n].T
-
-
 #: what :func:`level_select_lanes` runs, whatever the shape and the dispatch
-#: mode — XLA's own code, in either of its two forms: the kernel's rank-3
-#: (block, d, L) one-hot pads the lane axis to a 128-lane tile, so a row
-#: costs it d x 128 compare-multiply-adds for the d x L useful ones, and
-#: past 128 lanes VMEM admission refused it anyway.
+#: mode — XLA's own code, in either of its two forms: a Pallas kernel's
+#: rank-3 (block, d, L) one-hot pads the lane axis to a 128-lane tile, so a
+#: row would cost it d x 128 compare-multiply-adds for the d x L useful
+#: ones.
 #: The ``host.launch`` spans of the boosting programs carry it as
 #: ``route_kernel`` (models/trees.py ``_launch_counts``).
 ROUTE_KERNEL = "xla"

@@ -10,9 +10,9 @@ This module holds the ONE definition of that math for the TPU port:
 
 - :func:`split_scan_xla` — the formulation ``models/trees.py`` historically
   inlined per level, moved here verbatim so the XLA path, the Pallas
-  kernel, the parity tests, and the autotuner all share it;
+  kernel and the parity tests all share it;
 - :func:`split_scan_pallas` — the fused kernel: grid over lanes, each step
-  holds one lane block's (B, K, nn, d) histogram block in VMEM and produces
+  holds one lane's (B, K, nn, d) histogram block in VMEM and produces
   the per-node best split index / gain / missing-direction without any of
   the intermediate (L, nodes, d, bins) gain tensors touching HBM — the
   histogram epilogue fused to its decision;
@@ -95,30 +95,9 @@ def split_scan_xla(hist_g, hist_h, G, H, level_mask, n_bins: int,
     return best, best_gain, bml
 
 
-def _resolve_lane_block(lane_block, L: int, nn: int, K: int, d: int,
-                        n_bins: int, mode: str) -> int:
-    """Lane-block resolution: explicit arg > ``TMOG_SPLIT_LANE_BLOCK`` >
-    the autotuner's verified winner for this shape class > 1 (the original
-    one-lane-per-step grid)."""
-    import os
-
-    if lane_block is not None:
-        return int(lane_block)
-    if os.environ.get("TMOG_SPLIT_LANE_BLOCK") is not None:
-        return _dispatch.tuning_int("TMOG_SPLIT_LANE_BLOCK", 1)
-    try:
-        from .. import autotune as _autotune
-
-        cls = _autotune.shape_class("split", mode, lanes=L, nodes=nn,
-                                    classes=K, features=d, bins=n_bins)
-        return int(_autotune.kernel_param("split", cls, "lane_block", 1))
-    except Exception:  # pragma: no cover — autotune unavailable
-        return 1
-
-
 def split_scan_pallas(hist_g, hist_h, G, H, level_mask, n_bins: int,
                       reg_lambda, alpha, gamma, min_child_weight, *,
-                      interpret: bool = False, lane_block=None
+                      interpret: bool = False
                       ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Fused per-lane split scan; same contract as :func:`split_scan_xla`.
 
@@ -131,28 +110,12 @@ def split_scan_pallas(hist_g, hist_h, G, H, level_mask, n_bins: int,
     the winning feature is the smallest flat (feature, bin) index at the
     maximum — ``argmax``'s first-occurrence rule, without a flattening
     reshape or a gather.  Outputs are (L, nn, 1) so a one-lane block is a
-    legal (8, 128)-rule block shape.
-
-    ``lane_block`` lanes share one grid step (autotune family ``split``;
-    default 1).  Lanes padded up to the block multiple carry all-zero
-    histograms, score ``-inf`` everywhere (the min-child-weight guard), and
-    are sliced off — per-lane results are independent of the blocking."""
+    legal (8, 128)-rule block shape.  One lane a grid step."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     L, nn, K, d, B = hist_g.shape
     F = d * (n_bins - 1)
-    lb = max(1, _resolve_lane_block(
-        lane_block, L, nn, K, d, n_bins,
-        "interpret" if interpret else "pallas"))
-    pad = (-L) % lb
-    if pad:
-        hist_g = jnp.pad(hist_g, ((0, pad),) + ((0, 0),) * 4)
-        hist_h = jnp.pad(hist_h, ((0, pad),) + ((0, 0),) * 4)
-        G = jnp.pad(G, ((0, pad), (0, 0), (0, 0)))
-        H = jnp.pad(H, ((0, pad), (0, 0), (0, 0)))
-        level_mask = jnp.pad(level_mask, ((0, pad), (0, 0)))
-    L_p = L + pad
     hg_t = hist_g.transpose(0, 4, 2, 1, 3)              # (L, B, K, nn, d)
     hh_t = hist_h.transpose(0, 4, 2, 1, 3)
     G_t = G.transpose(0, 2, 1)[..., None]               # (L, K, nn, 1)
@@ -167,16 +130,16 @@ def split_scan_pallas(hist_g, hist_h, G, H, level_mask, n_bins: int,
     def kernel(hg_ref, hh_ref, g_ref, h_ref, mask_ref, p_ref,
                best_ref, gain_ref, bml_ref):
         args = (p_ref[0, 0], p_ref[0, 1], p_ref[0, 2], p_ref[0, 3])
-        Gt, Ht = g_ref[:], h_ref[:]                     # (lb, K, nn, 1)
+        Gt, Ht = g_ref[:], h_ref[:]                     # (1, K, nn, 1)
         g_miss, h_miss = hg_ref[:, n_bins], hh_ref[:, n_bins]
-        masked = mask_ref[:] > 0                        # (lb, 1, d)
+        masked = mask_ref[:] > 0                        # (1, 1, d)
 
         def score(gl, hl):
             mr = _gain_terms(gl, hl, Gt, Ht, *args, class_axis=1)
             ml = _gain_terms(gl + g_miss, hl + h_miss, Gt, Ht, *args,
                              class_axis=1)
             gain = jnp.where(masked, jnp.maximum(mr, ml), -jnp.inf)
-            return gain, (ml >= mr).astype(jnp.int32)   # (lb, nn, d) each
+            return gain, (ml >= mr).astype(jnp.int32)   # (1, nn, d) each
 
         def step(b, carry):
             gl, hl, best_gain, best_bin, best_ml = carry
@@ -195,7 +158,7 @@ def split_scan_pallas(hist_g, hist_h, G, H, level_mask, n_bins: int,
 
         # f32 index arithmetic (exact below 2^24): float lane reductions are
         # the ones every Mosaic version lowers
-        top = jnp.max(best_gain, axis=-1, keepdims=True)        # (lb, nn, 1)
+        top = jnp.max(best_gain, axis=-1, keepdims=True)        # (1, nn, 1)
         feat = jax.lax.broadcasted_iota(jnp.int32, best_bin.shape, 2)
         flat = (feat * (n_bins - 1) + best_bin).astype(jnp.float32)
         first = jnp.min(jnp.where(best_gain == top, flat, float(F - 1)),
@@ -206,31 +169,31 @@ def split_scan_pallas(hist_g, hist_h, G, H, level_mask, n_bins: int,
             jnp.where(flat == first, best_ml.astype(jnp.float32), 0.0),
             axis=-1, keepdims=True).astype(jnp.int32)
 
-    hist_spec = pl.BlockSpec((lb, B, K, nn, d), lambda l: (l, 0, 0, 0, 0),
+    hist_spec = pl.BlockSpec((1, B, K, nn, d), lambda l: (l, 0, 0, 0, 0),
                              memory_space=pltpu.VMEM)
-    gh_spec = pl.BlockSpec((lb, K, nn, 1), lambda l: (l, 0, 0, 0),
+    gh_spec = pl.BlockSpec((1, K, nn, 1), lambda l: (l, 0, 0, 0),
                            memory_space=pltpu.VMEM)
-    out_spec = pl.BlockSpec((lb, nn, 1), lambda l: (l, 0, 0),
+    out_spec = pl.BlockSpec((1, nn, 1), lambda l: (l, 0, 0),
                             memory_space=pltpu.VMEM)
     best, best_gain, bml = pl.pallas_call(
         kernel,
-        grid=(L_p // lb,),
+        grid=(L,),
         in_specs=[
             hist_spec, hist_spec, gh_spec, gh_spec,
-            pl.BlockSpec((lb, 1, d), lambda l: (l, 0, 0),
+            pl.BlockSpec((1, 1, d), lambda l: (l, 0, 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, 4), lambda l: (0, 0),
                          memory_space=pltpu.SMEM),
         ],
         out_specs=(out_spec, out_spec, out_spec),
         out_shape=(
-            jax.ShapeDtypeStruct((L_p, nn, 1), jnp.int32),
-            jax.ShapeDtypeStruct((L_p, nn, 1), jnp.float32),
-            jax.ShapeDtypeStruct((L_p, nn, 1), jnp.int32),
+            jax.ShapeDtypeStruct((L, nn, 1), jnp.int32),
+            jax.ShapeDtypeStruct((L, nn, 1), jnp.float32),
+            jax.ShapeDtypeStruct((L, nn, 1), jnp.int32),
         ),
         interpret=bool(interpret),
     )(hg_t, hh_t, G_t, H_t, mask3, params)
-    return best[:L, :, 0], best_gain[:L, :, 0], bml[:L, :, 0] != 0
+    return best[..., 0], best_gain[..., 0], bml[..., 0] != 0
 
 
 def split_scan(hist_g, hist_h, G, H, level_mask, n_bins: int,
@@ -238,17 +201,13 @@ def split_scan(hist_g, hist_h, G, H, level_mask, n_bins: int,
                ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Dispatching split scan (the entry ``models/trees.py`` calls)."""
     L, nn, K, d, B = hist_g.shape
-    mode0 = _dispatch.kernel_mode()
-    lb = _resolve_lane_block(None, int(L), int(nn), int(K), int(d),
-                             n_bins, mode0)
     # one step's grad + hess blocks as tiled in VMEM: (nn, d) pads to
-    # (8, 128) multiples under the leading (lb, B, K) axes
-    tiled = max(1, lb) * B * K * (-(-nn // 8) * 8) * (-(-d // 128) * 128) * 4
+    # (8, 128) multiples under the leading (B, K) axes
+    tiled = B * K * (-(-nn // 8) * 8) * (-(-d // 128) * 128) * 4
     mode = _dispatch.split_mode(2 * tiled)
     if mode is not None:
         return split_scan_pallas(
             hist_g, hist_h, G, H, level_mask, n_bins, reg_lambda, alpha,
-            gamma, min_child_weight, interpret=mode == "interpret",
-            lane_block=lb)
+            gamma, min_child_weight, interpret=mode == "interpret")
     return split_scan_xla(hist_g, hist_h, G, H, level_mask, n_bins,
                           reg_lambda, alpha, gamma, min_child_weight)

@@ -203,8 +203,8 @@ def run_cached(fn, *args, kwargs: Optional[Dict[str, Any]] = None,
     ``fn`` must be a ``jax.jit``-wrapped callable; ``statics`` are its
     static_argnames kwargs, ``kwargs`` its dynamic keyword operands.
     ``key_extras`` ride the cache key only — call sites thread module-level
-    layout and tuning flags (``_GBT_MAT_BINOH``, ``_HIST_CHUNK``) through here
-    so flipping one invalidates the cached executables it shaped.
+    layout constants (``_HIST_CHUNK``) through here so changing one
+    invalidates the cached executables it shaped.
     ``counts`` ride the dispatch's ``host.launch`` span beside its label.
     First call per key lowers + AOT-compiles (under the persistent
     compilation cache a warm process deserializes instead of compiling);
